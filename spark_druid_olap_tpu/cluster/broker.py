@@ -662,6 +662,10 @@ class ClusterClient:
         ver = self.engine.store.datasource_version(q.datasource)
         if ver != dp.ingest_version \
                 and self._ryw_state(q.datasource, ver) is None:
+            # say so: without the annotation a local read-your-writes
+            # serve is indistinguishable from a query the client never saw
+            self.engine.last_stats["cluster"] = {
+                "mode": "local", "reason": "read-your-writes"}
             return False
         # Select/Search carry no aggregations: their merges (concat +
         # re-page, count sum + re-limit) are always closed

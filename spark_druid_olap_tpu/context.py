@@ -32,17 +32,16 @@ def _enable_x64_once():
     import os
     if os.environ.get("SDOT_FORCE_32BIT"):
         return
-    try:
-        if jax.default_backend() == "cpu":
-            jax.config.update("jax_enable_x64", True)
-    except Exception:
-        pass
+    if jax.default_backend() == "cpu":
+        jax.config.update("jax_enable_x64", True)
 
 
 class Context:
     def __init__(self, config: Optional[Dict] = None, mesh=None,
                  auto_mesh: bool = False):
         _enable_x64_once()
+        from spark_druid_olap_tpu.utils import compile_cache
+        compile_cache.configure()
         self.config = Config(config)
         self.store = SegmentStore()
         if mesh is None and len(jax.devices()) > 1:

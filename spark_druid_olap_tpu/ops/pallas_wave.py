@@ -35,8 +35,9 @@ legal cross-step accumulator, same contract as ops/pallas_groupby.py):
   ``ops.theta.theta_registers``; the 128-lane reduction is an XLA
   epilogue in the same jit).
 
-Fallback matrix (every reject lowers through the unchanged jaxpr-fused
-program — routing tiers never change; see docs/KERNELS.md):
+Fallback matrix (every PLANNED decline lowers through the unchanged
+jaxpr-fused program — routing tiers never change; a compiler's refusal
+of an accepted group is an error, not a row here; see docs/KERNELS.md):
 
 - ``sdot.pallas.wave.enabled`` off, non-TPU backend without
   ``SDOT_PALLAS=interpret``, or group wider than
@@ -53,9 +54,10 @@ program — routing tiers never change; see docs/KERNELS.md):
   in the SAME jit after the kernel — still one kernel launch per wave,
   at the cost of one extra XLA stream of the sketch lanes' columns.
 
-Interpreter mode (``SDOT_PALLAS=interpret`` on CPU) runs the identical
-kernel through ``pl.pallas_call(..., interpret=True)`` — the
-chip-independent CI differential against the jaxpr path.
+Interpreter mode (``SDOT_PALLAS=interpret``, a test setting) runs the
+identical kernel through ``pl.pallas_call(..., interpret=True)`` — the
+chip-independent CI differential against the jaxpr path. What Mosaic
+refuses and the interpreter passes shows in tests/test_chip_compile.py.
 """
 
 from __future__ import annotations
@@ -90,8 +92,15 @@ MAX_OUT_ROWS = 4096
 
 
 class WaveFallback(Exception):
-    """Raised at build time when the group cannot lower to the wave
-    kernel; the caller builds the jaxpr-fused program instead."""
+    """Raised at build time when the group declines the wave kernel BY
+    PLAN (whitelist probe, scratch cap); the caller builds the
+    jaxpr-fused program instead."""
+
+
+class WaveCompileError(RuntimeError):
+    """The backend's compiler refused a wave program the planner had
+    accepted. Never a fallback: it fails the group's statements with
+    the compiler's text (docs/KERNELS.md "Dispatch-path selection")."""
 
 
 # =============================================================================
@@ -137,7 +146,7 @@ _SAFE_PRIMS = frozenset({
     "exp", "log", "sqrt", "rsqrt", "stop_gradient", "copy",
     "nextafter", "sub_f", "add_any",
 })
-_CALL_PRIMS = frozenset({"pjit", "closed_call", "custom_jvp_call",
+_CALL_PRIMS = frozenset({"jit", "closed_call", "custom_jvp_call",
                          "custom_vjp_call", "remat2", "checkpoint"})
 
 
@@ -185,6 +194,22 @@ def _lane_parts(lp, ctx: ScanContext, cse: Optional[FU.CSECache]):
             dense.append((p.kind, p.spec.name, vals, am))
     dense.append(("count", "__rows__", None, None))
     return base, key, dense, sketch
+
+
+def _key_masks(lp, base, key):
+    """Per-key row masks of one lane inside the kernel body.
+
+    Kernel contract (docs/KERNELS.md): every ``i1`` mask the kernel
+    combines is computed from a tile. Mosaic gives a constant the
+    replicated layout and cannot relayout a tile's mask into it, so a
+    select whose BRANCHES are all constants (``where(base, 0, n_keys)``
+    over the zero key of an ungrouped lane) is refused on the chip
+    though interpret mode passes it. An ungrouped lane's one key mask IS
+    its base mask — the same rows ``kb == 0`` selects."""
+    if not lp.dim_plans:
+        return [base]
+    kb = jnp.where(base, key.astype(jnp.int32), jnp.int32(lp.n_keys))
+    return [kb == k for k in range(lp.n_keys)]
 
 
 # =============================================================================
@@ -300,13 +325,7 @@ def build_wave_fn(ds, lanes, min_day: int, max_day: int, fplan, *,
             # theta; HLL + epilogue theta run in XLA where anything goes
         return outs
 
-    try:
-        jx = jax.make_jaxpr(probe)(probe_tiles)
-    except WaveFallback:
-        raise
-    except Exception as e:  # noqa: BLE001 — any trace failure -> jaxpr path
-        raise WaveFallback(f"lane trace failed: {e}") from e
-    _check_jaxpr(jx.jaxpr)
+    _check_jaxpr(jax.make_jaxpr(probe)(probe_tiles).jaxpr)
 
     # ---- scratch layout
     layouts: List[_LaneLayout] = []
@@ -338,12 +357,7 @@ def build_wave_fn(ds, lanes, min_day: int, max_day: int, fplan, *,
         return outs
 
     if any(lay.theta_base for lay in layouts):
-        try:
-            _check_jaxpr(jax.make_jaxpr(probe_theta)(probe_tiles).jaxpr)
-        except WaveFallback:
-            raise
-        except Exception as e:  # noqa: BLE001
-            raise WaveFallback(f"theta trace failed: {e}") from e
+        _check_jaxpr(jax.make_jaxpr(probe_theta)(probe_tiles).jaxpr)
 
     # ---- tile shape against the VMEM budget (planner/fusion.py)
     itemsizes = [np.dtype(_prep_dtype(np.dtype(array_dtype(ds, k))))
@@ -388,10 +402,8 @@ def build_wave_fn(ds, lanes, min_day: int, max_day: int, fplan, *,
             cse.prelower(fplan)                  # shared masks: once/tile
         for lp, lay in zip(lanes, layouts):
             base, key, dense, sketch = _lane_parts(lp, ctx, cse)
-            kb = jnp.where(base, key.astype(jnp.int32),
-                           jnp.int32(lp.n_keys))
-            for k in range(lp.n_keys):
-                mk = kb == k
+            key_masks = _key_masks(lp, base, key)
+            for k, mk in enumerate(key_masks):
                 for m, (kind, _, vals, am) in enumerate(dense):
                     eff = mk if am is None else (mk & am)
                     v32 = None if vals is None \
@@ -407,10 +419,9 @@ def build_wave_fn(ds, lanes, min_day: int, max_day: int, fplan, *,
                 eff = base if am is None else (base & am)
                 for j in range(TH.K_LANES):
                     hv = jnp.where(eff, TH._hash01(vals, j), 2.0)
-                    for k in range(lp.n_keys):
+                    for k, mk in enumerate(key_masks):
                         r = tbase + k * TH.K_LANES + j
-                        part = jnp.min(jnp.where(kb == k, hv, 2.0),
-                                       axis=0)
+                        part = jnp.min(jnp.where(mk, hv, 2.0), axis=0)
                         out_ref[r, :] = jnp.minimum(out_ref[r, :], part)
 
     interpret = PG._interpret()

@@ -37,7 +37,9 @@ def _hash01(v, seed: int):
     h = (h ^ (h >> 16)) * jnp.uint32(0x85EBCA6B)
     h = (h ^ (h >> 13)) * jnp.uint32(0xC2B2AE35)
     h = h ^ (h >> 16)
-    return ((h >> jnp.uint32(8)).astype(jnp.float32)
+    # 24 significant bits: exact in i32 and in f32. Through i32 because
+    # Mosaic has no unsigned -> float cast (the wave kernel traces this)
+    return ((h >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
             * jnp.float32(1.0 / (1 << 24))) + jnp.float32(1e-7)
 
 

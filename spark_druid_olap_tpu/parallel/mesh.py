@@ -70,18 +70,6 @@ def emulated_host_devices() -> Optional[int]:
     return int(m.group(1)) if m else None
 
 
-def shard_map(fn, *, mesh: Mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions. Newer jax exposes it at top
-    level (with ``check_vma``); 0.4.x only ships
-    ``jax.experimental.shard_map`` (same semantics, ``check_rep``). Every
-    engine shard_map site routes through here so the collective paths run
-    on whichever jax the host has — this is what keeps the CPU-emulated
-    8-device mesh (tests/conftest.py) a live surface rather than an
-    AttributeError."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm  # jax < 0.5
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
+# every engine shard_map site imports it from here (the sdlint mesh pass
+# roots on any call whose last segment is ``shard_map``)
+shard_map = jax.shard_map

@@ -34,7 +34,7 @@ that replication safe, and all four are checkable without a mesh:
 Shard bodies are discovered exactly like the purity pass discovers
 traced roots: direct ``shard_map(fn, ...)`` sites (any spelling whose
 last segment is ``shard_map`` — ``jax.shard_map``, the repo's
-version-compat ``parallel.mesh.shard_map``, lambdas), plus wrapper
+``parallel.mesh.shard_map`` alias, lambdas), plus wrapper
 functions that pass one of their own parameters into a shard_map call
 (``QueryEngine._shard_wrap``), whose call-site arguments then root.
 Anchors resolve by path suffix; a missing anchor skips its checks.

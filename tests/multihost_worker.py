@@ -372,13 +372,8 @@ def main():
     jax.config.update("jax_platforms", "cpu")
     # persistent XLA cache: the census compiles ~50 programs per process;
     # repeat runs (and the single-process oracle) come back warm
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          "/tmp/sdot_mh_xla_cache")
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.5)
-    except Exception:   # noqa: BLE001 — cache is an optimization only
-        pass
+    from spark_druid_olap_tpu.utils import compile_cache
+    compile_cache.configure()
     from spark_druid_olap_tpu.parallel import multihost as MH
     MH.initialize(f"127.0.0.1:{port}", nproc, pid,
                   local_device_count=devs)

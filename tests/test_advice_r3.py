@@ -1,7 +1,6 @@
 """Round-3 advisor findings, regression-locked (ADVICE.md r3).
 
-1. low — tailprobe's command channel lives in a private 0700 dir, not a
-   fixed world-writable /tmp path (local-user code-exec hazard).
+1. (retired with the probe tooling it covered, PR 22)
 2. low — EXPLAIN's late-materialization line is labelled an estimate
    (the execution-time decision additionally sees routes/sharding).
 3. low — the staged-filter split and int_set_membership share ONE
@@ -11,8 +10,6 @@
 5. low — negative plan-cache entries are a dedicated type, never a
    structural tuple sentinel.
 """
-
-import os
 
 import numpy as np
 import pandas as pd
@@ -24,36 +21,6 @@ from spark_druid_olap_tpu.ir import spec as S
 from spark_druid_olap_tpu.ops import expr_compile as EC
 from spark_druid_olap_tpu.parallel import cost as C
 from spark_druid_olap_tpu.parallel.executor import QueryEngine
-
-
-# -- 1. probe channel is private ---------------------------------------------
-
-def test_tailprobe_channel_is_private(tmp_path, monkeypatch):
-    monkeypatch.setenv("SDOT_PROBE_DIR", str(tmp_path / "probe"))
-    import importlib
-    import tools.tailprobe as tp
-    importlib.reload(tp)
-    d = tp.probe_dir()
-    assert d == str(tmp_path / "probe")
-    assert (os.stat(d).st_mode & 0o777) == 0o700
-    assert os.stat(d).st_uid == os.getuid()
-    assert tp.CMD.startswith(d) and tp.OUT.startswith(d)
-    assert not tp.CMD.startswith("/tmp/sdot_probe")
-
-
-def test_tailprobe_rejects_foreign_dir(tmp_path, monkeypatch):
-    target = tmp_path / "target"
-    target.mkdir()
-    link = tmp_path / "link"
-    link.symlink_to(target)
-    monkeypatch.setenv("SDOT_PROBE_DIR", str(link))
-    import importlib
-    import tools.tailprobe as tp
-    with pytest.raises(RuntimeError, match="symlink"):
-        importlib.reload(tp)
-    # restore a sane module state for other tests
-    monkeypatch.delenv("SDOT_PROBE_DIR")
-    importlib.reload(tp)
 
 
 # -- 3. shared chain-lowering predicate --------------------------------------

@@ -336,3 +336,56 @@ def test_wave_compile_cache_key_isolation(store):
         n2 = sum(1 for sig in eng._programs if sig and sig[0] == "aggmulti")
     assert n2 == n1 + 1, (n1, n2)
     _assert_matches(res1, res2, exact_cols=("units", "n"))
+
+
+# -- loud failures ------------------------------------------------------------
+
+class _RefusedLowering:
+    """A traced wave program whose compile the backend's compiler refuses."""
+
+    def lower(self, shapes):
+        return self
+
+    def compile(self):
+        raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: "
+                           "Invalid relayout (transport of vector<i1>)")
+
+
+@pytest.mark.parametrize("failure", ["compiler-refusal", "trace-crash"])
+def test_wave_build_failures_are_loud(store, monkeypatch, failure):
+    """Only a planned decline (``WaveFallback``) falls back to the jaxpr
+    program. A compiler refusal fails every member's statement with the
+    compiler's text and the lane set (never a silent solo re-run, never
+    taken for device loss); any other build crash still degrades the
+    group to solo — nobody hangs — and is kept in ``last_error``."""
+    from spark_druid_olap_tpu.ops.pallas_wave import WaveCompileError
+    from spark_druid_olap_tpu.parallel.sharedscan import SharedScanCoalescer
+    specs = _small_storm()
+    eng = _wave_engine(store)
+    orig = SharedScanCoalescer._wave_program_fn
+
+    def broken(self, *a, **k):
+        if failure == "trace-crash":
+            raise ValueError("lane builder bug")
+        _, unpacks, info, shapes = orig(self, *a, **k)
+        return _RefusedLowering(), unpacks, info, shapes
+
+    monkeypatch.setattr(SharedScanCoalescer, "_wave_program_fn", broken)
+    c0 = eng.sharedscan.stats()
+    with _interpret_env():
+        res, errs, _ = _run_concurrent(eng, specs)
+    c1 = eng.sharedscan.stats()
+    assert c1["pallas"]["fallbacks"] == c0["pallas"]["fallbacks"]
+    assert c1["pallas"]["launches"] == c0["pallas"]["launches"]
+    if failure == "compiler-refusal":
+        assert all(isinstance(e, WaveCompileError) for e in errs), errs
+        msg = str(errs[0])
+        assert "Invalid relayout" in msg and "GroupByQuerySpec" in msg
+        assert c1["fallbacks"] == c0["fallbacks"], "re-ran solo in silence"
+        assert eng._backend_lost_at is None
+    else:
+        assert not any(errs), errs
+        ref = [_ref_engine(store).execute(q).to_pandas() for q in specs]
+        _assert_matches(res, ref, exact_cols=("units", "n"))
+        assert c1["fallbacks"] - c0["fallbacks"] == len(specs)
+        assert c1["last_error"] == "ValueError: lane builder bug"
