@@ -1,0 +1,14 @@
+"""Median over statements of the ``dispatch`` + ``demux`` phases: HOST
+clock around launch, wait and fetch — not device time."""
+from harness import stats
+
+LAYER = "dispatch and demux (_run_agg*, sharedscan._dispatch)"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "program_span"
+MOVES = "class_geomean_ms"
+
+
+def compute(run):
+    return stats.median(stats.phase_sum(r, ("dispatch", "demux"))
+                        for r in run["records"])
