@@ -1,0 +1,13 @@
+"""Median over dispatches of the ``dispatch.fetch`` span: device->host
+copy of the ready buffers and host reshaping (unpack)."""
+from harness import spans
+
+LAYER = "dispatch and demux (_run_agg*, sharedscan._dispatch)"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "program_span"
+MOVES = "class_geomean_ms"
+
+
+def compute(run):
+    return spans.median_per_span(run["records"], "dispatch.fetch")

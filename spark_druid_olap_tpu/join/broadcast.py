@@ -51,8 +51,10 @@ from spark_druid_olap_tpu.parallel.executor import (
 from spark_druid_olap_tpu.parallel.mesh import (
     SEGMENT_AXIS,
     mesh_size,
+    named_jit,
     shard_map,
 )
+from spark_druid_olap_tpu.utils import phases as PH
 from spark_druid_olap_tpu.utils.config import (
     GROUPBY_MATMUL_MAX_KEYS,
     JOIN_MAX_MATCHES,
@@ -534,9 +536,9 @@ def execute_broadcast(ctx, plan) -> Tuple[Dict[str, np.ndarray], dict]:
         smfn = shard_map(core_merged, mesh=eng.mesh,
                          in_specs=(P(SEGMENT_AXIS, None), P()),
                          out_specs=P(), check_vma=False)
-        prog = jax.jit(smfn)
+        prog = named_jit("sdot_join_broadcast", smfn)
     else:
-        prog = jax.jit(core)
+        prog = named_jit("sdot_join_broadcast", core)
 
     # ---- device residency + the wave loop -----------------------------------
     tree = {"table": table.device_tree(),
@@ -569,9 +571,12 @@ def execute_broadcast(ctx, plan) -> Tuple[Dict[str, np.ndarray], dict]:
                 arrays = eng._bind_arrays(ds, names, w, s_pad, n_dev > 1)
                 eng._tier_prefetch(ds, names, waves, i + 1)
                 eng._tick()
-                out = prog(arrays, tdev)
-                eng._tick(1)
-                combine_wave(acc, out, routes, n_keys)
+                with PH.phase("dispatch"):
+                    out = eng._wait(eng._launch(
+                        lambda a: prog(a, tdev), arrays))
+                    eng._tick(1)
+                    eng._fetch(lambda o: combine_wave(acc, o, routes,
+                                                      n_keys), out)
         except EngineFallback as e:
             raise JoinUnsupported(str(e)) from e
     finally:

@@ -33,6 +33,7 @@ def _murmur_fmix32(x):
     return x
 
 
+@jax.named_scope("sdot_hll_registers")
 def hll_registers(key, mask, values, n_keys: int, log2m: int = 11):
     """Per-group HLL register maxima.
 

@@ -63,6 +63,7 @@ def _mix(h):
     return h ^ (h >> 16)
 
 
+@jax.named_scope("sdot_kll_registers")
 def kll_registers(key, mask, values, times, n_keys: int,
                   lanes: int = K_LANES):
     """Per-group KLL registers: ``[n_keys, width(lanes)]`` int32.

@@ -266,6 +266,7 @@ def pallas_dense_groupby(key, n_keys: int, inputs: List,
 
     out = pl.pallas_call(
         _make_kernel(n_keys, specs, n_in),
+        name="sdot_dense_groupby",      # the HLO instruction's name
         grid=grid,
         in_specs=[blk] * (1 + n_in),
         out_specs=out_blk,

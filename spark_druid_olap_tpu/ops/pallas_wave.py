@@ -437,18 +437,22 @@ def build_wave_fn(ds, lanes, min_day: int, max_day: int, fplan, *,
             n *= int(d)
         n_pad = -(-max(n, 1) // tile) * tile
         ops = []
-        for name in names:
-            a = arrays[name].reshape(-1)
-            if name in bool_names:
-                a = a.astype(jnp.int8)
-            elif a.dtype.kind == "i" and a.dtype.itemsize < 4:
-                a = a.astype(jnp.int32)
-            if n_pad > n:
-                a = jnp.pad(a, (0, n_pad - n))   # pads row_valid=0 rows
-            ops.append(a.reshape(n_pad // LANES, LANES))
-        ops.append(jnp.asarray(init_col))        # step-0 identity column
+        with jax.named_scope("sdot_wave_prep"):
+            for name in names:
+                a = arrays[name].reshape(-1)
+                if name in bool_names:
+                    a = a.astype(jnp.int8)
+                elif a.dtype.kind == "i" and a.dtype.itemsize < 4:
+                    a = a.astype(jnp.int32)
+                if n_pad > n:
+                    a = jnp.pad(a, (0, n_pad - n))   # pads row_valid=0 rows
+                ops.append(a.reshape(n_pad // LANES, LANES))
+            ops.append(jnp.asarray(init_col))    # step-0 identity column
+        # the name is the HLO instruction's (``%sdot_wave.N``), which is
+        # what a TPU trace's ``XLA Ops`` line calls the kernel
         out = pl.pallas_call(
             kernel,
+            name="sdot_wave",
             grid=(n_pad // tile,),
             in_specs=[blk] * n_in
             + [pl.BlockSpec((out_rows, 1), lambda i: (0, 0))],

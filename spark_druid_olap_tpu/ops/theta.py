@@ -43,6 +43,7 @@ def _hash01(v, seed: int):
             * jnp.float32(1.0 / (1 << 24))) + jnp.float32(1e-7)
 
 
+@jax.named_scope("sdot_theta_registers")
 def theta_registers(key, mask, values, n_keys: int,
                     k: int = K_LANES):
     """Per-group k-mins registers: ``[n_keys, k]`` f32 lane minima."""

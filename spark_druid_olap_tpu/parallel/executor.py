@@ -22,7 +22,6 @@ knob).
 from __future__ import annotations
 
 import dataclasses
-import os as _os
 import time as _time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -58,7 +57,7 @@ from spark_druid_olap_tpu.ops.scan import (
 )
 from spark_druid_olap_tpu.parallel import cost as C
 from spark_druid_olap_tpu.parallel.mesh import (SEGMENT_AXIS, mesh_size,
-                                                 shard_map)
+                                                 named_jit, shard_map)
 from spark_druid_olap_tpu.planner import fusion as FU
 from spark_druid_olap_tpu.result import QueryResult
 from spark_druid_olap_tpu.segment.column import ColumnKind
@@ -88,30 +87,6 @@ from spark_druid_olap_tpu.utils.config import (
     SHAREDSCAN_FUSION_ENABLED,
     TOPN_DEVICE_MIN_KEYS,
 )
-
-
-_STAGE_TIMING = _os.environ.get("SDOT_STAGE_TIMING", "") == "1"
-# SDOT_PROFILE_DISPATCH=N: amortized true-device-time measurement — the
-# dispatch sites re-run the compiled program N extra times back-to-back
-# and record (sync-to-sync time)/N as last_stats['profile_device_ms'],
-# factoring out the host-side jitter a single dispatch+sync includes
-try:
-    _PROFILE_N = int(_os.environ.get("SDOT_PROFILE_DISPATCH", "0"))
-except ValueError:
-    _PROFILE_N = 0
-
-
-def set_profile_dispatch(n: Optional[int]) -> None:
-    """Runtime override of SDOT_PROFILE_DISPATCH (None restores the env
-    value) — bench.py profiles one rep per query this way so scan GB/s is
-    denominated in measured device time, not RTT-contaminated wall."""
-    global _PROFILE_N
-    if n is None:
-        try:
-            n = int(_os.environ.get("SDOT_PROFILE_DISPATCH", "0"))
-        except ValueError:
-            n = 0
-    _PROFILE_N = int(n)
 
 
 class EngineFallback(Exception):
@@ -791,62 +766,52 @@ class QueryEngine:
     @property
     def dispatch_counts(self):
         """Thread-local MONOTONE [program_dispatches, host_transfers,
-        wave_kernel_launches] counters (never reset by execute);
+        wave_kernel_launches, fetched_bytes] counters (never reset by
+        execute);
         statement layers diff them around a statement to report device
         round trips — each costs a launch plus a host sync, so this is
         the per-query round-trip budget made visible. Slot 2 counts
         hand-scheduled Pallas wave mega-kernel launches
         (parallel/sharedscan.py wave path) — a
         subset-annotation of slot 0, surfaced as ``kernel_launches`` in
-        statement stats."""
+        statement stats. Slot 3 sums the bytes the ``dispatch.fetch``
+        spans copied back (``fetch_bytes``)."""
         c = getattr(self._tls, "dcount", None)
-        if c is None or len(c) < 3:
-            c = self._tls.dcount = [0, 0, 0]
+        if c is None:
+            c = self._tls.dcount = [0, 0, 0, 0]
         return c
 
     def _tick(self, kind: int = 0, n: int = 1):
         self.dispatch_counts[kind] += n
 
-    def _profile_dispatch(self, fn, args):
-        """See _PROFILE_N: amortized device time of one compiled program.
+    def _launch(self, prog, args, fetch=True):
+        """``dispatch.launch``: the call of the compiled program, which
+        enqueues it and returns device buffers that are not ready yet.
+        With ``fetch`` the device->host copies of everything it returns
+        are enqueued behind it, so they overlap the compute and each
+        other and ``dispatch.fetch`` finds them under way; without, the
+        outputs stay on the device (a table a later program reads)."""
+        with PH.phase("dispatch.launch"):
+            out = prog(args)
+            if fetch:
+                for buf in jax.tree_util.tree_leaves(out):
+                    buf.copy_to_host_async()
+            return out
 
-        Syncs are data-dependent fetches, not ``block_until_ready``: a
-        fetch cannot return before the dispatch that produces it
-        retires."""
-        if _PROFILE_N <= 0:
-            return
+    @staticmethod
+    def _wait(bufs):
+        """``dispatch.wait``: until the device has written ``bufs`` — the
+        host's view of device time."""
+        with PH.phase("dispatch.wait"):
+            return jax.block_until_ready(bufs)
 
-        def sync(r):
-            # first NON-EMPTY leaf: a zero-length leaf (multihost
-            # zero-size per-chip buffer) would not block on the dispatch
-            # and charge ~0ms (ADVICE r4)
-            leaves = jax.tree_util.tree_leaves(r)
-            for leaf in leaves:
-                if getattr(leaf, "size", 0):
-                    np.asarray(jax.numpy.ravel(leaf)[:1])
-                    return
-            jax.block_until_ready(leaves)
-
-        sync(fn(args))
-        t0 = _time.perf_counter()
-        r = None
-        for _ in range(_PROFILE_N):
-            r = fn(args)
-        sync(r)
-        st = self.last_stats
-        st["profile_device_ms"] = round(
-            st.get("profile_device_ms", 0.0)
-            + (_time.perf_counter() - t0) / _PROFILE_N * 1000, 2)
-
-    def _stamp(self, key: str, t_start: float):
-        """SDOT_STAGE_TIMING=1 diagnostic: accumulate per-stage wall ms
-        into last_stats (plan/bind/device/decode splits for latency
-        work). Off by default — the device stamp forces a block at the
-        dispatch boundary, which costs overlap."""
-        if _STAGE_TIMING:
-            st = self.last_stats
-            st[key] = round(st.get(key, 0.0)
-                            + (_time.perf_counter() - t_start) * 1000, 2)
+    def _fetch(self, unpack, bufs):
+        """``dispatch.fetch``: device->host copy and host reshaping of
+        ready buffers; their bytes count as ``fetch_bytes``."""
+        with PH.phase("dispatch.fetch"):
+            self._tick(3, sum(int(getattr(b, "nbytes", 0))
+                              for b in jax.tree_util.tree_leaves(bufs)))
+            return unpack(bufs)
 
     # -- cancellation / timeout ----------------------------------------------
     def register_query(self, query_id: str) -> None:
@@ -1163,11 +1128,11 @@ class QueryEngine:
                 return QueryResult(names, data)
             return QueryResult.empty(names)
 
-        _tp = _time.perf_counter()
-        all_dim_plans, agg_plans, min_day, max_day, n_keys, names, routes = \
-            self._plan_agg(ds, seg_idx, dimensions, aggregations,
-                           granularity, filter_spec, intervals)
-        self._stamp("plan_ms", _tp)
+        with PH.phase("plan.engine"):
+            all_dim_plans, agg_plans, min_day, max_day, n_keys, names, \
+                routes = self._plan_agg(ds, seg_idx, dimensions,
+                                        aggregations, granularity,
+                                        filter_spec, intervals)
         cards = [p.card for p in all_dim_plans]
 
         if bool(self.config.get(SHAREDSCAN_FUSION_ENABLED)):
@@ -1259,10 +1224,12 @@ class QueryEngine:
             if t0 is not None:
                 self._stage_check(q, t0)
             self._tick()
-            _td = _time.perf_counter()
-            table = dict(progA(dev_arrays))
-            PH.add("dispatch", _time.perf_counter() - _td)
-            cnt = int(np.asarray(table.pop("__stats__"))[0])
+            with PH.phase("dispatch"):
+                # the table stays on the device; only its count travels
+                table = self._wait(dict(self._launch(progA, dev_arrays,
+                                                     fetch=False)))
+                cnt = int(self._fetch(np.asarray,
+                                      table.pop("__stats__"))[0])
             n_out = min(n_keys,
                         1 << max(6, (max(cnt, 1) - 1).bit_length()))
             # most groups pass: the [n_keys] top_k sort costs more than
@@ -1275,9 +1242,9 @@ class QueryEngine:
                 lambda: self._build_agg_gather_program(
                     agg_plans, routes, n_out, n_keys, sharded, full=full))
             self._tick()
-            _td = _time.perf_counter()
-            out = unpackB(gfn(table))
-            PH.add("dispatch", _time.perf_counter() - _td)
+            with PH.phase("dispatch"):
+                out = self._fetch(unpackB,
+                                  self._wait(self._launch(gfn, table)))
             if t0 is not None:
                 self._stage_check(q, t0)
             finals = _finals_from_out(out, routes, n_out, sketch_plans)
@@ -1302,30 +1269,20 @@ class QueryEngine:
                 # estimate is structurally off for it, don't re-pay the
                 # double execution on every warm run
             for cm in ((compact_m, None) if compact_m else (None,)):
-                _tc = _time.perf_counter()
                 prog_fn, unpack = self._cached_program(
                     ("agg", base_sig, topk, cm),
                     lambda cm=cm: self._build_agg_program(
                         ds, all_dim_plans, agg_plans, filter_spec,
                         intervals, min_day, max_day, n_keys, sharded,
                         routes, topk=topk, compact_m=cm))
-                self._stamp("compile_ms", _tc)
-                _tb = _time.perf_counter()
                 dev_arrays = self._bind_arrays(ds, names, seg_idx, s_pad,
                                                sharded)
-                self._stamp("bind_ms", _tb)
                 if t0 is not None:
                     self._stage_check(q, t0)  # pre-dispatch boundary
                 self._tick()
-                self._profile_dispatch(prog_fn, dev_arrays)
-                _td = _time.perf_counter()
-                bufs = prog_fn(dev_arrays)
-                if _STAGE_TIMING:
-                    jax.block_until_ready(bufs)
-                    self._stamp("device_ms", _td)
-                out = unpack(bufs)
-                self._stamp("fetch_ms", _td)
-                PH.add("dispatch", _time.perf_counter() - _td)
+                with PH.phase("dispatch"):
+                    out = self._fetch(unpack, self._wait(
+                        self._launch(prog_fn, dev_arrays)))
                 if t0 is not None:
                     self._stage_check(q, t0)  # post-device boundary
                 over = out.pop("__over__", None)
@@ -1373,59 +1330,60 @@ class QueryEngine:
                 self._compact_overflowed.add(("aggw", base_sig))
 
         # --- decode -----------------------------------------------------------
-        _tdec = _time.perf_counter()
-        rows = finals["__rows__"]
-        sel = np.nonzero(rows > 0)[0]
-        # a GLOBAL aggregate (no dims, no time bucketing) over zero matching
-        # rows yields ONE identity row — SQL semantics (and Druid's default
-        # timeseries behavior, minus its sum-is-0 quirk: we emit NULL sums)
-        global_empty = (not all_dim_plans and gran_kind == "all"
-                        and len(sel) == 0)
-        if global_empty:
-            sel = np.zeros(1, dtype=np.int64)
-        data: Dict[str, np.ndarray] = {}
-        columns: List[str] = []
-        if all_dim_plans:
-            key_ids = top_idx[sel] if top_idx is not None else sel
-            code_lists = G.unfuse_key(key_ids, cards)
-            for p, codes in zip(all_dim_plans, code_lists):
-                data[p.output_name] = p.decode(codes)
-                columns.append(p.output_name)
-        for p in agg_plans:
-            name = p.spec.name
-            if p.kind in ("hll", "theta", "kll"):
-                regs = finals[name]
-                if self.partial_sketches:
-                    # cluster historical mode: ship the raw [G, m]
-                    # register block; the broker merges registers
-                    # across shards (max/min/minsum) and finalizes the
-                    # estimate once (cluster/merge.py) — that is what
-                    # makes the distributed estimate EQUAL the
-                    # single-engine one, not merely close
-                    data[name] = np.asarray(regs)[sel]
+        with PH.phase("decode"):
+            rows = finals["__rows__"]
+            sel = np.nonzero(rows > 0)[0]
+            # a GLOBAL aggregate (no dims, no time bucketing) over zero
+            # matching rows yields ONE identity row — SQL semantics (and
+            # Druid's default timeseries behavior, minus its sum-is-0
+            # quirk: we emit NULL sums)
+            global_empty = (not all_dim_plans and gran_kind == "all"
+                            and len(sel) == 0)
+            if global_empty:
+                sel = np.zeros(1, dtype=np.int64)
+            data: Dict[str, np.ndarray] = {}
+            columns: List[str] = []
+            if all_dim_plans:
+                key_ids = top_idx[sel] if top_idx is not None else sel
+                code_lists = G.unfuse_key(key_ids, cards)
+                for p, codes in zip(all_dim_plans, code_lists):
+                    data[p.output_name] = p.decode(codes)
+                    columns.append(p.output_name)
+            for p in agg_plans:
+                name = p.spec.name
+                if p.kind in ("hll", "theta", "kll"):
+                    regs = finals[name]
+                    if self.partial_sketches:
+                        # cluster historical mode: ship the raw [G, m]
+                        # register block; the broker merges registers
+                        # across shards (max/min/minsum) and finalizes the
+                        # estimate once (cluster/merge.py) — that is what
+                        # makes the distributed estimate EQUAL the
+                        # single-engine one, not merely close
+                        data[name] = np.asarray(regs)[sel]
+                        columns.append(name)
+                        continue
+                    if p.kind == "kll":
+                        data[name] = KLL.estimate(
+                            regs, p.spec.fraction or 0.5)[sel]
+                        columns.append(name)
+                        continue
+                    est = (HLL.estimate(regs) if p.kind == "hll"
+                           else TH.estimate(regs))[sel]
+                    data[name] = np.round(est).astype(np.int64)
                     columns.append(name)
                     continue
-                if p.kind == "kll":
-                    data[name] = KLL.estimate(
-                        regs, p.spec.fraction or 0.5)[sel]
-                    columns.append(name)
-                    continue
-                est = (HLL.estimate(regs) if p.kind == "hll"
-                       else TH.estimate(regs))[sel]
-                data[name] = np.round(est).astype(np.int64)
+                r = routes[name]
+                v = finals[name][sel]
+                data[name] = _decode_agg_value(ds, p, r, v)
                 columns.append(name)
-                continue
-            r = routes[name]
-            v = finals[name][sel]
-            data[name] = _decode_agg_value(ds, p, r, v)
-            columns.append(name)
-        if global_empty:
-            data.update(_identity_row(
-                {p.spec.name: p.kind for p in agg_plans
-                 if p.kind in ("sum", "min", "max")}))
+            if global_empty:
+                data.update(_identity_row(
+                    {p.spec.name: p.kind for p in agg_plans
+                     if p.kind in ("sum", "min", "max")}))
 
-        data = self._agg_epilogue(data, columns, post_aggregations, having,
-                                  limit)
+            data = self._agg_epilogue(data, columns, post_aggregations, having,
+                                      limit)
 
         if topk and not isinstance(q, S.TopNQuerySpec):
             # exact-contract GroupBy: the candidate selection is
@@ -1439,7 +1397,6 @@ class QueryEngine:
                                      granularity, filter_spec, intervals,
                                      t0, no_topk=True)
 
-        self._stamp("decode_ms", _tdec)
         self.last_stats.update({
             "datasource": ds.name, "segments": int(n_seg_sel),
             "sharded": sharded, "groups": int(len(sel)),
@@ -1798,18 +1755,18 @@ class QueryEngine:
                     self._stage_check(q, t0)
                 if compact or exch:
                     self._tick()
-                    self._profile_dispatch(lambda a: dict(prog(a)), cur)
-                    _td = _time.perf_counter()
-                    table = dict(prog(cur))         # table stays on device
-                    if _STAGE_TIMING:
-                        jax.block_until_ready(table)
-                        self._stamp("device_ms", _td)
-                    # wave i+2's cold chunks load behind wave i's compute
-                    # and wave i+1's (synchronous) bind
-                    self._tier_prefetch(ds, names, wave_segs, i + 2)
-                    nxt = bind(i + 1) if i + 1 < len(wave_segs) else None
-                    stats = np.asarray(
-                        table.pop("__stats__")).reshape(-1, 2)
+                    with PH.phase("dispatch"):
+                        # table stays on device
+                        table = dict(self._launch(prog, cur, fetch=False))
+                        # wave i+2's cold chunks load behind wave i's
+                        # compute and wave i+1's (synchronous) bind
+                        self._tier_prefetch(ds, names, wave_segs, i + 2)
+                        nxt = bind(i + 1) if i + 1 < len(wave_segs) \
+                            else None
+                        self._wait(table)
+                        stats = self._fetch(
+                            np.asarray,
+                            table.pop("__stats__")).reshape(-1, 2)
                     cur = nxt
                     unresolved += int(stats[:, 0].sum())
                     if unresolved:
@@ -1830,11 +1787,9 @@ class QueryEngine:
                                 agg_plans, routes, metric, ascending,
                                 k_cand, k_sel, T))
                         self._tick()
-                        self._profile_dispatch(gfn, table)
-                        _tf = _time.perf_counter()
-                        raw = unpackB(gfn(table))
-                        self._stamp("fetch_ms", _tf)
-                        PH.add("dispatch", _time.perf_counter() - _tf)
+                        with PH.phase("dispatch"):
+                            raw = self._fetch(unpackB, self._wait(
+                                self._launch(gfn, table)))
                         partials.extend(
                             _hash_chip_partials(raw, routes, k_sel, n_dev))
                         continue
@@ -1846,31 +1801,22 @@ class QueryEngine:
                         lambda kg=kg: self._build_hash_gather_program(
                             agg_plans, routes, kg, T, sharded))
                     self._tick()
-                    self._profile_dispatch(gfn, table)
-                    _tf = _time.perf_counter()
-                    raw = unpackB(gfn(table))
-                    self._stamp("fetch_ms", _tf)
-                    PH.add("dispatch", _time.perf_counter() - _tf)
+                    with PH.phase("dispatch"):
+                        raw = self._fetch(unpackB, self._wait(
+                            self._launch(gfn, table)))
                     partials.extend(
                         _hash_chip_partials(raw, routes, kg, n_dev))
                 else:
                     prog_fn, unpack = prog
                     self._tick()
-                    self._profile_dispatch(prog_fn, cur)
-                    _td = _time.perf_counter()
-                    buf = prog_fn(cur)              # async dispatch
-                    if _STAGE_TIMING:
-                        jax.block_until_ready(buf)
-                        self._stamp("device_ms", _td)
-                    # double buffer: next wave's transfer overlaps compute
-                    self._tier_prefetch(ds, names, wave_segs, i + 2)
-                    nxt = bind(i + 1) if i + 1 < len(wave_segs) else None
-                    _tf = _time.perf_counter()
-                    raw = unpack(buf)
-                    self._stamp("fetch_ms", _tf)
-                    # overlapped prefetch/bind charged to their own
-                    # phases; the rest of this interval is device work
-                    PH.add("dispatch", _time.perf_counter() - _td)
+                    with PH.phase("dispatch"):
+                        buf = self._launch(prog_fn, cur)
+                        # double buffer: the next wave's transfer (a
+                        # ``bind`` span inside this one) overlaps compute
+                        self._tier_prefetch(ds, names, wave_segs, i + 2)
+                        nxt = bind(i + 1) if i + 1 < len(wave_segs) \
+                            else None
+                        raw = self._fetch(unpack, self._wait(buf))
                     cur = nxt
                     unresolved += int(raw.pop("__unres__").sum())
                     if unresolved:
@@ -1899,29 +1845,28 @@ class QueryEngine:
         if t0 is not None:
             self._stage_check(q, t0)
 
-        _tm = _time.perf_counter()
-        keys, merged = _merge_hash_partials(partials, routes)
-        self._stamp("merge_ms", _tm)
-        _tdec = _time.perf_counter()
+        with PH.phase("merge"):
+            keys, merged = _merge_hash_partials(partials, routes)
         data: Dict[str, np.ndarray] = {}
         columns: List[str] = []
-        khi, klo = H.unpack_key(keys)
-        part_vals = [khi, klo]
-        dim_codes: Dict[int, np.ndarray] = {}
-        for pi, idxs in enumerate(parts):
-            for i, c in zip(idxs, H.unfuse_part(part_vals[pi], cards, idxs)):
-                dim_codes[i] = c
-        for i, p in enumerate(dim_plans):
-            data[p.output_name] = p.decode(dim_codes[i])
-            columns.append(p.output_name)
-        for p in agg_plans:
-            name = p.spec.name
-            data[name] = _decode_agg_value(ds, p, routes[name], merged[name])
-            columns.append(name)
-
-        data = self._agg_epilogue(data, columns, post_aggregations, having,
-                                  limit)
-        self._stamp("decode_ms", _tdec)
+        with PH.phase("decode"):
+            khi, klo = H.unpack_key(keys)
+            part_vals = [khi, klo]
+            dim_codes: Dict[int, np.ndarray] = {}
+            for pi, idxs in enumerate(parts):
+                for i, c in zip(idxs,
+                                H.unfuse_part(part_vals[pi], cards, idxs)):
+                    dim_codes[i] = c
+            for i, p in enumerate(dim_plans):
+                data[p.output_name] = p.decode(dim_codes[i])
+                columns.append(p.output_name)
+            for p in agg_plans:
+                name = p.spec.name
+                data[name] = _decode_agg_value(ds, p, routes[name],
+                                               merged[name])
+                columns.append(name)
+            data = self._agg_epilogue(data, columns, post_aggregations,
+                                      having, limit)
 
         if topk and tk_scores is not None \
                 and not isinstance(q, S.TopNQuerySpec):
@@ -1989,6 +1934,7 @@ class QueryEngine:
                           if compact_m else (filter_spec, None))
         fuse_cse = bool(self.config.get(SHAREDSCAN_FUSION_ENABLED))
 
+        @jax.named_scope("sdot_hashed_groupby")
         def core(arrays):
             ctx = ScanContext(ds, arrays, min_day, max_day,
                               tz=self.config.get(TZ_ID))
@@ -2130,14 +2076,14 @@ class QueryEngine:
         ordered, _ = MH.layout_segments(assignment, seg_idx, n_hosts, dph)
         return ordered, len(ordered), len(ordered), 1
 
-    def _shard_wrap(self, fn, in_spec, out_spec, gather_only=None):
-        """``gather_only``: multi-host, dict-shaped outputs — all_gather
+    def _shard_wrap(self, name, fn, in_spec, out_spec, gather_only=None):
+        """``name`` is the program's (``named_jit``). ``gather_only``: multi-host, dict-shaped outputs — all_gather
         (replicate for host fetch) ONLY these keys; the rest stay
         per-chip DEVICE-RESIDENT sharded arrays (the hashed tier's [T]
         slot tables, consumed by the gather dispatch without ever
         crossing hosts — VERDICT r4 item 3's transfer diet)."""
         if self.mesh is None:
-            return jax.jit(fn)
+            return named_jit(name, fn)
         if MH.is_multihost() and out_spec == P(SEGMENT_AXIS):
             inner = fn
             if gather_only is None:
@@ -2160,7 +2106,7 @@ class QueryEngine:
                 smfn = shard_map(
                     fn2, mesh=self.mesh, in_specs=(in_spec,),
                     out_specs=(P(), P(SEGMENT_AXIS)), check_vma=False)
-                jfn = jax.jit(smfn)
+                jfn = named_jit(name, smfn)
 
                 def wrapped(x):
                     g, rest = jfn(x)
@@ -2168,7 +2114,7 @@ class QueryEngine:
                 return wrapped
         smfn = shard_map(fn, mesh=self.mesh, in_specs=(in_spec,),
                              out_specs=out_spec, check_vma=False)
-        return jax.jit(smfn)
+        return named_jit(name, smfn)
 
     def _build_hash_program(self, ds, dim_plans, parts, agg_plans,
                             filter_spec, intervals, min_day, max_day, T,
@@ -2195,8 +2141,9 @@ class QueryEngine:
             return pack(out)
 
         if not sharded:
-            return jax.jit(run), unpack
-        return self._shard_wrap(run, P(SEGMENT_AXIS, None),
+            return named_jit("sdot_agg_hashed", run), unpack
+        return self._shard_wrap("sdot_agg_hashed", run,
+                                P(SEGMENT_AXIS, None),
                                 P(SEGMENT_AXIS)), unpack
 
     def _build_hash_table_program(self, ds, dim_plans, parts, agg_plans,
@@ -2219,8 +2166,9 @@ class QueryEngine:
             return out
 
         if not sharded:
-            return jax.jit(run)
-        return self._shard_wrap(run, P(SEGMENT_AXIS, None), P(SEGMENT_AXIS),
+            return named_jit("sdot_agg_hashed_table", run)
+        return self._shard_wrap("sdot_agg_hashed_table", run,
+                                P(SEGMENT_AXIS, None), P(SEGMENT_AXIS),
                                 gather_only=("__stats__",))
 
     def _plan_hash_topk_exchange(self, q, limit, having, agg_plans):
@@ -2366,7 +2314,7 @@ class QueryEngine:
             out_spec = P()
         smfn = shard_map(run, mesh=self.mesh, in_specs=(in_specs,),
                              out_specs=out_spec, check_vma=False)
-        return jax.jit(lambda table: smfn(table)), unpack
+        return named_jit("sdot_hashed_topk_exchange", smfn), unpack
 
     def _build_hash_gather_program(self, agg_plans, routes, k_gather, T,
                                    sharded):
@@ -2384,8 +2332,8 @@ class QueryEngine:
             return pack(_gather_rows(table, idx, T))
 
         if not sharded:
-            return jax.jit(run), unpack
-        return self._shard_wrap(run, P(SEGMENT_AXIS),
+            return named_jit("sdot_hashed_gather", run), unpack
+        return self._shard_wrap("sdot_hashed_gather", run, P(SEGMENT_AXIS),
                                 P(SEGMENT_AXIS)), unpack
 
     def _run_waves(self, q, ds, names, seg_idx, spw, sharded, prog_fn,
@@ -2413,16 +2361,14 @@ class QueryEngine:
             if t0 is not None:
                 self._stage_check(q, t0)   # per-wave boundary
             self._tick()
-            _td = _time.perf_counter()
-            bufs = prog_fn(cur)            # async dispatch
-            # wave i+2's cold chunks load behind wave i's compute and
-            # wave i+1's (synchronous) bind
-            self._tier_prefetch(ds, names, wave_segs, i + 2)
-            nxt = bind(wave_segs[i + 1]) if i + 1 < len(wave_segs) else None
-            out = unpack(bufs)             # blocks on the device round-trip
-            # the overlapped prefetch/bind above charge to their own
-            # phases; what's left of this interval is device round-trip
-            PH.add("dispatch", _time.perf_counter() - _td)
+            with PH.phase("dispatch"):
+                bufs = self._launch(prog_fn, cur)       # async dispatch
+                # wave i+2's cold chunks load behind wave i's compute and
+                # wave i+1's (synchronous) bind, a ``bind`` span in here
+                self._tier_prefetch(ds, names, wave_segs, i + 2)
+                nxt = bind(wave_segs[i + 1]) \
+                    if i + 1 < len(wave_segs) else None
+                out = self._fetch(unpack, self._wait(bufs))
             over = out.pop("__over__", None)
             if over is not None:
                 n_over = int(np.asarray(over).reshape(-1)[0])
@@ -2675,7 +2621,7 @@ class QueryEngine:
                         out["__over__"] = over
                 return pack(out)
 
-            fn = jax.jit(plain)
+            fn = named_jit("sdot_agg_dense", plain)
         else:
             mesh = self.mesh
 
@@ -2723,7 +2669,7 @@ class QueryEngine:
                                  in_specs=(P(SEGMENT_AXIS, None),),
                                  out_specs=out_specs,
                                  check_vma=False)
-            fn = jax.jit(lambda arrays: smfn(arrays))
+            fn = named_jit("sdot_agg_dense", smfn)
 
         return fn, unpack
 
@@ -2841,7 +2787,8 @@ class QueryEngine:
             return out
 
         if not sharded:
-            return jax.jit(lambda arrays: finish(core(arrays)))
+            return named_jit("sdot_agg_table",
+                             lambda arrays: finish(core(arrays)))
         mesh = self.mesh
 
         sketch_kinds = {p.spec.name: "hll" for p in hll_plans}
@@ -2860,7 +2807,7 @@ class QueryEngine:
         smfn = shard_map(sharded_core, mesh=mesh,
                              in_specs=(P(SEGMENT_AXIS, None),),
                              out_specs=out_specs, check_vma=False)
-        return jax.jit(lambda arrays: smfn(arrays))
+        return named_jit("sdot_agg_table", smfn)
 
     def _agg_out_specs(self, agg_plans, routes, with_stats=True):
         """Per-leaf shard specs of the post-merge finals dict: merged
@@ -2919,14 +2866,14 @@ class QueryEngine:
             return pack(g)
 
         if not sharded:
-            return jax.jit(gather), unpack
+            return named_jit("sdot_gather", gather), unpack
         # '__stats__' was already popped host-side after dispatch 1
         in_specs = self._agg_out_specs(agg_plans, routes, with_stats=False)
         in_specs["__hmask__"] = P()
         smfn = shard_map(gather, mesh=self.mesh, in_specs=(in_specs,),
                              out_specs=(P(), P(SEGMENT_AXIS)),
                              check_vma=False)
-        return jax.jit(lambda table: smfn(table)), unpack
+        return named_jit("sdot_gather", smfn), unpack
 
     def _agg_meta_packers(self, agg_plans, routes, n_out, with_idx,
                           with_score=False, with_over=False):
@@ -2982,11 +2929,7 @@ class QueryEngine:
                 pack_group(out, perchip_meta)
 
         def unpack(bufs) -> Dict[str, np.ndarray]:
-            for b in bufs:
-                try:       # overlap the two device->host round trips
-                    b.copy_to_host_async()
-                except Exception:  # noqa: BLE001 — plain np inputs in tests
-                    pass
+            # both copies are under way since ``_launch`` enqueued them
             mflat = np.asarray(bufs[0])
             uflat = np.asarray(bufs[1])
             out = {}
@@ -3247,7 +3190,7 @@ class QueryEngine:
             with self._compile_lock:
                 prog = self._programs.get(sig)
                 if prog is None:
-                    prog = jax.jit(core)
+                    prog = named_jit("sdot_select", core)
                     self._programs[sig] = prog
         try:
             # cached device bindings: a repeated (dashboard/paging) select
@@ -3255,7 +3198,9 @@ class QueryEngine:
             # re-uploading the filter columns every call
             arrays = self._bind_arrays(ds, names, seg_idx, s_pad, False)
             self._tick()
-            words = np.asarray(prog(arrays))
+            with PH.phase("dispatch"):
+                words = self._fetch(np.asarray, self._wait(
+                    self._launch(prog, arrays)))
         except (EngineFallback, EC.Unsupported):
             return None
         shifts = np.arange(32, dtype=np.uint32)

@@ -33,6 +33,16 @@ def make_mesh(n_devices: Optional[int] = None,
     return Mesh(np.array(devices), (SEGMENT_AXIS,))
 
 
+def named_jit(name: str, fn):
+    """``jax.jit`` of ``fn`` under a name that says the tier: a
+    profiler trace's ``XLA Modules`` line and the compile cache's file
+    names read ``jit_<name>``, not ``jit__lambda_``."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program)
+
+
 def segment_sharding(mesh: Mesh) -> NamedSharding:
     """[S, R] arrays shard along the segment axis."""
     return NamedSharding(mesh, P(SEGMENT_AXIS, None))

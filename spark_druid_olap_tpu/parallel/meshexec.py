@@ -56,7 +56,8 @@ from spark_druid_olap_tpu.ops import theta as TH
 from spark_druid_olap_tpu.parallel import cost as C
 from spark_druid_olap_tpu.parallel import mesh as M
 from spark_druid_olap_tpu.parallel import multihost as MH
-from spark_druid_olap_tpu.parallel.mesh import SEGMENT_AXIS, shard_map
+from spark_druid_olap_tpu.parallel.mesh import (SEGMENT_AXIS, named_jit,
+                                                 shard_map)
 from spark_druid_olap_tpu.utils.config import (
     COST_MODEL_ENABLED,
     HLL_LOG2M,
@@ -202,7 +203,7 @@ def build_sharded_program(eng, lane_outs_fn: Callable, lanes,
         in_specs=(P(SEGMENT_AXIS, None),),
         out_specs=tuple((P(), P(SEGMENT_AXIS)) for _ in lanes),
         check_vma=False)
-    return jax.jit(lambda arrays: smfn(arrays))
+    return named_jit("sdot_mesh_program", smfn)
 
 
 # -- device-resident partial-buffer ledger ------------------------------------

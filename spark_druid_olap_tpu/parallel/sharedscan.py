@@ -101,7 +101,8 @@ class _Member:
 
 
 class _Group:
-    __slots__ = ("gid", "ds_name", "members", "state", "close_ev")
+    __slots__ = ("gid", "ds_name", "members", "state", "close_ev",
+                 "closed_ns")
 
     def __init__(self, gid: int, ds_name: str):
         self.gid = gid
@@ -109,6 +110,9 @@ class _Group:
         self.members: List[_Member] = []
         self.state = "open"          # open -> closing -> closed
         self.close_ev = threading.Event()
+        # perf_counter_ns at which the leader left its hold: where every
+        # member's coalesce.hold span ends and a follower's ride begins
+        self.closed_ns = None
 
 
 class _LanePlan:
@@ -218,6 +222,7 @@ class SharedScanCoalescer:
                        float(eng.config.get(WLM_BATCH_WINDOW_MS)) / 1000.0)
         maxq = max(1, int(eng.config.get(SHAREDSCAN_MAX_QUERIES)))
         tok = getattr(eng._tls, "inflight_tok", None)
+        joined_ns = _time.perf_counter_ns()
         with self._lock:
             g = self._groups.get(q.datasource)
             if g is not None and g.state == "open" and len(g.members) < maxq:
@@ -234,7 +239,9 @@ class SharedScanCoalescer:
                 self._groups[q.datasource] = g
 
         if m.leader:
-            self._hold_window(g, m, window_s)
+            with PH.phase("coalesce.hold"):
+                self._hold_window(g, m, window_s)
+                g.closed_ns = _time.perf_counter_ns()
             with self._lock:
                 g.state = "closed"
                 if self._groups.get(q.datasource) is g:
@@ -246,6 +253,12 @@ class SharedScanCoalescer:
                 # honors the follower's OWN cancel/timeout while parked;
                 # a late delivery into an abandoned slot is harmless
                 eng._stage_check(q, t0)
+            # parked through both: the spans are cut at the group's close
+            # after the fact (add() writes no profiler annotation)
+            woke_ns = _time.perf_counter_ns()
+            closed_ns = min(max(g.closed_ns or woke_ns, joined_ns), woke_ns)
+            PH.add("coalesce.hold", (closed_ns - joined_ns) / 1e9, closed_ns)
+            PH.add("coalesce.ride", (woke_ns - closed_ns) / 1e9, woke_ns)
 
         out = m.outcome
         if out is _FALLBACK:
@@ -323,107 +336,108 @@ class SharedScanCoalescer:
         demultiplex. Members that cannot ride stay at _FALLBACK."""
         from spark_druid_olap_tpu.parallel import executor as X
         eng = self.engine
-        ds_name = live[0].q.datasource
-        try:
-            ds = eng.store.get(ds_name)
-        except Exception:  # noqa: BLE001 — solo path reports the real error
-            return
-        if getattr(ds, "is_partial", False) or ds.num_rows == 0:
-            return
-
-        shaped = []
-        for m in live:
-            lp = self._shape_member(eng, ds, m.q)
-            if lp is not None:
-                shaped.append((m, lp))
-        if len(shaped) < 2:
-            return
-
-        seg_u = np.unique(np.concatenate([lp.seg for _, lp in shaped]))
-        mins, maxs = ds.segment_time_bounds()
-        min_day = int(mins[seg_u].min() // T.MILLIS_PER_DAY)
-        max_day = int(maxs[seg_u].max() // T.MILLIS_PER_DAY)
-
-        planned = []
-        for m, lp in shaped:
-            if self._plan_lane(eng, ds, lp, min_day, max_day):
-                planned.append((m, lp))
-        if len(planned) < 2:
-            return
-
-        # dedup identical specs into shared lanes, sorted by signature so
-        # the compile-cache key is order-independent across arrivals
-        by_sig: Dict[str, _LanePlan] = {}
-        for _, lp in planned:
-            by_sig.setdefault(lp.sig, lp)
-        sigs = tuple(sorted(by_sig))
-        lanes = [by_sig[s] for s in sigs]
-        lane_idx = {s: i for i, s in enumerate(sigs)}
-
-        union_cols = sorted(set().union(*[lp.needed for lp in lanes]))
-        union_time = any(lp.time_in_play for lp in lanes)
-        union_names = array_names(ds, union_cols, union_time)
-        seg_bytes = C.bytes_per_segment(ds, union_names)
-        # mesh tier (parallel/meshexec.py): static precheck; any
-        # disqualifying condition falls back to single-device with a
-        # named reason. The decision shapes the traced program AND the
-        # wave plan (per-device budgets multiply by n_dev)
-        dec = MX.decide(eng, ds, lanes, len(seg_u))
-        n_dev = dec.n_dev
-        spw, n_waves = C.plan_waves(
-            len(seg_u), n_dev, seg_bytes, C.wave_budget_bytes(eng.config),
-            eng.config, max(lp.n_keys for lp in lanes),
-            sum(len(lp.agg_plans) for lp in lanes),
-            io_budget=C.tier_io_budget(ds, eng.config))
-        s_pad = spw if n_waves > 1 else X._pad_segments(len(seg_u), n_dev)
-
-        # fusion planning is advisory: any error lowers the unfused way
-        # (routing tiers never change). Runs on EVERY fused execution —
-        # warm program-cache runs included — so the counters below are
-        # deterministic and CI-guardable without a chip.
-        fplan = None
-        if bool(eng.config.get(SHAREDSCAN_FUSION_ENABLED)):
+        with PH.phase("coalesce.plan"):
+            ds_name = live[0].q.datasource
             try:
-                fplan = FU.plan_lanes(
-                    [(lp.q.filter, lp.q.intervals,
-                      tuple(a.filter for a in lp.aggs)) for lp in lanes],
-                    per_lane_cols=[len(lp.needed) for lp in lanes],
-                    union_cols=len(union_cols),
-                    max_nodes=int(
-                        eng.config.get(SHAREDSCAN_FUSION_MAX_NODES)))
-            except Exception:  # noqa: BLE001 — fall back to unfused
-                fplan = None
-                with self._lock:
-                    self.fusion_fallbacks += 1
+                ds = eng.store.get(ds_name)
+            except Exception:  # noqa: BLE001 — solo path reports it
+                return
+            if getattr(ds, "is_partial", False) or ds.num_rows == 0:
+                return
 
-        wave_ok = bool(eng.config.get(PALLAS_WAVE_ENABLED)) \
-            and PW.wave_eligible(
-                lanes, int(eng.config.get(PALLAS_WAVE_MAX_LANES)))
+            shaped = []
+            for m in live:
+                lp = self._shape_member(eng, ds, m.q)
+                if lp is not None:
+                    shaped.append((m, lp))
+            if len(shaped) < 2:
+                return
 
-        sig = ("aggmulti", ds.name, id(ds), s_pad, ds.padded_rows,
-               min_day, max_day, tuple(union_names),
-               eng.config.get(TZ_ID),
-               eng.config.get(GROUPBY_MATMUL_MAX_KEYS),
-               eng.config.get(HLL_LOG2M),
-               eng.config.get(QUANTILE_LANES), jax.default_backend(),
-               bool(jax.config.jax_enable_x64), sigs,
-               # the fusion plan shapes the traced program: the token is
-               # a pure function of the sorted lane set (arrival-order
-               # independent), None when planning declined or failed
-               bool(eng.config.get(SHAREDSCAN_FUSION_ENABLED)),
-               int(eng.config.get(SHAREDSCAN_FUSION_MAX_NODES)),
-               fplan.token() if fplan is not None else None,
-               # wave mega-kernel routing: eligibility is re-derived on
-               # EVERY fused execution from plan metadata + env + config,
-               # so a config flip or backend change re-keys the program
-               wave_ok,
-               bool(eng.config.get(PALLAS_WAVE_ENABLED)),
-               int(eng.config.get(PALLAS_WAVE_TILE_BYTES)),
-               int(eng.config.get(PALLAS_WAVE_MAX_LANES)),
-               # mesh decision re-derived on EVERY fused execution (a
-               # sdot.mesh.* flip, device-count change, or cost-model
-               # swing re-keys the program — sdlint K1)
-               dec.sig_fields())
+            seg_u = np.unique(np.concatenate([lp.seg for _, lp in shaped]))
+            mins, maxs = ds.segment_time_bounds()
+            min_day = int(mins[seg_u].min() // T.MILLIS_PER_DAY)
+            max_day = int(maxs[seg_u].max() // T.MILLIS_PER_DAY)
+
+            planned = []
+            for m, lp in shaped:
+                if self._plan_lane(eng, ds, lp, min_day, max_day):
+                    planned.append((m, lp))
+            if len(planned) < 2:
+                return
+
+            # dedup identical specs into shared lanes, sorted by signature so
+            # the compile-cache key is order-independent across arrivals
+            by_sig: Dict[str, _LanePlan] = {}
+            for _, lp in planned:
+                by_sig.setdefault(lp.sig, lp)
+            sigs = tuple(sorted(by_sig))
+            lanes = [by_sig[s] for s in sigs]
+            lane_idx = {s: i for i, s in enumerate(sigs)}
+
+            union_cols = sorted(set().union(*[lp.needed for lp in lanes]))
+            union_time = any(lp.time_in_play for lp in lanes)
+            union_names = array_names(ds, union_cols, union_time)
+            seg_bytes = C.bytes_per_segment(ds, union_names)
+            # mesh tier (parallel/meshexec.py): static precheck; any
+            # disqualifying condition falls back to single-device with a
+            # named reason. The decision shapes the traced program AND the
+            # wave plan (per-device budgets multiply by n_dev)
+            dec = MX.decide(eng, ds, lanes, len(seg_u))
+            n_dev = dec.n_dev
+            spw, n_waves = C.plan_waves(
+                len(seg_u), n_dev, seg_bytes, C.wave_budget_bytes(eng.config),
+                eng.config, max(lp.n_keys for lp in lanes),
+                sum(len(lp.agg_plans) for lp in lanes),
+                io_budget=C.tier_io_budget(ds, eng.config))
+            s_pad = spw if n_waves > 1 else X._pad_segments(len(seg_u), n_dev)
+
+            # fusion planning is advisory: any error lowers the unfused way
+            # (routing tiers never change). Runs on EVERY fused execution —
+            # warm program-cache runs included — so the counters below are
+            # deterministic and CI-guardable without a chip.
+            fplan = None
+            if bool(eng.config.get(SHAREDSCAN_FUSION_ENABLED)):
+                try:
+                    fplan = FU.plan_lanes(
+                        [(lp.q.filter, lp.q.intervals,
+                          tuple(a.filter for a in lp.aggs)) for lp in lanes],
+                        per_lane_cols=[len(lp.needed) for lp in lanes],
+                        union_cols=len(union_cols),
+                        max_nodes=int(
+                            eng.config.get(SHAREDSCAN_FUSION_MAX_NODES)))
+                except Exception:  # noqa: BLE001 — fall back to unfused
+                    fplan = None
+                    with self._lock:
+                        self.fusion_fallbacks += 1
+
+            wave_ok = bool(eng.config.get(PALLAS_WAVE_ENABLED)) \
+                and PW.wave_eligible(
+                    lanes, int(eng.config.get(PALLAS_WAVE_MAX_LANES)))
+
+            sig = ("aggmulti", ds.name, id(ds), s_pad, ds.padded_rows,
+                   min_day, max_day, tuple(union_names),
+                   eng.config.get(TZ_ID),
+                   eng.config.get(GROUPBY_MATMUL_MAX_KEYS),
+                   eng.config.get(HLL_LOG2M),
+                   eng.config.get(QUANTILE_LANES), jax.default_backend(),
+                   bool(jax.config.jax_enable_x64), sigs,
+                   # the fusion plan shapes the traced program: the token is
+                   # a pure function of the sorted lane set (arrival-order
+                   # independent), None when planning declined or failed
+                   bool(eng.config.get(SHAREDSCAN_FUSION_ENABLED)),
+                   int(eng.config.get(SHAREDSCAN_FUSION_MAX_NODES)),
+                   fplan.token() if fplan is not None else None,
+                   # wave mega-kernel routing: eligibility is re-derived on
+                   # EVERY fused execution from plan metadata + env + config,
+                   # so a config flip or backend change re-keys the program
+                   wave_ok,
+                   bool(eng.config.get(PALLAS_WAVE_ENABLED)),
+                   int(eng.config.get(PALLAS_WAVE_TILE_BYTES)),
+                   int(eng.config.get(PALLAS_WAVE_MAX_LANES)),
+                   # mesh decision re-derived on EVERY fused execution (a
+                   # sdot.mesh.* flip, device-count change, or cost-model
+                   # swing re-keys the program — sdlint K1)
+                   dec.sig_fields())
 
         def _build():
             """Wave first (one pallas launch per wave), jaxpr-fused on
@@ -704,7 +718,7 @@ class SharedScanCoalescer:
             def fused(arrays):
                 return tuple(pack(o) for (pack, _), o
                              in zip(packers, lane_outs(arrays)))
-            fn = jax.jit(fused)
+            fn = M.named_jit("sdot_fused_program", fused)
         return fn, [u for _, u in packers]
 
     def _wave_program_fn(self, ds, lanes: List[_LanePlan],
@@ -743,7 +757,7 @@ class SharedScanCoalescer:
                 outs = wave_fn(arrays)
                 return tuple(pack(o)
                              for (pack, _), o in zip(packers, outs))
-            fn = jax.jit(fused)
+            fn = M.named_jit("sdot_wave_program", fused)
         shapes = {k: jax.ShapeDtypeStruct(
             (s_pad, ds.padded_rows),
             jnp.zeros((), dtype=array_dtype(ds, k)).dtype,
@@ -795,16 +809,21 @@ class SharedScanCoalescer:
                    if p.kind in ("hll", "theta", "kll")]
                   for lp in lanes]
         payload = MX.merged_payload_bytes(eng, lanes) * n_dev
+
+        def lane_finals(bufs):
+            return [X._finals_from_out(unpacks[i](bufs[i]), lp.routes,
+                                       lp.n_keys, sketch[i])
+                    for i, lp in enumerate(lanes)]
+
         if n_waves == 1:
             dev = eng._bind_arrays(ds, union_names, seg_u, s_pad, sharded)
             eng._stage_check(leader.q, leader.t0)
             eng._tick()
             tok = MX.LEDGER.acquire_partials(payload)
             try:
-                bufs = prog_fn(dev)
-                return [X._finals_from_out(unpacks[i](bufs[i]), lp.routes,
-                                           lp.n_keys, sketch[i])
-                        for i, lp in enumerate(lanes)]
+                with PH.phase("dispatch"):
+                    bufs = eng._wait(eng._launch(prog_fn, dev))
+                    return eng._fetch(lane_finals, bufs)
             finally:
                 MX.LEDGER.release_partials(tok)
         seg_rows = None
@@ -832,22 +851,22 @@ class SharedScanCoalescer:
                 for i in range(len(wave_segs)):
                     eng._stage_check(leader.q, leader.t0)
                     eng._tick()
-                    _td = _time.perf_counter()
-                    bufs = prog_fn(cur)            # async dispatch
-                    eng._tier_prefetch(ds, union_names, wave_segs, i + 2)
-                    nxt = eng._bind_wave(ds, union_names, wave_segs[i + 1],
-                                         spw, sharding, False) \
-                        if i + 1 < len(wave_segs) else None
-                    for li, lp in enumerate(lanes):
-                        f = X._finals_from_out(unpacks[li](bufs[li]),
-                                               lp.routes, lp.n_keys,
-                                               sketch[li])
-                        finals[li] = f if finals[li] is None \
-                            else X._merge_wave_finals(finals[li], f,
-                                                      lp.routes, sketch[li])
-                    # leader-thread attribution: overlapped prefetch/bind
-                    # charge to their own phases inside this interval
-                    PH.add("dispatch", _time.perf_counter() - _td)
+                    # leader-thread attribution; the overlapped
+                    # prefetch/bind are spans of their own inside this one
+                    with PH.phase("dispatch"):
+                        bufs = eng._launch(prog_fn, cur)    # async dispatch
+                        eng._tier_prefetch(ds, union_names, wave_segs,
+                                           i + 2)
+                        nxt = eng._bind_wave(ds, union_names,
+                                             wave_segs[i + 1], spw,
+                                             sharding, False) \
+                            if i + 1 < len(wave_segs) else None
+                        wave = eng._fetch(lane_finals, eng._wait(bufs))
+                        for li, lp in enumerate(lanes):
+                            finals[li] = wave[li] if finals[li] is None \
+                                else X._merge_wave_finals(
+                                    finals[li], wave[li], lp.routes,
+                                    sketch[li])
                     cur = nxt
             finally:
                 MX.LEDGER.release_partials(tok)

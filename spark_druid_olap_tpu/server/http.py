@@ -43,6 +43,8 @@ from urllib.parse import parse_qs, urlparse
 import numpy as np
 import pandas as pd
 
+from spark_druid_olap_tpu.utils import phases as PH
+
 
 def _df_to_json_rows(df: pd.DataFrame) -> bytes:
     # native C++ row encoder (GIL-released) when available/eligible
@@ -390,50 +392,63 @@ class SqlServer:
         h.end_headers()
         h.wfile.write(payload)
 
-    def _handle_post(self, h):
-        url = urlparse(h.path)
-        if url.path == "/sql":
+    def _handle_sql(self, h, root):
+        """``POST /sql``. ``root`` is the statement's ``http.request``
+        span (None with the phase profiler off): the session's spans
+        become its children and write the history record, which keeps
+        the span list by reference, so ``http.encode`` and ``http.write``
+        complete that record after it was written."""
+        with PH.phase("http.read"):
             req = self._read_json(h)
-            sql = req.get("sql")
-            if not sql:
-                h._send(400, b'{"error": "missing \'sql\'"}')
-                return
-            fmt = req.get("format", "json")
-            # the client supplies (or we mint) a query id; supplying one is
-            # what makes POST /sql/cancel reachable mid-flight (≈ Druid's
-            # client-set queryId in QuerySpecContext). Restricted charset:
-            # the id is echoed into the JSON envelope and a response header
-            qid = str(req.get("queryId") or uuid.uuid4().hex)
-            import re as _re
-            if not _re.fullmatch(r"[A-Za-z0-9_.:\-]{1,128}", qid):
-                h._send(400, b'{"error": "invalid queryId"}')
-                return
-            from spark_druid_olap_tpu.sql.lexer import SqlSyntaxError
-            from spark_druid_olap_tpu.parallel.executor import (
-                QueryCancelled, QueryTimeout)
-            from spark_druid_olap_tpu.wlm.lanes import AdmissionRejected
-            lane, tenant, prio = self._wlm_request(h, req)
-            try:
-                r = self.ctx.sql(sql, query_id=qid, lane=lane,
-                                 tenant=tenant, priority=prio)
-            except SqlSyntaxError as e:
-                h._error(400, e)
-                return
-            except KeyError as e:
-                h._error(404, e)
-                return
-            except AdmissionRejected as e:
-                self._send_shed(h, e, qid)
-                return
-            except (QueryCancelled, QueryTimeout) as e:
-                body = json.dumps({"error": type(e).__name__,
-                                   "message": str(e),
-                                   "queryId": qid}).encode()
-                h._send(499 if isinstance(e, QueryCancelled) else 504, body)
-                return
+        sql = req.get("sql")
+        if not sql:
+            h._send(400, b'{"error": "missing \'sql\'"}')
+            return
+        fmt = req.get("format", "json")
+        # the client supplies (or we mint) a query id; supplying one is
+        # what makes POST /sql/cancel reachable mid-flight (≈ Druid's
+        # client-set queryId in QuerySpecContext). Restricted charset:
+        # the id is echoed into the JSON envelope and a response header
+        qid = str(req.get("queryId") or uuid.uuid4().hex)
+        import re as _re
+        if not _re.fullmatch(r"[A-Za-z0-9_.:\-]{1,128}", qid):
+            h._send(400, b'{"error": "invalid queryId"}')
+            return
+        if root is not None:
+            root.qid = qid
+        from spark_druid_olap_tpu.sql.lexer import SqlSyntaxError
+        from spark_druid_olap_tpu.parallel.executor import (
+            QueryCancelled, QueryTimeout)
+        from spark_druid_olap_tpu.wlm.lanes import AdmissionRejected
+        lane, tenant, prio = self._wlm_request(h, req)
+        try:
+            r = self.ctx.sql(sql, query_id=qid, lane=lane,
+                             tenant=tenant, priority=prio)
+        except SqlSyntaxError as e:
+            h._error(400, e)
+            return
+        except KeyError as e:
+            h._error(404, e)
+            return
+        except AdmissionRejected as e:
+            self._send_shed(h, e, qid)
+            return
+        except (QueryCancelled, QueryTimeout) as e:
+            body = json.dumps({"error": type(e).__name__,
+                               "message": str(e),
+                               "queryId": qid}).encode()
+            h._send(499 if isinstance(e, QueryCancelled) else 504, body)
+            return
+        with PH.phase("http.encode"):
             df = r.to_pandas()
             if fmt == "arrow":
                 body = _df_to_arrow(df)   # serialize BEFORE the status line
+            else:
+                # splice the id into the JSON envelope
+                body = _df_to_json_rows(df)[:-1] \
+                    + b', "queryId": "%s"}' % qid.encode()
+        with PH.phase("http.write"):
+            if fmt == "arrow":
                 h.send_response(200)
                 h.send_header("Content-Type",
                               "application/vnd.apache.arrow.stream")
@@ -442,10 +457,18 @@ class SqlServer:
                 h.end_headers()
                 h.wfile.write(body)
             else:
-                body = _df_to_json_rows(df)
-                # splice the id into the JSON envelope
-                body = body[:-1] + b', "queryId": "%s"}' % qid.encode()
                 h._send(200, body)
+
+    def _handle_post(self, h):
+        url = urlparse(h.path)
+        if url.path == "/sql":
+            from spark_druid_olap_tpu.utils.config import PHASES_ENABLED
+            root = PH.open_root("http.request") \
+                if self.ctx.config.get(PHASES_ENABLED) else None
+            try:
+                self._handle_sql(h, root)
+            finally:
+                PH.close_root(root)
             return
         if url.path == "/query":
             req = self._read_json(h)

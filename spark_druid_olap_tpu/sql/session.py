@@ -401,7 +401,12 @@ def _run_select_tz(ctx, stmt, sql: str) -> QueryResult:
                                                    PLAN_MEMO_ENTRIES)
     # nested entries (union branches, window base statements) get None
     # back and merge their phases into the outer statement's accumulator
-    ph_tok = PH.begin(bool(ctx.config.get(PHASES_ENABLED)))
+    ph_tok = PH.begin(bool(ctx.config.get(PHASES_ENABLED)),
+                      qid=getattr(host_exec.ctx_tls(ctx), "query_id", None))
+    # total_ms counts from where the accumulator does (a stashed parse
+    # and the memo lookup included), so it covers every phase; a nested
+    # entry counts from here
+    t0 = ph_tok.t0_ns / 1e9 if ph_tok is not None else _time.perf_counter()
     try:
         memo = None
         memo_hit = None
@@ -428,24 +433,17 @@ def _run_select_tz(ctx, stmt, sql: str) -> QueryResult:
                 # _UNSET): only deterministic outcomes memoize
                 memo.window = wp
         if wp is not None:
-            return _run_windowed(ctx, wp, sql, ph_tok)
-        return _run_select_planned(ctx, stmt, sql, ph_tok, memo, memo_hit)
+            return _run_windowed(ctx, wp, sql, ph_tok, t0)
+        return _run_select_planned(ctx, stmt, sql, ph_tok, memo, memo_hit,
+                                   t0)
     finally:
         PH.end(ph_tok)   # idempotent: normally closed at stats assembly
 
 
 def _run_select_planned(ctx, stmt, sql: str, ph_tok, memo,
-                        memo_hit) -> QueryResult:
-    t0 = _time.perf_counter()
+                        memo_hit, t0: float) -> QueryResult:
     dc0 = list(ctx.engine.dispatch_counts)
     sq0 = getattr(_subq_tls, "hits", 0)
-    _stage = __import__("os").environ.get("SDOT_STAGE_TIMING", "") == "1"
-    _marks = {}
-
-    def _mark(key, t_start):
-        if _stage:
-            _marks[key] = round(_marks.get(key, 0.0)
-                                + (_time.perf_counter() - t_start) * 1000, 2)
     offset = stmt.offset
     if offset:
         # strip the offset before planning: the engine/host paths see an
@@ -505,7 +503,6 @@ def _run_select_planned(ctx, stmt, sql: str, ph_tok, memo,
                     # composite/host tiers
                     raise PlanUnsupported(pq.reason)
             else:
-                _tr = _time.perf_counter()
                 with PH.phase("plan.rewrite"):
                     stmt2 = trace("merge_derived", stmt,
                                   merge_derived(ctx, stmt))
@@ -515,8 +512,6 @@ def _run_select_planned(ctx, stmt, sql: str, ph_tok, memo,
                                   inline_correlated_scalars(ctx, stmt2))
                     stmt2 = trace("inline_subqueries", stmt2,
                                   inline_subqueries(ctx, stmt2))
-                _mark("stmt_rewrite_ms", _tr)
-                _tb = _time.perf_counter()
                 try:
                     with PH.phase("plan.build"):
                         pq = B.build(ctx, stmt2)
@@ -527,14 +522,11 @@ def _run_select_planned(ctx, stmt, sql: str, ph_tok, memo,
                     if memo is not None:
                         memo.pq = neg
                     raise
-                _mark("stmt_build_ms", _tb)
                 if _pc_on:
                     host_exec.result_cache_put(_pcache, _pkey, pq)
                 if memo is not None:
                     memo.pq = pq
-        _te = _time.perf_counter()
         df = execute_planned(ctx, pq)
-        _mark("stmt_exec_ms", _te)
         mode = "engine"
         rollup_status = f"rollup:{pq.rollup}" if pq.rollup else "base"
     except (PlanUnsupported, EngineFallback) as e:
@@ -630,8 +622,9 @@ def _run_select_planned(ctx, stmt, sql: str, ph_tok, memo,
     # hand-scheduled Pallas wave mega-kernel launches (sharedscan wave
     # path) attributed to this statement's thread — a subset-annotation
     # of n_dispatch, 0 on the jaxpr path
-    stats["kernel_launches"] = (dc1[2] - dc0[2]
-                                if len(dc1) > 2 and len(dc0) > 2 else 0)
+    stats["kernel_launches"] = dc1[2] - dc0[2]
+    # bytes the statement's dispatch.fetch spans copied device -> host
+    stats["fetch_bytes"] = dc1[3] - dc0[3]
     # explicit provenance for LEGITIMATE zero-dispatch engine statements
     # (bench.py's zero_dispatch_engine guard exempts annotated ones and
     # flags the rest): a semantic result-cache hit, or a statement whose
@@ -646,10 +639,7 @@ def _run_select_planned(ctx, stmt, sql: str, ph_tok, memo,
         stats["plan_cached"] = True
     if memo_hit is not None:
         stats["plan_memo"] = {"hit": bool(memo_hit)}
-    phases = PH.end(ph_tok)
-    if phases is not None:
-        stats["phases"] = {k: round(v, 3) for k, v in phases.items()}
-    stats.update(_marks)
+    _close_phases(stats, ph_tok)
     ctx.history.record(stmt, stats, sql=sql)
     res = QueryResult(list(df.columns),
                       {c: df[c].to_numpy() for c in df.columns})
@@ -663,6 +653,21 @@ def _run_select_planned(ctx, stmt, sql: str, ph_tok, memo,
     return res
 
 
+def _close_phases(stats, ph_tok) -> None:
+    """Close the statement's accumulator into its record: the flat
+    ``phases`` and the span tree they are a view of. ``spans`` is the
+    live list — a root the server's handler opened gets its
+    ``http.encode`` / ``http.write`` after the record is written."""
+    phases = PH.end(ph_tok)
+    if phases is None:
+        return
+    stats["phases"] = {k: round(v, 3) for k, v in phases.items()}
+    stats["spans"] = ph_tok.stmt.spans
+    stats["t0_ns"] = ph_tok.stmt.t0_ns
+    if ph_tok.stmt.qid is not None:
+        stats["query_id"] = ph_tok.stmt.qid
+
+
 def _maybe_windows(ctx, stmt):
     """Strip ``OVER (...)`` calls BEFORE any planning (window/plan.py).
     Returns ``(base_stmt, WindowPlan)`` or None. Runs ahead of the plan
@@ -672,7 +677,7 @@ def _maybe_windows(ctx, stmt):
     return WPLAN.extract(ctx, stmt)
 
 
-def _run_windowed(ctx, wp, sql: str, ph_tok=None) -> QueryResult:
+def _run_windowed(ctx, wp, sql: str, ph_tok, t0: float) -> QueryResult:
     """Window post-pass: run the base statement through the normal
     tiers (engine pushdown / cluster scatter / composite / host), then
     compute the window columns on device over the merged result frame
@@ -684,7 +689,6 @@ def _run_windowed(ctx, wp, sql: str, ph_tok=None) -> QueryResult:
     statement's ``stats['phases']`` covers the whole pipeline."""
     from spark_druid_olap_tpu.window import exec as WEXEC
     base_stmt, plan = wp
-    t0 = _time.perf_counter()
     base = _run_select_tz(ctx, base_stmt, f"{sql} <window base>")
     _tw = _time.perf_counter()
     with PH.phase("epilogue"):
@@ -696,9 +700,7 @@ def _run_windowed(ctx, wp, sql: str, ph_tok=None) -> QueryResult:
                        "window_ms": round(
                            (_time.perf_counter() - _tw) * 1000, 2)}
     stats["total_ms"] = (_time.perf_counter() - t0) * 1000
-    phases = PH.end(ph_tok)
-    if phases is not None:
-        stats["phases"] = {k: round(v, 3) for k, v in phases.items()}
+    _close_phases(stats, ph_tok)
     ctx.history.record(base_stmt, stats, sql=sql)
     res = QueryResult(list(df.columns),
                       {c: df[c].to_numpy() for c in df.columns})
@@ -737,14 +739,23 @@ def execute_planned(ctx, pq: PlannedQuery) -> pd.DataFrame:
         r = ctx.engine.execute(q)
         if r.degraded is not None:
             degraded.append(r.degraded)
-        df = r.to_pandas()
-        if "__count__" in df.columns and "__count__" not in pq.output_columns:
-            df = df.drop(columns=["__count__"])
-        # null-fill dims missing from this grouping set
-        for d in pq.all_dims:
-            if d not in df.columns:
-                df[d] = None
-        frames.append(df)
+        with PH.phase("result"):
+            df = r.to_pandas()
+            if "__count__" in df.columns \
+                    and "__count__" not in pq.output_columns:
+                df = df.drop(columns=["__count__"])
+            # null-fill dims missing from this grouping set
+            for d in pq.all_dims:
+                if d not in df.columns:
+                    df[d] = None
+            frames.append(df)
+    with PH.phase("result"):
+        return _finish_planned(ctx, pq, frames, degraded)
+
+
+def _finish_planned(ctx, pq: PlannedQuery, frames, degraded) -> pd.DataFrame:
+    """The host finish over the specs' frames: concat, residual filter,
+    exact count-distinct phase 2, deferred order/limit, renames."""
     df = pd.concat(frames, ignore_index=True) if len(frames) > 1 else frames[0]
 
     if pq.residual is not None:

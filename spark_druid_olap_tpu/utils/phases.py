@@ -1,39 +1,61 @@
-"""Per-query host-path phase profiler.
+"""Per-statement span tree: the one mechanism that says where a
+statement's host time went.
 
-Every query pays a ~1.7 ms dispatch floor on the device; everything
-else is host work spread across parsing, a recognizer cascade, caches,
-binding and demux.  This module attributes that host time to *named
-phases* with two monotonic-clock reads per phase, cheap enough to stay
-always-on (< 1% of wall, enforced by tests/test_phases.py).
+Every statement pays well under a millisecond of scan on the device;
+everything else is host work spread across HTTP, parsing, a recognizer
+cascade, caches, the shared-scan coalescer, binding, launch, wait,
+fetch and decode.  This module attributes that time to *named spans*
+with two monotonic-clock reads and one list append per span, cheap
+enough to stay always-on (< 1% of wall, enforced by
+tests/test_phases.py).
 
 Usage::
 
-    tok = PH.begin()                 # open a per-query accumulator
+    root = PH.open_root("http.request", qid)   # server only
+    tok = PH.begin()                 # open the statement's accumulator
     with PH.phase("plan.build"):
         ...
-    PH.add("dispatch", seconds)      # hot loops: pre-measured interval
+    PH.add("tier.fault", seconds)    # pre-measured interval ending now
     phases = PH.end(tok)             # {"plan.build": ms, ...}
+    tok.stmt.spans, tok.stmt.t0_ns   # the tree, for the record
+    PH.close_root(root)
 
 Semantics:
 
-- The accumulator is thread-local.  ``begin()`` returns ``None`` when
-  an accumulator is already open (nested query execution, e.g. UNION
-  branches re-entering the select path) — inner phases then merge into
-  the outer accumulator and the inner ``end(None)`` is a no-op.
-- ``phase()``/``add()`` outside any open accumulator are no-ops, so
+- A statement is one tree of spans ``[name, start_us, dur_us, parent]``
+  on ``time.perf_counter_ns()``: ``start_us`` counts from the root's
+  start (``t0_ns``), ``parent`` is the index of the span that was open
+  on the same thread when this one began, the root is index 0 with
+  parent -1.  The root is ``http.request`` when the server's handler
+  opened it (``open_root``) and ``sql`` when ``begin()`` had to.  A span
+  still open has ``dur_us`` None; readers skip it.
+- ``stats["phases"]`` is the flat view ``{name: ms}`` of the spans that
+  are *direct children of the root* while the accumulator is open.  A
+  span nested in another (``dispatch.launch`` in ``dispatch``,
+  ``plan.star`` in ``plan.build``, ``tier.fault`` in ``bind``) and a
+  span outside ``begin()``..``end()`` (``http.*``) appear in the tree
+  only, so the phases of a statement never overlap and
+  ``total_ms - sum(phases)`` is never negative.
+- The state is thread-local.  ``begin()`` returns ``None`` when an
+  accumulator is already open (nested query execution, e.g. a window
+  statement re-entering the select path) — inner spans then land in the
+  outer statement and the inner ``end(None)`` is a no-op.
+- ``phase()``/``add()`` outside any open statement are no-ops, so
   background threads (tier prefetcher) and non-query entry points can
   share the instrumented call sites for free.
-- Phases are *inclusive*: a phase nested inside another counts in
-  both, so the per-query sum may exceed wall time.  Readers should
-  treat each entry as "time attributable to this stage", not as a
-  partition of the wall clock.
 - ``stash(name, seconds)`` records time measured *before* the
   accumulator could be opened (statement parse happens before the
   select path begins); the next ``begin()`` on the same thread folds
   the stash in.  ``clear_stash()`` drops leftovers so one statement's
   parse can never leak into the next.
+- Every ``phase()`` also enters a ``jax.profiler.TraceAnnotation``
+  named ``"sdot:" + name`` carrying ``qid`` and ``t0_ns`` (the span's
+  own ``perf_counter_ns`` start), so any profiler capture of the process
+  shows the same spans on the clock of the device lines; with no
+  capture running the annotation is a flag test.  ``add()`` learns of
+  its interval after the fact and writes no annotation.
 
-The ``PHASES`` registry below is the single source of truth for phase
+The ``PHASES`` registry below is the single source of truth for span
 names; sdlint cross-checks every ``PH.phase("...")``/``PH.add("...")``
 call site against it and against the docs/STATS.md phase table.
 """
@@ -41,10 +63,17 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, Optional
+from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # name -> one-line meaning (kept a pure literal: sdlint parses it)
 PHASES = {
+    "sql": "root of a statement run without the server (ctx.sql)",
+    "http.request": "root of a POST /sql: handler's first line to its last",
+    "http.read": "request body read + json.loads",
+    "http.encode": "result -> DataFrame -> JSON rows / Arrow bytes",
+    "http.write": "status line, headers and body written to the socket",
     "parse": "SQL text -> AST (memoized; counted when actually run)",
     "plan.memo": "planning-cascade memo lookup",
     "plan.window": "window-function extraction",
@@ -55,39 +84,110 @@ PHASES = {
     "plan.star": "star-join collapse over the FROM list",
     "plan.join": "general-join recognition",
     "plan.composite": "composite (host-assist) plan build",
+    "plan.engine": "engine-side aggregation plan (dims, routes, segments)",
     "wlm.admit": "workload-manager admission",
     "cache.lookup": "result-cache probe",
+    "coalesce.hold": "shared scan: joining a group -> the group's close",
+    "coalesce.ride": "shared scan follower: group close -> outcome delivered",
+    "coalesce.plan": "shared scan leader: lanes, fusion plan, program key",
     "compile": "program build + jit (per signature, first run only)",
     "tier.fault": "tiered-store faults on the demand path",
     "tier.decode": "encoded-chunk decode on the demand path",
     "bind": "host->device array binding",
     "dispatch": "device execution + result fetch",
+    "dispatch.launch": "enqueue: the compiled program + its outputs' D2H",
+    "dispatch.wait": "block_until_ready on what the launch returned",
+    "dispatch.fetch": "rest of the device->host copy + unpack on the host",
+    "merge": "hashed tier: cross-wave / cross-chip partial merge on host",
+    "decode": "solo finals -> QueryResult (dictionary decode, epilogue)",
     "demux": "shared-scan per-lane demux/decode",
+    "result": "engine results -> the statement's frame (host finish)",
     "epilogue": "window post-pass and result epilogue",
 }
 
 _tls = threading.local()
 
 
-def _acc() -> Optional[Dict[str, float]]:
-    return getattr(_tls, "acc", None)
+class _Stmt:
+    """One statement's span tree; lives with the history record."""
+
+    __slots__ = ("spans", "stack", "t0_ns", "qid", "acc", "note")
+
+    def __init__(self, name: str, qid: Optional[str], t0_ns: int) -> None:
+        self.spans: List[list] = [[name, 0.0, None, -1]]
+        self.stack = [0]
+        self.t0_ns = t0_ns
+        self.qid = qid
+        self.acc: Optional[_Acc] = None     # the open accumulator
+        self.note = TraceAnnotation("sdot:" + name, qid=qid or "",
+                                    t0_ns=t0_ns)
+        self.note.__enter__()
+
+    def close(self) -> None:
+        if self.spans[0][2] is None:
+            self.spans[0][2] = (time.perf_counter_ns() - self.t0_ns) / 1e3
+            self.note.__exit__(None, None, None)
+        if getattr(_tls, "st", None) is self:
+            _tls.st = None
 
 
-def begin(enabled: bool = True) -> Optional[Dict[str, float]]:
+class _Acc(dict):
+    """``{name: seconds}`` of one begin()..end(), the token of both."""
+
+    __slots__ = ("stmt", "owns_root", "t0_ns")
+
+
+def _acc() -> Optional[_Acc]:
+    st = getattr(_tls, "st", None)
+    return st.acc if st is not None else None
+
+
+def open_root(name: str, qid: Optional[str] = None) -> Optional[_Stmt]:
+    """Open a statement whose root span starts now (the server's
+    handler); None when this thread already has one."""
+    if getattr(_tls, "st", None) is not None:
+        return None
+    st = _tls.st = _Stmt(name, qid, time.perf_counter_ns())
+    return st
+
+
+def close_root(st: Optional[_Stmt]) -> None:
+    """Close the root opened by open_root(); ``close_root(None)`` and a
+    second close are no-ops."""
+    if st is not None:
+        st.close()
+
+
+def begin(enabled: bool = True, qid: Optional[str] = None) -> Optional[_Acc]:
     """Open a per-query accumulator; None if nested or disabled."""
     stash = getattr(_tls, "stash", None)
     _tls.stash = None
-    if not enabled or getattr(_tls, "acc", None) is not None:
+    if not enabled:
         return None
-    acc: Dict[str, float] = {}
+    st = getattr(_tls, "st", None)
+    if st is not None and st.acc is not None:
+        return None
+    acc = _Acc()
+    acc.owns_root = st is None
+    # where the statement's own clock starts: a stashed parse began
+    # before this call
+    acc.t0_ns = time.perf_counter_ns()
     if stash:
-        for k, v in stash.items():
-            acc[k] = acc.get(k, 0.0) + v
-    _tls.acc = acc
+        acc.t0_ns = min(acc.t0_ns, min(s for s, _ in stash.values()))
+    if st is None:
+        st = _tls.st = _Stmt("sql", qid, acc.t0_ns)
+    elif qid is not None:
+        st.qid = qid
+    acc.stmt = st
+    st.acc = acc
+    if stash:
+        for k, (start, dt) in stash.items():
+            acc[k] = acc.get(k, 0.0) + dt
+            st.spans.append([k, (start - st.t0_ns) / 1e3, dt * 1e6, 0])
     return acc
 
 
-def end(tok: Optional[Dict[str, float]]) -> Optional[Dict[str, float]]:
+def end(tok: Optional[_Acc]) -> Optional[Dict[str, float]]:
     """Close the accumulator opened by begin(); returns {name: ms}.
 
     Idempotent and nested-safe: ``end(None)`` is a no-op returning
@@ -95,42 +195,63 @@ def end(tok: Optional[Dict[str, float]]) -> Optional[Dict[str, float]]:
     """
     if tok is None:
         return None
-    if getattr(_tls, "acc", None) is tok:
-        _tls.acc = None
+    if tok.stmt.acc is tok:
+        tok.stmt.acc = None
+        if tok.owns_root:
+            tok.stmt.close()
     return {k: v * 1000.0 for k, v in tok.items()}
 
 
 class _Phase:
-    __slots__ = ("name", "acc", "t0")
+    __slots__ = ("name", "st", "row", "t0", "note")
 
     def __init__(self, name: str) -> None:
         self.name = name
-        self.acc = None
-        self.t0 = 0.0
+        self.st = None
 
     def __enter__(self) -> "_Phase":
-        self.acc = _acc()
-        if self.acc is not None:
-            self.t0 = time.perf_counter()
+        st = self.st = getattr(_tls, "st", None)
+        if st is not None:
+            self.t0 = t0 = time.perf_counter_ns()
+            self.row = [self.name, (t0 - st.t0_ns) / 1e3, None,
+                        st.stack[-1]]
+            st.stack.append(len(st.spans))
+            st.spans.append(self.row)
+            self.note = TraceAnnotation("sdot:" + self.name,
+                                        qid=st.qid or "", t0_ns=t0)
+            self.note.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
-        if self.acc is not None:
-            dt = time.perf_counter() - self.t0
-            self.acc[self.name] = self.acc.get(self.name, 0.0) + dt
-            self.acc = None
+        st = self.st
+        if st is not None:
+            self.note.__exit__(None, None, None)
+            dt = time.perf_counter_ns() - self.t0
+            self.row[2] = dt / 1e3
+            st.stack.pop()
+            if self.row[3] == 0 and st.acc is not None:
+                st.acc[self.name] = st.acc.get(self.name, 0.0) + dt / 1e9
+            self.st = None
 
 
 def phase(name: str) -> _Phase:
-    """Context manager timing one phase; no-op without an open acc."""
+    """Context manager timing one span; no-op without an open statement."""
     return _Phase(name)
 
 
-def add(name: str, seconds: float) -> None:
-    """Fold a pre-measured interval into the open accumulator."""
-    acc = _acc()
-    if acc is not None:
-        acc[name] = acc.get(name, 0.0) + seconds
+def add(name: str, seconds: float, end_ns: Optional[int] = None) -> None:
+    """Fold a pre-measured interval into the open statement; it ends at
+    ``end_ns`` (``perf_counter_ns``), now when None."""
+    st = getattr(_tls, "st", None)
+    if st is None:
+        return
+    if end_ns is None:
+        end_ns = time.perf_counter_ns()
+    parent = st.stack[-1]
+    st.spans.append([name, (end_ns - st.t0_ns) / 1e3 - seconds * 1e6,
+                     seconds * 1e6, parent])
+    if parent == 0 and st.acc is not None:
+        st.acc[name] = st.acc.get(name, 0.0) + seconds
 
 
 def stash(name: str, seconds: float) -> None:
@@ -139,7 +260,9 @@ def stash(name: str, seconds: float) -> None:
     if st is None:
         st = {}
         _tls.stash = st
-    st[name] = st.get(name, 0.0) + seconds
+    start, dt = st.get(name, (time.perf_counter_ns() - int(seconds * 1e9),
+                              0.0))
+    st[name] = (start, dt + seconds)
 
 
 def clear_stash() -> None:

@@ -86,10 +86,18 @@ def chip_mode(monkeypatch):
         cc.reset_cache()
 
 
-def _assert_kernel(compiled, n_kernels=1):
+def _assert_kernel(compiled, name, n_kernels=1):
+    """A Mosaic kernel, under the HLO instruction name a TPU trace's
+    ``XLA Ops`` line will show (``pl.pallas_call(..., name=...)``) —
+    not under the name of whichever Python function enclosed the call."""
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= n_kernels, \
         "no Mosaic kernel in the compiled program"
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert calls and all(ln.lstrip().startswith(("%" + name, "ROOT %" + name))
+                         for ln in calls), \
+        f"kernel not named {name}: {[ln.split(' = ')[0] for ln in calls]}"
 
 
 # -- dense small-K kernel (ops/pallas_groupby.py) -----------------------------
@@ -136,7 +144,8 @@ def test_dense_groupby_compiles_at_sf1(chip_mode, one_chip, n_keys, n_aggs):
         assert PG.eligible(n_keys, inputs, 64, n_rows=SF1_ROWS)
         return PG.pallas_dense_groupby(arrays["key"], n_keys, inputs)
 
-    _assert_kernel(jax.jit(fn).lower(shapes).compile())
+    _assert_kernel(jax.jit(fn).lower(shapes).compile(),
+                   "sdot_dense_groupby")
 
 
 # -- wave mega-kernel (ops/pallas_wave.py via parallel/sharedscan.py) ---------
@@ -198,7 +207,7 @@ def test_wave_program_compiles(chip_mode, one_chip, store, batch):
     interval-restricted lane (TPC-H q6's shape) Mosaic once refused with
     an ``i1`` relayout error (docs/KERNELS.md kernel contract)."""
     compiled, _ = _compile_wave(_wave_engine(store), batch(), one_chip)
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "sdot_wave")
 
 
 def _theta_wide_batch():
@@ -225,7 +234,7 @@ def test_wave_theta_stripe_and_widest_scratch_compile(chip_mode, one_chip,
                                                       store):
     eng = _wave_engine(store)
     compiled, info = _compile_wave(eng, _theta_wide_batch(), one_chip)
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "sdot_wave")
     assert info["theta_inkernel"] == 1, info
     assert PW.MAX_OUT_ROWS * 3 // 4 <= info["out_rows"] \
         <= PW.MAX_OUT_ROWS, info
@@ -248,6 +257,6 @@ def test_wave_program_compiles_under_shard_map(chip_mode, topo, store):
     compiled, _ = _compile_wave(
         eng, specs, NamedSharding(mesh, P(SEGMENT_AXIS, None)),
         mesh_sharded=True)
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "sdot_wave")
     assert "all-reduce" in compiled.as_text(), \
         "no interconnect merge in the program"

@@ -1,11 +1,12 @@
-"""Phase profiler + planning-cascade memo + decode-ahead tier tests.
+"""Span tree + planning-cascade memo + decode-ahead tier tests.
 
-Four layers:
+Five layers:
 
 1. **Profiler unit semantics** (utils/phases.py): begin/end exactness,
-   nested-begin merge, no-op outside an accumulator, stash folding, and
-   the always-on overhead micro-budget (< 1% of wall enforced as a
-   per-timing ceiling far below the ~1.7 ms dispatch floor).
+   nested-begin merge, no-op outside an accumulator, stash folding, the
+   span tree (parents, starts, durations, self time), the child-span
+   rule, and the always-on overhead micro-budget (< 1% of wall enforced
+   as a per-timing ceiling far below the ~1.7 ms dispatch floor).
 2. **Stats contract** — every executed statement carries
    ``stats["phases"]`` whose names all come from the PHASES registry,
    and the key disappears when ``sdot.phases.enabled`` is off.
@@ -14,13 +15,26 @@ Four layers:
    ``plan_memo == {"hit": True}``; any ingest, semantic config flip,
    CLEAR METADATA, or rollup DDL invalidates the memo (store-version /
    fingerprint keyed, exactly like the plan caches).
+5. **The record's span tree** — on a dense statement, a hashed
+   statement and an eight-tile fused group (interpret mode, tiny
+   store): child spans never become keys of ``phases``,
+   ``total_ms - sum(phases) >= 0``, the leader carries ``dispatch`` with
+   its three children and the followers ``coalesce.hold`` +
+   ``coalesce.ride``; the server's root, the query id, and the same
+   spans in a ``jax.profiler`` capture.
 4. **Decode-ahead differential** — over an encoded tiered store the
    second pass serves decoded chunks from the decoded-side cache
    (``decode_ms_saved > 0``) with bit-identical answers.
 """
 
+import contextlib
+import json
+import os
+import threading
 import time
+import urllib.request
 
+import jax
 import numpy as np
 import pandas as pd
 import pytest
@@ -76,13 +90,122 @@ def test_phase_and_add_are_noops_without_accumulator():
     assert PH.end(tok) == {}            # nothing leaked in
 
 
-def test_inclusive_nesting_counts_both():
+def test_nested_phase_is_a_span_not_a_phase():
+    """The child-span rule: a phase nested in another is in the tree
+    only, so the flat view never counts an interval twice."""
     tok = PH.begin()
     with PH.phase("plan.build"):
         with PH.phase("plan.rollup"):
             time.sleep(0.005)
     out = PH.end(tok)
-    assert out["plan.build"] >= out["plan.rollup"] >= 4.0
+    assert set(out) == {"plan.build"} and out["plan.build"] >= 4.0
+    by = {sp[0]: sp for sp in tok.stmt.spans}
+    assert by["plan.rollup"][2] >= 4000.0                   # us
+    assert tok.stmt.spans[by["plan.rollup"][3]][0] == "plan.build"
+
+
+def _self_us(spans, i):
+    """Duration of span ``i`` minus the union of its children."""
+    kids = sorted((sp[1], sp[1] + sp[2]) for sp in spans if sp[3] == i)
+    covered, end = 0.0, float("-inf")
+    for a, b in kids:
+        covered += max(0.0, b - max(a, end))
+        end = max(end, b)
+    return spans[i][2] - covered
+
+
+def test_span_tree_of_a_nested_sequence():
+    """Parents, starts, durations and self time of a known sequence:
+    sql > [bind, dispatch > [dispatch.launch, dispatch.wait > tier.fault
+    (pre-measured)], decode]."""
+    tok = PH.begin()
+    with PH.phase("bind"):
+        time.sleep(0.002)
+    with PH.phase("dispatch"):
+        with PH.phase("dispatch.launch"):
+            time.sleep(0.001)
+        with PH.phase("dispatch.wait"):
+            time.sleep(0.004)
+            PH.add("tier.fault", 0.003)        # ends now, began 3 ms ago
+        time.sleep(0.002)                      # dispatch's own time
+    with PH.phase("decode"):
+        pass
+    PH.end(tok)
+    spans = tok.stmt.spans
+    names = [sp[0] for sp in spans]
+    assert names == ["sql", "bind", "dispatch", "dispatch.launch",
+                     "dispatch.wait", "tier.fault", "decode"]
+    parents = [sp[3] for sp in spans]
+    assert parents == [-1, 0, 0, 2, 2, 4, 0]
+    assert spans[0][1] == 0.0 and all(sp[2] is not None for sp in spans)
+    for i, sp in enumerate(spans[1:], 1):      # a child lies in its parent
+        par = spans[sp[3]]
+        assert par[1] - 1.0 <= sp[1]
+        assert sp[1] + sp[2] <= par[1] + par[2] + 1.0, (sp, par)
+    starts = [sp[1] for sp in spans if sp[0] != "tier.fault"]
+    assert starts == sorted(starts)            # opened in program order
+    assert spans[5][2] == pytest.approx(3000.0)
+    wait_end = spans[4][1] + spans[4][2]       # add() ends at the call,
+    assert wait_end - 2000.0 <= spans[5][1] + spans[5][2] <= wait_end + 1.0
+    # self time: dispatch = its sleep(2 ms); the root = the gaps
+    assert 1900.0 <= _self_us(spans, 2) <= spans[2][2] - 4900.0
+    assert _self_us(spans, 4) == pytest.approx(spans[4][2] - 3000.0,
+                                               abs=1.0)
+    assert 0.0 <= _self_us(spans, 0) < spans[0][2] - 8900.0
+
+
+def test_phases_and_spans_agree_by_name():
+    """Every key of ``phases`` is the sum of the root's children of that
+    name, and every child of the root is a key."""
+    PH.stash("parse", 0.001)
+    tok = PH.begin()
+    for _ in range(2):
+        with PH.phase("bind"):
+            time.sleep(0.001)
+        with PH.phase("dispatch"):
+            with PH.phase("bind"):             # a wave's overlapped bind
+                pass
+    PH.add("tier.decode", 0.002)
+    out = PH.end(tok)
+    spans = tok.stmt.spans
+    top = {}
+    for sp in spans[1:]:
+        if sp[3] == 0:
+            top[sp[0]] = top.get(sp[0], 0.0) + sp[2] / 1000.0
+    assert set(out) == set(top) == {"parse", "bind", "dispatch",
+                                    "tier.decode"}
+    for k in out:
+        assert out[k] == pytest.approx(top[k], abs=1e-3)
+    assert sum(1 for sp in spans if sp[0] == "bind") == 4
+    # the stashed parse began before begin(): the root starts with it
+    assert min(sp[1] for sp in spans) >= 0.0
+
+
+def test_server_root_keeps_handler_spans_out_of_phases():
+    """``open_root`` (the HTTP handler) owns the root; the session's
+    begin()..end() only opens the flat view, so spans before and after
+    it are in the tree and not in ``phases``."""
+    root = PH.open_root("http.request", "q-1")
+    assert PH.open_root("http.request") is None    # one per thread
+    with PH.phase("http.read"):
+        pass
+    tok = PH.begin()
+    assert tok is not None and tok.stmt is root
+    with PH.phase("bind"):
+        pass
+    out = PH.end(tok)
+    assert root.spans[0][2] is None                # the root is still open
+    with PH.phase("http.encode"):
+        pass
+    PH.close_root(root)
+    PH.close_root(root)                            # idempotent
+    assert set(out) == {"bind"}
+    assert [sp[0] for sp in root.spans] == [
+        "http.request", "http.read", "bind", "http.encode"]
+    assert all(sp[3] == 0 for sp in root.spans[1:])
+    assert root.spans[0][2] >= root.spans[-1][1] + root.spans[-1][2]
+    assert root.qid == "q-1" and PH._acc() is None
+    assert PH.begin() is not None                  # the thread is clean
 
 
 def test_stash_folds_into_next_begin_and_clears():
@@ -113,19 +236,22 @@ def test_disabled_begin_returns_none():
 
 
 def test_overhead_micro_budget():
-    """Always-on budget: one phase timing is two perf_counter reads plus
-    a dict update. 50 us per timing is ~40x observed cost and keeps the
-    ~15 timings of a real query under 1 ms — far below 1% of the
-    multi-ms host path it instruments."""
+    """Always-on budget, spans on: one span is two perf_counter_ns
+    reads, a list append, a dict update and an inactive
+    TraceAnnotation. 50 us per span is ~15x observed cost and keeps
+    the ~25 spans of a real statement under 1.5 ms worst case — far
+    below 1% of the multi-ms host path it instruments."""
     n = 10_000
     tok = PH.begin()
     t0 = time.perf_counter()
-    for _ in range(n):
-        with PH.phase("bind"):
-            pass
+    for _ in range(n // 2):
+        with PH.phase("dispatch"):             # a top-level phase ...
+            with PH.phase("dispatch.wait"):    # ... and a child span
+                pass
     per = (time.perf_counter() - t0) / n
     PH.end(tok)
-    assert per < 50e-6, f"{per * 1e6:.1f}us per phase timing"
+    assert len(tok.stmt.spans) == n + 1
+    assert per < 50e-6, f"{per * 1e6:.1f}us per span"
 
 
 # -- 2/3. session stats contract + memo --------------------------------------
@@ -283,6 +409,294 @@ def test_negative_outcomes_are_memoized(ctx):
         assert str(warm["mode"]).startswith("host")
     assert "plan.build" not in warm["phases"]
     np.testing.assert_array_equal(r1.data["odd"], r2.data["odd"])
+
+
+# -- 5. the record's span tree ------------------------------------------------
+
+CHILD_SPANS = {"sql", "http.request", "http.read", "http.encode",
+               "http.write", "dispatch.launch", "dispatch.wait",
+               "dispatch.fetch"}
+
+
+def _check_record(st):
+    """What holds for every record: the tree is well formed, the flat
+    view is the root's children, no child span is a key of it, and it
+    never exceeds the statement's wall time."""
+    spans, ph = st["spans"], st["phases"]
+    assert spans[0][3] == -1 and spans[0][0] in ("sql", "http.request")
+    assert {sp[0] for sp in spans} <= set(PH.PHASES)
+    assert all(0 <= sp[3] < i for i, sp in enumerate(spans) if i)
+    assert not set(ph) & CHILD_SPANS, ph
+    top = {sp[0] for sp in spans[1:] if sp[3] == 0 and sp[2] is not None}
+    assert set(ph) <= top, (set(ph) - top)
+    for i, sp in enumerate(spans):
+        if sp[0].startswith("dispatch."):
+            assert spans[sp[3]][0] == "dispatch", (sp, spans[sp[3]])
+    assert st["total_ms"] - sum(ph.values()) >= -0.05, (st["total_ms"], ph)
+    assert isinstance(st["t0_ns"], int)
+
+
+def _children(spans, name):
+    """[[child names] for every span called ``name``]."""
+    return [[c[0] for c in spans if c[3] == i]
+            for i, sp in enumerate(spans) if sp[0] == name]
+
+
+def test_span_rule_dense_statement(ctx):
+    ctx.sql(Q)
+    ctx.sql(Q)                                  # warm: no compile, memo
+    st = _last_stats(ctx)
+    _check_record(st)
+    assert st["spans"][0][0] == "sql"           # no server, no handler
+    assert st["spans"][0][2] is not None        # end() closed the root
+    for name in ("plan.engine", "bind", "dispatch", "decode"):
+        assert name in st["phases"], (name, st["phases"])
+    assert _children(st["spans"], "dispatch") == [
+        ["dispatch.launch", "dispatch.wait", "dispatch.fetch"]]
+    assert st["fetch_bytes"] > 0 and st["n_dispatch"] == 1
+    assert "query_id" not in st                 # ctx.sql was given none
+
+
+def test_span_rule_hashed_statement(ctx):
+    """q3's shape: a group-by whose key space takes the hashed tier."""
+    ctx.config.set("sdot.engine.groupby.dense.max.keys", 64)
+    q = ("SELECT region, qty, SUM(price) AS rev FROM sales "
+         "GROUP BY region, qty ORDER BY rev DESC LIMIT 10")
+    ctx.sql(q, query_id="hashed-1")
+    ctx.sql(q, query_id="hashed-2")
+    st = _last_stats(ctx)
+    _check_record(st)
+    assert st["mode"] == "engine" and st.get("hashed"), st
+    assert st["query_id"] == "hashed-2"
+    for name in ("plan.engine", "dispatch", "merge", "decode"):
+        assert name in st["phases"], (name, st["phases"])
+    kids = _children(st["spans"], "dispatch")
+    assert len(kids) == st["n_dispatch"] >= 1
+    for k in kids:                              # an overlapped bind may
+        assert [c for c in k if c != "bind"] == [   # sit inside a wave's
+            "dispatch.launch", "dispatch.wait", "dispatch.fetch"], kids
+
+
+@contextlib.contextmanager
+def _interpret_env():
+    """The wave kernel through ``pl.pallas_call(interpret=True)``, for
+    the storm only (test_pallas_wave._interpret_env says why)."""
+    old = os.environ.get("SDOT_PALLAS")
+    os.environ["SDOT_PALLAS"] = "interpret"
+    try:
+        yield
+    finally:
+        if old is None:
+            os.environ.pop("SDOT_PALLAS", None)
+        else:
+            os.environ["SDOT_PALLAS"] = old
+
+
+TILES = [
+    "SELECT region, SUM(qty) AS q, COUNT(*) AS n FROM sales GROUP BY region",
+    "SELECT region, SUM(price) AS p FROM sales WHERE qty >= 10 "
+    "GROUP BY region",
+    "SELECT SUM(qty) AS q FROM sales WHERE region = 'east'",
+    "SELECT SUM(price) AS p, COUNT(*) AS n FROM sales WHERE qty < 24",
+    "SELECT region, MAX(qty) AS m FROM sales GROUP BY region",
+    "SELECT region, SUM(qty) AS q FROM sales WHERE region <> 'west' "
+    "GROUP BY region",
+    "SELECT COUNT(*) AS n FROM sales WHERE qty >= 40",
+    "SELECT region, MIN(price) AS lo FROM sales GROUP BY region",
+]
+
+
+@pytest.fixture(scope="module")
+def storm_records():
+    """One refresh of eight tiles fired together at a coalescing
+    context: the eight history records of ONE fused group."""
+    c = sdot.Context({"sdot.cache.enabled": False,
+                      "sdot.sharedscan.enabled": True,
+                      "sdot.wlm.batch.window.ms": 2000})
+    c.ingest_dataframe("sales", _sales_df(), time_column="ts")
+    errs = []
+
+    def fire(sql, barrier):
+        try:
+            barrier.wait()
+            c.sql(sql)
+        except BaseException as e:  # noqa: BLE001 — asserted below
+            errs.append(e)
+
+    try:
+        with _interpret_env():
+            for _ in range(2):                  # cold, then warm
+                c.history.clear()
+                barrier = threading.Barrier(len(TILES))
+                ts = [threading.Thread(target=fire, args=(q, barrier))
+                      for q in TILES]
+                for t in ts:
+                    t.start()
+                for t in ts:
+                    t.join()
+        assert not errs, errs
+        return [r.stats for r in c.history.entries()]
+    finally:
+        c.close()
+
+
+def test_span_rule_fused_group(storm_records):
+    assert len(storm_records) == len(TILES)
+    gids = {st["sharedscan"]["group"] for st in storm_records}
+    assert len(gids) == 1 and all(
+        st["sharedscan"]["queries"] == len(TILES) for st in storm_records)
+    for st in storm_records:
+        _check_record(st)
+        assert "coalesce.hold" in st["phases"], st["phases"]
+
+
+def test_fused_leader_dispatches_followers_ride(storm_records):
+    leaders = [st for st in storm_records
+               if st["sharedscan"]["role"] == "leader"]
+    followers = [st for st in storm_records
+                 if st["sharedscan"]["role"] == "follower"]
+    assert len(leaders) == 1 and len(followers) == len(TILES) - 1
+    lead = leaders[0]
+    # the repair: the single-wave launch is inside a dispatch phase
+    for name in ("coalesce.hold", "coalesce.plan", "bind", "dispatch",
+                 "demux"):
+        assert name in lead["phases"], (name, lead["phases"])
+    assert "coalesce.ride" not in lead["phases"]
+    assert _children(lead["spans"], "dispatch") == [
+        ["dispatch.launch", "dispatch.wait", "dispatch.fetch"]]
+    assert lead["n_dispatch"] == 1 and lead["fetch_bytes"] > 0
+    order = [sp[0] for sp in lead["spans"] if sp[3] == 0
+             and sp[0] in ("coalesce.hold", "coalesce.plan", "dispatch")]
+    assert order == ["coalesce.hold", "coalesce.plan", "dispatch"]
+    close_ns = []
+    for st in followers:
+        names = {sp[0] for sp in st["spans"]}
+        assert {"coalesce.hold", "coalesce.ride"} <= set(st["phases"])
+        assert not names & {"dispatch", "dispatch.launch", "coalesce.plan",
+                            "bind", "demux"}, names
+        assert st["n_dispatch"] == 0 and st["fetch_bytes"] == 0
+        hold = next(sp for sp in st["spans"] if sp[0] == "coalesce.hold")
+        ride = next(sp for sp in st["spans"] if sp[0] == "coalesce.ride")
+        assert hold[1] + hold[2] == pytest.approx(ride[1], abs=1.0)
+        close_ns.append(st["t0_ns"] + ride[1] * 1e3)
+    # every member's hold ends at the group's one close, the leader's too
+    hold = next(sp for sp in lead["spans"] if sp[0] == "coalesce.hold")
+    close_ns.append(lead["t0_ns"] + (hold[1] + hold[2]) * 1e3)
+    assert max(close_ns) - min(close_ns) < 1e6, close_ns   # within 1 ms
+
+
+@pytest.fixture()
+def served(ctx):
+    from spark_druid_olap_tpu.server.http import SqlServer
+    srv = SqlServer(ctx, "127.0.0.1", 0).start(background=True)
+    try:
+        yield srv
+    finally:
+        srv.stop()
+
+
+def _post_sql(port, sql, **extra):
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/sql",
+        data=json.dumps({"sql": sql, **extra}).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=60) as resp:
+        return json.loads(resp.read())
+
+
+def _history(port):
+    with urllib.request.urlopen(f"http://127.0.0.1:{port}/history",
+                                timeout=60) as resp:
+        return json.loads(resp.read())["history"]
+
+
+def _completed(port, qid):
+    """The record of ``qid`` once its handler has closed the root (the
+    client has its answer a moment before the handler's last line)."""
+    for _ in range(200):
+        rec = next(r for r in _history(port) if r.get("query_id") == qid)
+        if rec["spans"][0][2] is not None:
+            return rec
+        time.sleep(0.01)
+    raise AssertionError(f"root of {qid} never closed: {rec['spans']}")
+
+
+def test_query_id_in_record_equals_response(served):
+    minted = _post_sql(served.port, Q)["queryId"]
+    given = _post_sql(served.port, Q, queryId="dash-7.tile-3")["queryId"]
+    assert given == "dash-7.tile-3" and minted != given
+    ids = [r.get("query_id") for r in _history(served.port)]
+    assert ids[-2:] == [minted, given]
+
+
+def test_http_root_and_its_children(served):
+    _post_sql(served.port, Q)
+    qid = _post_sql(served.port, Q)["queryId"]
+    rec = _completed(served.port, qid)
+    _check_record(rec)
+    spans = rec["spans"]
+    assert spans[0][0] == "http.request"
+    top = [sp[0] for sp in spans if sp[3] == 0]
+    assert top[0] == "http.read" and top[-2:] == ["http.encode",
+                                                  "http.write"]
+    assert "dispatch" in top and "decode" in top
+    assert not any(k.startswith("http.") for k in rec["phases"])
+    # total_ms is the session's: the handler's spans lie around it
+    read, enc = spans[1], next(sp for sp in spans if sp[0] == "http.encode")
+    assert (enc[1] - (read[1] + read[2])) / 1000.0 >= rec["total_ms"] - 0.05
+    assert spans[0][2] >= enc[1] + enc[2]
+
+
+def test_profiler_capture_carries_the_spans(served, tmp_path):
+    """Any ``jax.profiler`` capture of the process shows the spans as
+    ``sdot:<name>`` host events with ``qid`` and ``t0_ns`` stats, and
+    ``t0_ns`` (the span's own ``perf_counter_ns``) is one fixed offset
+    from the capture's clock — the anchor that lays a record's spans
+    onto the device lines of the same trace."""
+    _post_sql(served.port, Q)                   # compile outside
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        qids = [_post_sql(served.port, Q)["queryId"] for _ in range(3)]
+        rec = _completed(served.port, qids[-1])
+    finally:
+        jax.profiler.stop_trace()
+    files = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    assert files, list(tmp_path.rglob("*"))
+    data = jax.profiler.ProfileData.from_file(str(files[0]))
+    events = [(ev.name, ev.start_ns, ev.duration_ns, dict(ev.stats))
+              for plane in data.planes for line in plane.lines
+              for ev in line.events if ev.name.startswith("sdot:")]
+    waits = [e for e in events if e[0] == "sdot:dispatch.wait"]
+    assert {e[3]["qid"] for e in waits} == set(qids), waits
+    assert {e[0] for e in events} >= {
+        "sdot:http.request", "sdot:http.encode", "sdot:http.write",
+        "sdot:bind", "sdot:dispatch", "sdot:dispatch.launch",
+        "sdot:dispatch.fetch", "sdot:decode"}
+    # (but for a thread descheduled between its clock read and the
+    # annotation's, on a loaded machine: the outer twentieth is left out)
+    offsets = sorted(e[3]["t0_ns"] - e[1] for e in events)
+    trim = len(offsets) // 20
+    core = offsets[trim:len(offsets) - trim]
+    assert core[-1] - core[0] < 1e6, (core[0], core[-1])
+    # and the record's spans are those events: same start, same length
+    off = core[len(core) // 2]
+    # (the root and http.read begin before the body names the query)
+    mine = {}                                   # name -> events in order
+    for e in sorted((e for e in events if e[3].get("qid") == qids[-1]),
+                    key=lambda e: e[1]):
+        mine.setdefault(e[0], []).append(e)
+    assert len(mine) >= 8, sorted(mine)
+    for name, start_us, dur_us, _ in rec["spans"][1:]:
+        evs = mine.get("sdot:" + name)
+        if not evs:                             # stashed / add(): no event
+            continue
+        ev = evs.pop(0)                         # a name may repeat (result)
+        assert ev[1] + off == pytest.approx(
+            rec["t0_ns"] + start_us * 1e3, abs=1e6), name
+        assert ev[2] == pytest.approx(dur_us * 1e3, abs=1e6), name
 
 
 # -- 4. decode-ahead tiered serves --------------------------------------------
