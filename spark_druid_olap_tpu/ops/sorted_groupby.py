@@ -1,10 +1,10 @@
 """Sorted-run aggregation for the hashed group-by tier.
 
 The hashed tier's slot assignment (``hash_groupby.build_slots``) already
-pays ONE ``lax.sort`` over the fused key pairs — ~1.3ms/6M rows on a v5e,
-plus ~4ms per extra payload operand. The existing aggregation then
-scatters every aggregation's values into its slot (~40ms per 6M-row
-scatter on v5e, XLA's measured cost regardless of index order) — q18-class
+pays ONE ``lax.sort`` over the fused key pairs. The scatter core then
+scatters every aggregation's values into its slot — one XLA scatter per
+aggregation, 4.7-4.9 ns an update on a v5e even with sorted unique indices
+(5.1 ms per 2^20 rows, 28 ms per 6.0M; my chip run, PR 27), and q18-class
 programs stack ~6 of those. This module replaces the scatters entirely:
 
 - **Ride the aggregation values as sort payloads.** After the sort, every
@@ -20,10 +20,39 @@ programs stack ~6 of those. This module replaces the scatters entirely:
   carry the PREFIX magnitude's cancellation error into small groups,
   which is why the naive version is wrong and this one is not.
 - **min/max** use a segmented scan with the same reset flag.
-- **Per-group finals** sit at each run's LAST row; a ``searchsorted``
-  over the (sorted, nondecreasing) group-id vector finds the T run-end
-  positions — log2(N) rounds of T-probe 1D gathers (take1d discipline),
-  ~log2(6M) * T probes total, versus 6M scatter updates per agg.
+- **Per-group finals** sit at each run's LAST row, and a row is the last
+  of its run iff the next row starts one — known without any search. A
+  second ``lax.sort`` keyed on the run-last rows' group id compacts them
+  to the front in group order, carrying the key parts and each
+  aggregation's scanned column; the ``[T]`` table is the first ``T``
+  entries (prefix-sum routes take the adjacent difference after the
+  compaction). Work after the first sort is O(n) whatever ``T``; where the
+  table is under 1/64 of the rows the row index rides alone and ``T``-wide
+  takes read the columns (``_run_last_to_front``).
+
+What it costs on one v5e at q3's shape — n = 2^20 rows (``compact_m``),
+T = 2^21 slots, three aggregations (max, max, sum ``ff``), six table
+columns (all: my chip runs, PR 27; "trace" = device time per q3 in the
+benchmark's traced ``adhoc_seq`` run, "micro" = the op alone, host clock
+around ``block_until_ready``):
+
+- first sort, 2 keys + 3 payloads: 4.4 ms (micro, random keys), 2.4-2.5
+  ms (trace); a 2-operand sort of 2^20 rows 2.0 ms; each further operand
+  0.3 ms per 2^20 rows, 0.6 ns a row at 6.0M rows (the cost model's
+  ``sort.payload.seconds.per.row`` 6.7e-10 holds);
+- ``cumsum`` 0.8 ms, one segmented max scan 2.9 ms (micro);
+- the compaction sort, 1 key + 6 columns: 4.0 ms (micro), 2.5 ms (trace);
+  the whole program 71.3 ms a q3, 51 of them late materialization's six
+  gathers of 2^20 survivors out of 4.0M rows, before this module runs;
+- what it replaced, a binary search for the run end of EVERY slot
+  (``T`` x 21 rounds of ``T``-probe gathers in a ``fori_loop``, then six
+  ``[T]``-wide takes): 316 ms + 139 ms of q3's 524 ms (trace; 501 ms as a
+  micro) — 7.1 ns a probe, as ``gather.seconds.per.probe`` 7e-9 says. It
+  was written for ``T << n`` ("log2(N) x T probes versus N scatter
+  updates"); late materialization sizes ``T`` at twice the rows that
+  survive it, so the search ran over two million slots of which 99.4 %
+  were empty. At n = 6.0M, T = 2^14 the same search took 9.8 ms, the index
+  way here 10.5 ms, carrying all six columns 33.3 ms (micro).
 
 Outputs keep the hashed tier's existing contracts (``groupby.Route``
 outputs / ``combine_route`` / host key-wise merge): ``i32`` for counts
@@ -32,11 +61,12 @@ sums, the ``ff`` (acc, c) pair for float sums, ``i32``/``f32``(/x64
 ``i64``/``f64``) sentinel min-max. Table keys/'__unres__' match
 ``build_slots`` exactly (sorted occupied prefix, EMPTY padding).
 
-Backend economics: on TPU the sort is ~30x cheaper than one scatter, so
-this path wins whenever >=1 aggregation exists; the CPU fallback's x64
-sort is the expensive op (~0.3s/M rows measured) while its scatters are
-cheap, so the executor gates this to TPU backends (config-overridable —
-tests force it on CPU for differential coverage).
+Backend economics: on TPU one sort operand costs about a tenth of one
+scatter of the same rows (above), so this path wins whenever >=1
+aggregation exists; the CPU fallback's x64 sort is the expensive op
+(~0.3s/M rows measured) while its scatters are cheap, so the executor
+gates this to TPU backends (config-overridable — tests force it on CPU for
+differential coverage).
 
 ≈ reference scope: the groupBy v2 per-segment aggregation the reference
 delegated to Druid historicals (``DruidQuerySpec.scala:638-683``); the
@@ -124,31 +154,6 @@ def _two_sum(a, b):
     return s, e
 
 
-def _end_positions(gid_sorted, T: int):
-    """Run-end position of each of the first ``T`` group ids — binary
-    search over the nondecreasing [N] gid vector: log2(N) rounds of
-    T-probe 1D gathers (cheap) instead of any N-update scatter."""
-    n = gid_sorted.shape[0]
-    q = jnp.arange(T, dtype=jnp.int32)
-    lo = jnp.zeros((T,), jnp.int32)
-    hi = jnp.full((T,), n, jnp.int32)
-    steps = int(np.ceil(np.log2(max(n, 2)))) + 1
-
-    def body(_, st):
-        lo_, hi_ = st
-        mid = (lo_ + hi_) // 2
-        mid_c = jnp.clip(mid, 0, n - 1)
-        gv = jnp.take(gid_sorted, mid_c)     # 1D gather (take1d shape)
-        less_eq = gv <= q
-        lo_ = jnp.where(less_eq & (lo_ < hi_), mid + 1, lo_)
-        hi_ = jnp.where((~less_eq) & (lo_ < hi_), mid, hi_)
-        return lo_, hi_
-
-    lo, _ = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    # lo = first index with gid > g == one past run end
-    return jnp.clip(lo - 1, 0, n - 1), lo
-
-
 def _cumsum64(v32):
     """Inclusive prefix sum of i32 values in TRUE 64-bit on a 32-bit
     backend (jnp.int64 silently canonicalizes to i32 there): the value is
@@ -175,126 +180,126 @@ def _sub64(ahi, alo, bhi, blo):
     return ahi - bhi - borrow, lo
 
 
+def _sentinel(kind: str, tag: str):
+    """What an unoccupied slot of a min/max route holds."""
+    if tag == "i32":
+        return I32_MAX if kind == "min" else I32_MIN
+    if tag == "i64":
+        return I64_MAX if kind == "min" else I64_MIN
+    if tag == "f64":
+        return jnp.float64(np.inf if kind == "min" else -np.inf)
+    return F32_MAX if kind == "min" else -F32_MAX
+
+
+# Below this share of the rows the table is small enough that carrying the
+# row index alone through the compaction sort and reading the columns with
+# [T]-wide takes beats carrying every column (v5e, PR 27: a column costs
+# the sort 0.3-0.6 ns a row, a take ~18 ns a probe; at n = 6.0M, T = 2^14
+# the index way took 10.5 ms, the carry-all way 33.3 ms).
+_TAKE_BELOW = 64
+
+
+def _run_last_to_front(keep, gid, cols, T: int):
+    """The ``[T]`` table columns: ``cols`` read at the run-last rows
+    (``keep``), in gid order. ONE ``lax.sort`` keyed on the kept rows' gid
+    (unique among them, so stability is moot; every other row keys
+    INT32_MAX and sorts behind) brings them to the front — carrying the
+    columns themselves, or, where the table is a small share of the rows,
+    only the row index for ``T`` takes. The table is the first ``T``
+    entries, zero-padded where ``T > n`` (the caller masks everything past
+    the occupied prefix). No search: work is O(n), whatever ``T``."""
+    n = gid.shape[0]
+    key = jnp.where(keep, gid, I32_MAX)
+    if T * _TAKE_BELOW <= n:
+        _, pos = jax.lax.sort((key, jnp.arange(n, dtype=jnp.int32)),
+                              num_keys=1, is_stable=False)
+        pos = jax.lax.slice_in_dim(pos, 0, T)
+        return [jnp.take(c, pos) for c in cols]
+    moved = jax.lax.sort((key,) + tuple(cols), num_keys=1,
+                         is_stable=False)[1:]
+    if T <= n:
+        return [jax.lax.slice_in_dim(c, 0, T) for c in moved]
+    return [jnp.concatenate([c, jnp.zeros((T - n,), c.dtype)])
+            for c in moved]
+
+
+def _shift1(c):
+    """``c[g-1]`` at ``g`` (0 at ``g == 0``): the previous run's end."""
+    return jnp.concatenate([jnp.zeros((1,), c.dtype), c[:-1]])
+
+
 def sorted_hash_groupby(khi, klo, valid, T: int, inputs: List[AggInput],
                         routes: Dict[str, Route]) -> Dict[str, object]:
-    """One-sort hashed group-by: returns the same output dict the
+    """Sorted-run hashed group-by: returns the same output dict the
     ``build_slots`` + ``dense_groupby`` pair produces — route outputs per
     ``Route.outputs(T)`` plus '__tkhi__', '__tklo__', '__unres__'."""
     x64 = _x64()
-    n = khi.reshape(-1).shape[0]
-    khi_f = jnp.where(valid.reshape(-1), khi.reshape(-1).astype(jnp.int32),
-                      H.EMPTY)
-    klo_f = jnp.where(valid.reshape(-1), klo.reshape(-1).astype(jnp.int32),
-                      H.EMPTY)
+    base = valid.reshape(-1)
+    khi_f = jnp.where(base, khi.reshape(-1).astype(jnp.int32), H.EMPTY)
+    klo_f = jnp.where(base, klo.reshape(-1).astype(jnp.int32), H.EMPTY)
 
     # payloads: pre-masked per-agg value vectors (masking BEFORE the sort
     # keeps the per-agg filter masks off the sort operand list)
     payloads = []
-    meta = []                      # (agg, route, payload slice indices)
     for a in inputs:
         r = routes[a.name]
-        base = valid.reshape(-1)
         am = base if a.mask is None else (base & a.mask.reshape(-1))
         if a.kind == "count":
             payloads.append(am.astype(jnp.int32))
-            meta.append((a, r, (len(payloads) - 1,)))
             continue
         v = a.values.reshape(-1)
         if a.kind in ("min", "max"):
-            if r.tag == "i32":
-                sent = I32_MAX if a.kind == "min" else I32_MIN
-                v = jnp.where(am, v.astype(jnp.int32), sent)
-            elif r.tag == "i64":
-                sent = I64_MAX if a.kind == "min" else I64_MIN
-                v = jnp.where(am, v.astype(jnp.int64), sent)
-            elif r.tag == "f64":
-                sent = jnp.float64(np.inf if a.kind == "min" else -np.inf)
-                v = jnp.where(am, v.astype(jnp.float64), sent)
-            else:
-                sent = F32_MAX if a.kind == "min" else -F32_MAX
-                v = jnp.where(am, v.astype(jnp.float32), sent)
+            dt = {"i32": jnp.int32, "i64": jnp.int64,
+                  "f64": jnp.float64}.get(r.tag, jnp.float32)
+            v = jnp.where(am, v.astype(dt), _sentinel(a.kind, r.tag))
+        elif r.tag in ("i32", "s64", "i64"):
+            v = jnp.where(am, v.astype(
+                jnp.int64 if (x64 and r.tag == "i64") else jnp.int32), 0)
         else:
-            if r.tag in ("i32", "s64", "i64"):
-                v = jnp.where(am, v.astype(
-                    jnp.int64 if (x64 and r.tag == "i64")
-                    else jnp.int32), 0)
-            else:
-                v = jnp.where(am, v.astype(
-                    jnp.float64 if r.tag == "f64" else jnp.float32), 0.0)
+            v = jnp.where(am, v.astype(
+                jnp.float64 if r.tag == "f64" else jnp.float32), 0.0)
         payloads.append(v)
-        meta.append((a, r, (len(payloads) - 1,)))
 
     ops = jax.lax.sort((khi_f, klo_f) + tuple(payloads), num_keys=2)
     skh, skl = ops[0], ops[1]
-    sorted_payloads = ops[2:]
+    n = skh.shape[0]
 
     new = (skh != jnp.roll(skh, 1)) | (skl != jnp.roll(skl, 1))
     new = new.at[0].set(True)
     gid = jnp.cumsum(new.astype(jnp.int32)) - 1
     occupied_row = skh != H.EMPTY
     unresolved = jnp.sum((occupied_row & (gid >= T)).astype(jnp.int32))
+    # a row is the LAST of its run iff the next row starts one: no search.
+    # Invalid rows sort last as one trailing pseudo-group and are not kept.
+    keep = jnp.roll(new, -1).at[n - 1].set(True) & occupied_row
 
-    end_pos, first_after = _end_positions(gid, T)
-    # group g occupied iff some row has gid == g AND its key is real
-    g_occ = (first_after > jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), first_after[:-1]])) \
-        & (jnp.take(skh, end_pos) != H.EMPTY)
-    tk_hi = jnp.where(g_occ, jnp.take(skh, end_pos), H.EMPTY)
-    tk_lo = jnp.where(g_occ, jnp.take(skl, end_pos), H.EMPTY)
-
-    prev_end = jnp.concatenate(
-        [jnp.full((1,), -1, jnp.int32), end_pos[:-1]])
-
-    out: Dict[str, object] = {}
-    for (a, r, pidx) in meta:
-        v = sorted_payloads[pidx[0]]
+    # per-row scanned columns; a group's final sits at its run-last row.
+    # ``plan`` remembers which table columns each aggregation reads.
+    cols = [skh, skl]
+    plan = []
+    for a, v in zip(inputs, ops[2:]):
+        r = routes[a.name]
+        at = len(cols)
         if a.kind in ("min", "max"):
-            comb = (lambda x, y: tuple(jnp.minimum(a_, b_)
-                                       for a_, b_ in zip(x, y))) \
-                if a.kind == "min" else \
-                (lambda x, y: tuple(jnp.maximum(a_, b_)
-                                    for a_, b_ in zip(x, y)))
-            scanned, = _seg_scan(new, (v,), comb)
-            finals = jnp.take(scanned, end_pos)
-            if r.tag == "i32":
-                sent = I32_MAX if a.kind == "min" else I32_MIN
-            elif r.tag == "i64":
-                sent = I64_MAX if a.kind == "min" else I64_MIN
-            elif r.tag == "f64":
-                sent = jnp.float64(np.inf if a.kind == "min" else -np.inf)
-            else:
-                sent = F32_MAX if a.kind == "min" else -F32_MAX
-            out[r.name] = jnp.where(g_occ, finals, sent)
-        elif r.tag in ("i32",) and a.kind in ("count", "sum"):
+            pick = jnp.minimum if a.kind == "min" else jnp.maximum
+            cols += _seg_scan(new, (v,),
+                              lambda x, y, pick=pick: (pick(x[0], y[0]),))
+            how = "final"
+        elif r.tag == "i32":
             # wrap-exact mod 2^32: per-group totals fit i32 by the route
             # gate, so the two's-complement prefix difference is exact
-            c = jnp.cumsum(v.astype(jnp.int32))
-            tot = jnp.take(c, end_pos) - jnp.where(
-                prev_end < 0, 0, jnp.take(c, jnp.maximum(prev_end, 0)))
-            out[r.name] = jnp.where(g_occ, tot, 0)
+            cols.append(jnp.cumsum(v.astype(jnp.int32)))
+            how = "diff"
         elif r.tag == "i64":
             # x64 CPU: native 64-bit prefix sums, exact at any magnitude
-            c = jnp.cumsum(v.astype(jnp.int64))
-            tot = jnp.take(c, end_pos) - jnp.where(
-                prev_end < 0, jnp.int64(0),
-                jnp.take(c, jnp.maximum(prev_end, 0)))
-            out[r.name] = jnp.where(g_occ, tot, jnp.int64(0))
+            cols.append(jnp.cumsum(v.astype(jnp.int64)))
+            how = "diff"
         elif r.tag == "s64":
-            chi, clo = _cumsum64(v.astype(jnp.int32))
-            ehi = jnp.take(chi, end_pos)
-            elo = jnp.take(clo, end_pos)
-            phi = jnp.where(prev_end < 0, jnp.int32(0),
-                            jnp.take(chi, jnp.maximum(prev_end, 0)))
-            plo = jnp.where(prev_end < 0, jnp.uint32(0),
-                            jnp.take(clo, jnp.maximum(prev_end, 0)))
-            thi, tlo = _sub64(ehi, elo, phi, plo)
-            out[r.name + ".hi"] = jnp.where(g_occ, thi, 0)
-            out[r.name + ".lo"] = jax.lax.bitcast_convert_type(
-                jnp.where(g_occ, tlo, jnp.uint32(0)), jnp.int32)
+            cols += _cumsum64(v.astype(jnp.int32))
+            how = "diff64"
         elif r.tag == "f64":
-            scanned, = _seg_scan(new, (v,),
-                                 lambda x, y: (x[0] + y[0],))
-            out[r.name] = jnp.where(g_occ, jnp.take(scanned, end_pos), 0.0)
+            cols += _seg_scan(new, (v,), lambda x, y: (x[0] + y[0],))
+            how = "final"
         else:
             # float sums: segmented COMPENSATED scan — (sum, err) pairs
             # combined with TwoSum so the error term never carries the
@@ -302,13 +307,34 @@ def sorted_hash_groupby(khi, klo, valid, T: int, inputs: List[AggInput],
             def comb(xa, xb):
                 s, e = _two_sum(xa[0], xb[0])
                 return (s, e + xa[1] + xb[1])
-            acc, comp = _seg_scan(new, (v, jnp.zeros_like(v)), comb)
-            out[r.name + ".acc"] = jnp.where(
-                g_occ, jnp.take(acc, end_pos), 0.0)
-            out[r.name + ".c"] = jnp.where(
-                g_occ, jnp.take(comp, end_pos), 0.0)
+            cols += _seg_scan(new, (v, jnp.zeros_like(v)), comb)
+            how = "ff"
+        plan.append((a, r, how, at))
 
-    out["__tkhi__"] = tk_hi
-    out["__tklo__"] = tk_lo
+    tab = _run_last_to_front(keep, gid, cols, T)
+    # occupied groups are exactly the first sum(keep) gids
+    g_occ = jnp.arange(T, dtype=jnp.int32) < jnp.sum(keep.astype(jnp.int32))
+
+    out: Dict[str, object] = {}
+    for a, r, how, at in plan:
+        if how == "final":
+            fill = _sentinel(a.kind, r.tag) if a.kind in ("min", "max") \
+                else 0.0
+            out[r.name] = jnp.where(g_occ, tab[at], fill)
+        elif how == "diff":
+            # cumulative value at this run's end minus the previous run's
+            out[r.name] = jnp.where(g_occ, tab[at] - _shift1(tab[at]), 0)
+        elif how == "diff64":
+            thi, tlo = _sub64(tab[at], tab[at + 1],
+                              _shift1(tab[at]), _shift1(tab[at + 1]))
+            out[r.name + ".hi"] = jnp.where(g_occ, thi, 0)
+            out[r.name + ".lo"] = jax.lax.bitcast_convert_type(
+                jnp.where(g_occ, tlo, jnp.uint32(0)), jnp.int32)
+        else:
+            out[r.name + ".acc"] = jnp.where(g_occ, tab[at], 0.0)
+            out[r.name + ".c"] = jnp.where(g_occ, tab[at + 1], 0.0)
+
+    out["__tkhi__"] = jnp.where(g_occ, tab[0], H.EMPTY)
+    out["__tklo__"] = jnp.where(g_occ, tab[1], H.EMPTY)
     out["__unres__"] = unresolved.reshape(1)
     return out

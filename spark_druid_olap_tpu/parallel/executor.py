@@ -1888,6 +1888,11 @@ class QueryEngine:
             "bytes_scanned": int(seg_bytes) * int(n_seg_sel),
             "segments_per_wave": int(s_pad), "hashed": True,
             "hash_slots": int(T), "hash_compact_k": int(kg_used),
+            # the mechanism that ran: sorted-run core or scatter, and the
+            # rows a chip's table was built from (per wave)
+            "sorted_run": bool(sorted_run),
+            "hash_rows": int(lm) if lm
+            else int(s_pad // n_dev) * int(ds.padded_rows),
             "topk_device": int(topk[1]) if topk
             else (int(exch[1]) if exch else 0),
             "topk_exchange": bool(exch)})

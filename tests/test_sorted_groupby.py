@@ -74,6 +74,23 @@ def test_sorted_run_matches_scatter_and_pandas():
     assert np.allclose(on.mx.fillna(-9), o.mx.fillna(-9), rtol=1e-6)
 
 
+@pytest.mark.parametrize("mode", ["on", "off"])
+def test_stats_say_which_core_ran(mode):
+    """'sorted_run' and 'hash_rows' ride beside 'hash_slots' (and reach
+    the /history record as the rest of last_stats does)."""
+    df = _frame(n=20_000, seed=3, n_keys=3000)
+    ctx = sdot.Context(config={
+        **HASHED_CONF, "sdot.engine.groupby.hash.sortedrun": mode,
+        "sdot.engine.scan.compact.min.rows": 0})
+    ctx.ingest_dataframe("t", df)
+    ctx.sql("select k, sum(q) as s from t where q < 5 group by k")
+    st = ctx.history.entries()[-1].stats
+    assert st["hashed"] and st["sorted_run"] is (mode == "on"), st
+    ds = ctx.store.get("t")
+    rows = st.get("compact_m") or ds.padded_rows * ds.num_segments
+    assert st["hash_rows"] == rows and st["hash_slots"] > 0, st
+
+
 def test_sorted_run_sharded_matches():
     df = _frame(n=40_000, seed=4)
     conf = {**HASHED_CONF, "sdot.querycostmodel.enabled": False}
@@ -236,3 +253,166 @@ def test_medium_k_reroute_skips_sketches():
     st = ctx.history.entries()[-1].stats
     assert st["mode"] == "engine" and not st.get("hashed"), st
     assert len(r) == df.k.nunique()
+
+
+# -- the finals stage: run-last rows to the table by one compaction sort -----
+#
+# Differential against the scatter tier (``build_slots`` + ``dense_groupby``)
+# at the kernel's own signature, over the shapes a run-end search used to
+# absorb. Float values are whole numbers, so an f32 sum is exact in any
+# order and even the ``ff`` (acc, c) pair is bit-identical across tiers.
+
+# name -> (n rows, T slots, key space, valid share)
+SHAPES = {
+    "T_gt_n": (3000, 8192, 700, 0.9),            # q3's: T = 2n and more
+    "T_eq_n": (4096, 4096, 700, 0.9),
+    "T_lt_groups": (3000, 256, 700, 0.9),        # '__unres__' > 0
+    "all_invalid": (3000, 512, 700, 0.0),
+    "one_group": (3000, 64, 1, 1.0),
+    "every_row_a_group": (2048, 4096, 0, 1.0),   # key space 0: key = row
+    "every_row_a_group_T_eq_n": (2048, 2048, 0, 1.0),
+    "trailing_invalid": (3000, 1024, 50, 0.5),
+    # T * 64 <= n: the row index alone rides the compaction sort
+    "T_much_lt_n": (8192, 128, 40, 0.9),
+    "T_much_lt_n_overflow": (8192, 64, 40, 0.9),
+}
+
+# name -> (kind, sorted-run tag, scatter tag, integer values, filtered)
+AGGS = {
+    "count_i32": ("count", "i32", "i32", True, True),
+    "sum_i32": ("sum", "i32", "i32", True, False),
+    "sum_s64": ("sum", "s64", "i64", True, True),
+    "sum_ff": ("sum", "ff", "ff", False, True),
+    "min_f32": ("min", "f32", "f32", False, True),
+    "max_f32": ("max", "f32", "f32", False, False),
+    "min_i32": ("min", "i32", "i32", True, False),
+    "max_i32": ("max", "i32", "i32", True, True),
+}
+
+
+def _finals_case(shape, seed=11):
+    n, T, n_keys, p_valid = SHAPES[shape]
+    rng = np.random.default_rng(seed)
+    khi = rng.integers(0, n_keys, n) if n_keys else rng.permutation(n)
+    klo = rng.integers(0, 3, n) if n_keys else np.zeros(n)
+    valid = rng.random(n) < p_valid
+    filt = rng.random(n) < 0.7
+    ints = rng.integers(-2**30, 2**30, n)
+    small = rng.integers(-40, 40, n)
+    # a kernel takes key parts in any shape: two "segments" of rows
+    seg = lambda a, dt: jnp.asarray(np.asarray(a).astype(dt)).reshape(2, -1)
+    return (n, T, seg(khi, np.int32), seg(klo, np.int32), seg(valid, bool),
+            seg(filt, bool), seg(ints, np.int32), seg(small, np.int32))
+
+
+def _agg_inputs(names, filt, ints, small):
+    """(inputs, sorted-run routes, scatter routes) for the named AGGS."""
+    inputs, sroutes, routes = [], {}, {}
+    for name in names:
+        kind, stag, dtag, is_int, filtered = AGGS[name]
+        vals = None if kind == "count" else \
+            (small if name == "sum_i32" else ints) if is_int \
+            else small.astype(jnp.float32)
+        inputs.append(G.AggInput(name, kind, vals,
+                                 filt if filtered else None, is_int=is_int))
+        merged = stag != "ff"
+        sroutes[name] = G.Route(name, kind, stag, merged=merged)
+        routes[name] = G.Route(name, kind, dtag, merged=merged)
+    return inputs, sroutes, routes
+
+
+def _both_tiers(shape, names):
+    n, T, khi, klo, valid, filt, ints, small = _finals_case(shape)
+    inputs, sroutes, routes = _agg_inputs(names, filt, ints, small)
+    got = SG.sorted_hash_groupby(khi, klo, valid, T, inputs, sroutes)
+    slot, tk_hi, tk_lo, unres = H.build_slots(khi, klo, valid, T)
+    want = G.dense_groupby(slot, valid, T, inputs, routes)
+    want.update({"__tkhi__": tk_hi, "__tklo__": tk_lo,
+                 "__unres__": unres.reshape(1)})
+    return T, sroutes, routes, got, want
+
+
+def _assert_same_bits(got, want, what):
+    """Exact equality, value for value (under the suite's x64 the scatter
+    tier widens an i32 sum's dtype; the values are what must agree)."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape and got.dtype.kind == want.dtype.kind, what
+    bad = np.flatnonzero(got != want)
+    assert bad.size == 0, (what, bad[:5], got[bad[:5]], want[bad[:5]])
+
+
+def _assert_routes_match(T, sroutes, routes, got, want, what):
+    """Keys and '__unres__' equal everywhere; route outputs equal on the
+    occupied slots (an EMPTY slot is dropped by the host, and the scatter
+    tier leaves a float min/max's identity there, +-inf, not the route's
+    sentinel). Returns False for an overflowed table: retried, not read."""
+    for k in ("__tkhi__", "__tklo__", "__unres__"):
+        _assert_same_bits(got[k], want[k], what + (k,))
+    if int(got["__unres__"][0]):
+        return False
+    occ = np.asarray(got["__tkhi__"]) != H.EMPTY
+    for name, r in sroutes.items():
+        for oname, size, dt in r.outputs(T):
+            assert got[oname].shape == (size,), oname
+            assert got[oname].dtype == {"i32": np.int32,
+                                        "f32": np.float32}[dt], oname
+        if r.tag == routes[name].tag:
+            for oname, _, _ in r.outputs(T):
+                _assert_same_bits(np.asarray(got[oname])[occ],
+                                  np.asarray(want[oname])[occ],
+                                  what + (oname,))
+        else:                         # s64 against the scatter tier's i64
+            _assert_same_bits(G.combine_route(r, got, T)[occ],
+                              G.combine_route(routes[name], want, T)[occ],
+                              what + (name,))
+    return True
+
+
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_finals_match_scatter_tier_every_route(shape):
+    """All eight routes in one program, over each edge shape: the table
+    keys, '__unres__' and every route output equal the scatter tier's."""
+    T, sroutes, routes, got, want = _both_tiers(shape, list(AGGS))
+    read = _assert_routes_match(T, sroutes, routes, got, want, (shape,))
+    assert read == (shape not in ("T_lt_groups", "T_much_lt_n_overflow"))
+
+
+@pytest.mark.parametrize("agg", list(AGGS))
+@pytest.mark.parametrize("shape", ["T_gt_n", "trailing_invalid",
+                                   "T_much_lt_n"])
+def test_finals_match_scatter_tier_one_route(shape, agg):
+    """Each route tag alone (one payload, its own column offsets)."""
+    T, sroutes, routes, got, want = _both_tiers(shape, [agg])
+    assert set(got) == {o for o, _, _ in sroutes[agg].outputs(T)} \
+        | {"__tkhi__", "__tklo__", "__unres__"}
+    assert _assert_routes_match(T, sroutes, routes, got, want, (shape,))
+
+
+def test_occupied_prefix_is_sorted_and_padding_empty():
+    T, sroutes, _, got, _ = _both_tiers("T_gt_n", ["count_i32", "min_f32"])
+    khi, klo = np.asarray(got["__tkhi__"]), np.asarray(got["__tklo__"])
+    n_occ = int((khi != H.EMPTY).sum())
+    assert 0 < n_occ < T and (khi[n_occ:] == H.EMPTY).all() \
+        and (klo[n_occ:] == H.EMPTY).all()
+    packed = H.pack_key(khi[:n_occ], klo[:n_occ])
+    assert (np.diff(packed) > 0).all()
+    assert (np.asarray(got["count_i32"])[n_occ:] == 0).all()
+    assert (np.asarray(got["min_f32"])[n_occ:] == G.F32_MAX).all()
+
+
+@pytest.mark.parametrize("shape", ["T_gt_n", "T_much_lt_n"])
+def test_lowered_program_has_no_loop(shape):
+    """The run ends are known from the run starts: no search, so the
+    lowered program holds no ``while`` on either way to the table (a
+    table-wide binary search for them cost q3 three fifths of its device
+    time on the v5e — PERF.md, PR 27)."""
+    n, T, khi, klo, valid, filt, ints, small = _finals_case(shape)
+    assert (T * SG._TAKE_BELOW <= n) == (shape == "T_much_lt_n")
+
+    def run(khi, klo, valid, filt, ints, small):
+        inputs, sroutes, _ = _agg_inputs(list(AGGS), filt, ints, small)
+        return SG.sorted_hash_groupby(khi, klo, valid, T, inputs, sroutes)
+
+    text = jax.jit(run).lower(khi, klo, valid, filt, ints, small).as_text()
+    assert text.count("stablehlo.sort") == 2
+    assert "stablehlo.while" not in text
