@@ -4,6 +4,7 @@ that the system still starts on the accelerator.
 
     python chip_smoke.py                 # one chip: load, serve, storm
     python chip_smoke.py --chips 4       # four chips: the mesh phase only
+    python chip_smoke.py --params        # first answer for an unseen literal
     python chip_smoke.py --rehearse --sf 0.01    # CPU rehearsal, "ok": false
 
 A SMOKE, not a benchmark: it proves the served path executes on the
@@ -28,6 +29,16 @@ Phases (one chip):
           ungrouped date-range shape among them) through the shared-scan
           tier: one fused Pallas wave kernel, no fallback, answers equal
           to the same statements served one by one.
+
+``--params`` replaces serve and storm by the user's view of a new
+literal: per TPC-H template (q1 q3 q5 q6 q12), draws of the spec's
+substitution parameters (``tools.tpch.substitution_parameters``, from
+``--seed``) are served until every year of the template has been seen
+(each twice: the warm-up), then ONE draw that was never sent is served
+once — its wall ms, its ``compile`` phase, the compile-cache entries it
+added, whether its program's signature had been seen. A tree whose
+records carry ``program`` fails the phase if that draw built a program
+under a signature the warm-up had already run.
 
 ``--chips 4`` runs the storm through the mesh tier and q1 through the
 solo sharded path on a four-device mesh, against a single-device context
@@ -322,6 +333,65 @@ def phase_serve(ctx, port, flat):
     emit("serve_done", statements=len(SERVED), **device_bytes(ctx))
 
 
+def _served(port, name, sql):
+    """POST one statement; (frame, wall ms, its history record)."""
+    n0 = len(get_json(port, "/history")["history"])
+    got, ms = post_sql(port, sql)
+    rec = history_since(port, n0)[-1]
+    check(rec["sql"] == sql, f"{name}: history out of step")
+    engine_stats(name, rec)
+    return got, ms, rec
+
+
+def phase_params(ctx, port, seed, cache_dir):
+    """First answer for a literal nobody has sent: warm each template
+    with drawn statements, then serve one unseen draw of it once."""
+    import random
+    from spark_druid_olap_tpu.utils import compile_cache
+    rng = random.Random(seed)
+    for t in tpch.TEMPLATES:
+        years = {"q1": 1, "q3": 1}.get(t, 5)    # q5 q6 q12: DATE's year
+        sent, sigs, seen_years = {}, set(), set()
+        t0 = time.perf_counter()
+        while len(sent) < 2 or len(seen_years) < years:
+            params = tpch.substitution_parameters(t, rng)
+            sql = tpch.render(t, params)
+            if sql in sent:
+                continue
+            for _ in range(2):
+                got, ms, rec = _served(port, t, sql)
+            sent[sql] = (got, params)
+            sigs.add((rec.get("program") or {}).get("sig"))
+            seen_years.add(params["date"][:4] if years > 1 else "")
+        warm_s = time.perf_counter() - t0
+        sql0, (got0, _) = next(iter(sent.items()))
+        check_frames(t + " (warm)", got0, json_frame(ref_host(ctx, sql0)))
+        while True:
+            params = tpch.substitution_parameters(t, rng)
+            sql = tpch.render(t, params)
+            if sql not in sent:
+                break
+        entries0 = compile_cache.entries(cache_dir)
+        got, ms, rec = _served(port, t, sql)
+        added = compile_cache.entries(cache_dir) - entries0
+        worst = check_frames(t, got, json_frame(ref_host(ctx, sql)))
+        prog = rec.get("program")
+        compile_ms = (rec.get("phases") or {}).get("compile")
+        shape_seen = None if prog is None else prog["sig"] in sigs
+        emit("params", template=t, params=params, warm_texts=len(sent),
+             warm_seconds=round(warm_s, 1), warm_programs=len(sigs),
+             wall_ms=round(ms, 1), compile_ms=compile_ms,
+             cache_entries_added=added, program=prog,
+             shape_seen=shape_seen, n_transfer=rec.get("n_transfer"),
+             phases_ms=rec.get("phases"), max_rel_err=worst, correct=True)
+        if shape_seen:
+            check(not prog["built"] and compile_ms is None and added == 0,
+                  f"{t}: an unseen draw of a seen shape compiled "
+                  f"(built {prog['built']}, compile {compile_ms} ms, "
+                  f"{added} cache entries)")
+    emit("params_done", templates=len(tpch.TEMPLATES), **device_bytes(ctx))
+
+
 def run_storm(port):
     """Eight concurrent POST /sql on the interactive lane; returns
     {name: (frame, ms)}."""
@@ -465,6 +535,9 @@ def main():
     ap.add_argument("--seed", type=int, default=20260729)
     ap.add_argument("--target-rows", type=int, default=1 << 20,
                     help="rows per segment")
+    ap.add_argument("--params", action="store_true",
+                    help="instead of serve and storm: one never-sent draw "
+                         "of each TPC-H template after a warm-up")
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal: skips ONLY the device assertion "
                          "and ends with \"ok\": false")
@@ -489,11 +562,15 @@ def main():
         srv = SqlServer(ctx, "127.0.0.1", 0).start(background=True)
         try:
             t0 = time.perf_counter()
-            phase_serve(ctx, srv.port, flat)
-            t1 = time.perf_counter()
-            phase_storm(ctx, srv.port)
-            emit("seconds", serve=round(t1 - t0, 1),
-                 storm=round(time.perf_counter() - t1, 1))
+            if args.params:
+                phase_params(ctx, srv.port, args.seed, cache_dir)
+                emit("seconds", params=round(time.perf_counter() - t0, 1))
+            else:
+                phase_serve(ctx, srv.port, flat)
+                t1 = time.perf_counter()
+                phase_storm(ctx, srv.port)
+                emit("seconds", serve=round(t1 - t0, 1),
+                     storm=round(time.perf_counter() - t1, 1))
         finally:
             srv.stop()
     emit("cache", dir=cache_dir, entries=compile_cache.entries(cache_dir),

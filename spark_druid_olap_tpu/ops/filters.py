@@ -9,6 +9,8 @@ stacked segment tensors:
 - bound     -> two integer compares (sorted global dictionary ⇒ lexicographic
                bounds are code ranges; numeric bounds compare values directly)
 - in        -> host ``np.isin`` over the dictionary -> constant code-mask gather
+               (a short list whose literals arrive as an operand: one
+               code compare per value)
 - like/regex/contains -> host regex over the dictionary -> code-mask gather
 - expr      -> compiled XLA predicate (replaces the JavaScript filter)
 - and/or/not, is-null, time-interval masks
@@ -18,6 +20,15 @@ dictionary-predicate half of the compressed columnar subsystem (the code
 tests evaluate identically on plain or bit-packed codes, so an encoded
 store filters without ever decoding a string — or even a code — on
 host). This module owns only the device-mask lowering around them.
+
+A leaf's literals are never converted here: the lowering asks its scan
+context (``ctx.literals(f)``, ``ctx.interval_literals``) and gets either
+Python constants (the wave path) or scalars read from the program's
+literal operand (the solo path) — ``ops/literals.py`` has the one
+conversion and says which literals take a slot. A shortcut that needs
+to KNOW a value (an absent dictionary value -> no scan at all) is taken
+only for a constant; the operand form compares against a code that
+matches no row, so every draw of a statement's shape is one program.
 """
 
 from __future__ import annotations
@@ -31,7 +42,6 @@ from spark_druid_olap_tpu.encode import predicates as P
 from spark_druid_olap_tpu.ir import expr as E
 from spark_druid_olap_tpu.ir import spec as S
 from spark_druid_olap_tpu.ops import expr_compile as EC
-from spark_druid_olap_tpu.ops import time_ops
 from spark_druid_olap_tpu.ops.scan import ScanContext
 from spark_druid_olap_tpu.segment.column import ColumnKind
 
@@ -66,6 +76,11 @@ def _false(ctx):
     return jnp.zeros_like(ctx.row_valid())
 
 
+def _static(v) -> bool:
+    """A Python constant, not a value read from the literal operand."""
+    return isinstance(v, (int, float))
+
+
 def _nullsafe(mask, name: str, ctx: ScanContext):
     nv = ctx.null_valid(name)
     return mask if nv is None else (mask & nv)
@@ -77,20 +92,20 @@ def _selector(f: S.SelectorFilter, ctx):
         nv = ctx.null_valid(f.dimension)
         return ~nv if nv is not None else _false(ctx)
     if kind == ColumnKind.DIM:
-        code = P.selector_code(ctx.ds.dims[f.dimension], f.value)
-        if code < 0:
+        code, = ctx.literals(f)
+        if _static(code) and code < 0:
             return _false(ctx)
         return _nullsafe(ctx.col(f.dimension) == code, f.dimension, ctx)
     if kind in (ColumnKind.LONG, ColumnKind.DOUBLE):
-        v = float(f.value) if kind == ColumnKind.DOUBLE else int(float(f.value))
+        v, = ctx.literals(f)
         return _nullsafe(ctx.col(f.dimension) == v, f.dimension, ctx)
     if kind == ColumnKind.DATE:
-        return ctx.col(f.dimension) == time_ops.date_literal_to_days(f.value)
+        day, = ctx.literals(f)
+        return ctx.col(f.dimension) == day
     if kind == ColumnKind.TIME:
         # same literal policy as _time_bound: naive literals are
         # session-local, zoned ones absolute
-        ms = time_ops.literal_to_utc_millis(f.value, ctx.tz)
-        day, rem = divmod(ms, time_ops.MILLIS_PER_DAY)
+        day, rem = ctx.literals(f)
         return (ctx.col(f.dimension) == day) & (ctx.time_ms() == rem)
     raise EC.Unsupported(f"selector on {kind}")
 
@@ -98,12 +113,12 @@ def _selector(f: S.SelectorFilter, ctx):
 def _bound(f: S.BoundFilter, ctx):
     kind = ctx.kind(f.dimension)
     if kind == ColumnKind.DIM and not f.numeric:
-        lo, hi = P.bound_code_range(
-            ctx.ds.dims[f.dimension], f.lower, f.upper,
-            f.lower_strict, f.upper_strict)
+        lo, hi = ctx.literals(f)        # the half-open code interval
+        codes = ctx.col(f.dimension)
+        if not _static(lo):
+            return _nullsafe((codes >= lo) & (codes < hi), f.dimension, ctx)
         if lo >= hi:
             return _false(ctx)
-        codes = ctx.col(f.dimension)
         mask = None
         if lo > 0:
             mask = codes >= lo
@@ -119,14 +134,12 @@ def _bound(f: S.BoundFilter, ctx):
         vals = ctx.dictionary(f.dimension)
         lut = np.array([_try_float(s) for s in vals], dtype=np.float32)
         arr = EC._take_lut(lut, ctx.col(f.dimension))
-        return _nullsafe(_range_mask(arr, f, float), f.dimension, ctx)
+        return _nullsafe(_range_mask(arr, f, ctx), f.dimension, ctx)
     if kind in (ColumnKind.LONG, ColumnKind.DOUBLE):
-        conv = float if kind == ColumnKind.DOUBLE else (lambda x: int(float(x)))
-        return _nullsafe(_range_mask(ctx.col(f.dimension), f, conv),
+        return _nullsafe(_range_mask(ctx.col(f.dimension), f, ctx),
                          f.dimension, ctx)
     if kind == ColumnKind.DATE:
-        return _range_mask(ctx.col(f.dimension), f,
-                           time_ops.date_literal_to_days)
+        return _range_mask(ctx.col(f.dimension), f, ctx)
     if kind == ColumnKind.TIME:
         return _time_bound(f, ctx)
     raise EC.Unsupported(f"bound on {kind}")
@@ -139,14 +152,13 @@ def _try_float(s):
         return np.nan
 
 
-def _range_mask(arr, f: S.BoundFilter, conv):
+def _range_mask(arr, f: S.BoundFilter, ctx):
+    lo, hi = ctx.literals(f)
     mask = None
-    if f.lower is not None:
-        lo = conv(f.lower)
+    if lo is not None:
         m = (arr > lo) if f.lower_strict else (arr >= lo)
         mask = m
-    if f.upper is not None:
-        hi = conv(f.upper)
+    if hi is not None:
         m = (arr < hi) if f.upper_strict else (arr <= hi)
         mask = m if mask is None else (mask & m)
     return mask if mask is not None else (arr == arr)
@@ -155,17 +167,16 @@ def _range_mask(arr, f: S.BoundFilter, conv):
 def _time_bound(f: S.BoundFilter, ctx):
     days = ctx.col(f.dimension)
     ms = ctx.time_ms()
+    dlo, rlo, dhi, rhi = ctx.literals(f)
     mask = None
 
-    if f.lower is not None:
-        lo = time_ops.literal_to_utc_millis(f.lower, ctx.tz)
-        d, r = divmod(lo, time_ops.MILLIS_PER_DAY)
+    if dlo is not None:
+        d, r = dlo, rlo
         cmp = (ms > r) if f.lower_strict else (ms >= r)
         m = (days > d) | ((days == d) & cmp)
         mask = m
-    if f.upper is not None:
-        hi = time_ops.literal_to_utc_millis(f.upper, ctx.tz)
-        d, r = divmod(hi, time_ops.MILLIS_PER_DAY)
+    if dhi is not None:
+        d, r = dhi, rhi
         cmp = (ms < r) if f.upper_strict else (ms <= r)
         m = (days < d) | ((days == d) & cmp)
         mask = m if mask is None else (mask & m)
@@ -189,19 +200,15 @@ def _in(f: S.InFilter, ctx):
             raise EC.Unsupported("IN-set values exceed 32-bit column range")
         return _nullsafe(EC.int_set_membership(arr, vals),
                          f.dimension, ctx)
-    if kind == ColumnKind.DIM:
+    lits = ctx.literals(f)      # codes, days or numbers; absent code: -1
+    if kind == ColumnKind.DIM and all(map(_static, lits)):
         mask = P.in_code_mask(ctx.dictionary(f.dimension), f.values)
         return _nullsafe(EC._take_mask(mask, ctx.col(f.dimension)),
                          f.dimension, ctx)
     arr = ctx.col(f.dimension)
     out = None
-    for v in f.values:
-        if kind == ColumnKind.DATE:
-            b = arr == time_ops.date_literal_to_days(v)
-        elif kind == ColumnKind.DOUBLE:
-            b = arr == float(v)
-        else:
-            b = arr == int(float(v))
+    for v in lits:
+        b = arr == v
         out = b if out is None else (out | b)
     return _nullsafe(out if out is not None else _false(ctx),
                      f.dimension, ctx)
@@ -272,14 +279,7 @@ def interval_mask(intervals, ctx: ScanContext):
     days = ctx.col(ctx.ds.time.name)
     ms = ctx.time_ms()
     out = None
-    for lo, hi in intervals:
-        dlo, rlo, dhi, rhi = time_ops.interval_day_range(lo, hi)
-        # open-ended interval bounds carry +-2^63-scale ms; their day
-        # numbers overflow the i32 lanes on a 32-bit backend. Scanned days
-        # all lie in [min_day, max_day], so clamping one day past that
-        # range preserves the mask exactly.
-        dlo = min(max(dlo, ctx.min_day - 1), ctx.max_day + 1)
-        dhi = min(max(dhi, ctx.min_day - 1), ctx.max_day + 1)
+    for dlo, rlo, dhi, rhi in ctx.interval_literals(intervals):
         m_lo = (days > dlo) | ((days == dlo) & (ms >= rlo))
         m_hi = (days < dhi) | ((days == dhi) & (ms < rhi))
         m = m_lo & m_hi

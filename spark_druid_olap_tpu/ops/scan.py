@@ -16,6 +16,7 @@ from typing import Dict, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from spark_druid_olap_tpu.ops import literals as L
 from spark_druid_olap_tpu.segment.column import ColumnKind
 from spark_druid_olap_tpu.segment.store import Datasource
 
@@ -33,6 +34,33 @@ class ScanContext:
     min_day: int                       # over the selected segments
     max_day: int
     tz: str = "UTC"                    # session timezone (instants shift)
+    # a program that takes its filter literals as an operand
+    # (ops/literals.py) reads them here; without, they are constants
+    operands: Optional[L.Operands] = None
+
+    # -- filter literals ------------------------------------------------------
+    def literals(self, f):
+        """What leaf filter ``f`` compares against, converted on the host
+        (``literals.leaf_literals``): one entry per literal, None for an
+        absent bound; None when the leaf has no scalar literals. Traced
+        scalars where the program takes them as an operand, else Python
+        constants."""
+        if self.operands is not None:
+            got = self.operands.of(f)
+            if got is not None:
+                return got
+        lits = L.leaf_literals(f, self.ds, self.tz)
+        return None if lits is None else tuple(
+            None if x is None else x[0] for x in lits)
+
+    def interval_literals(self, intervals):
+        """[(day_lo, ms_lo, day_hi, ms_hi)] of the residual time mask."""
+        if self.operands is not None:
+            got = self.operands.of_intervals()
+            if got is not None:
+                return got
+        return L.interval_literals(intervals, self.ds, self.min_day,
+                                   self.max_day)
 
     # -- device array access --------------------------------------------------
     def col(self, name: str):

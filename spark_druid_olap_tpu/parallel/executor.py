@@ -22,6 +22,7 @@ knob).
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import time as _time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -39,6 +40,7 @@ from spark_druid_olap_tpu.ops import groupby as G
 from spark_druid_olap_tpu.ops import hash_groupby as H
 from spark_druid_olap_tpu.ops import hll as HLL
 from spark_druid_olap_tpu.ops import kll as KLL
+from spark_druid_olap_tpu.ops import literals as L
 from spark_druid_olap_tpu.ops import pallas_groupby as PG_tpu
 from spark_druid_olap_tpu.ops import sorted_groupby as SG
 from spark_druid_olap_tpu.ops import theta as TH
@@ -122,6 +124,9 @@ class DimPlan:
     build: object            # ctx -> int32 codes in [0, card)
     decode: object           # np.ndarray[int] -> np.ndarray of output values
     source_cols: tuple
+    # cardinality and buckets come from the SELECTED segments' day range,
+    # so the range is part of the program's signature
+    reads_days: bool = False
 
 
 def _with_null_slot(build, decode, card, name, nullable):
@@ -233,6 +238,7 @@ def _plan_time_extraction(dspec: S.DimensionSpec, ds: Datasource,
         ms_build = lambda ctx: None
 
     field = ex.field
+    reads_days = kind == ColumnKind.TIME
     if field.startswith("trunc_"):
         grain = field[len("trunc_"):]
         def build(ctx, grain=grain):
@@ -245,7 +251,8 @@ def _plan_time_extraction(dspec: S.DimensionSpec, ds: Datasource,
             lo_day, hi_day)
         decode = lambda idx: np.array([decode1(i) for i in np.asarray(idx)],
                                       dtype="datetime64[ms]")
-        return DimPlan(dspec.output_name, card, build, decode, (name,))
+        return DimPlan(dspec.output_name, card, build, decode, (name,),
+                       reads_days)
     if field == "year":
         y_lo = host_eval._civil(np.array([lo_day]))[0][0]
         y_hi = host_eval._civil(np.array([hi_day]))[0][0]
@@ -255,7 +262,7 @@ def _plan_time_extraction(dspec: S.DimensionSpec, ds: Datasource,
             return T.extract_field("year", days) - int(y_lo)
         return DimPlan(dspec.output_name, card, build,
                        lambda idx: np.asarray(idx, np.int64) + int(y_lo),
-                       (name,))
+                       (name,), reads_days)
     if field == "week":
         lo = (lo_day + 3) // 7
         hi = (hi_day + 3) // 7
@@ -263,7 +270,7 @@ def _plan_time_extraction(dspec: S.DimensionSpec, ds: Datasource,
             return T.extract_field("week", day_build(ctx)) - lo
         return DimPlan(dspec.output_name, hi - lo + 1, build,
                        lambda idx: ((np.asarray(idx, np.int64) + lo) * 7 - 3)
-                       .astype("datetime64[D]"), (name,))
+                       .astype("datetime64[D]"), (name,), reads_days)
     if field in _FIELD_CARDS:
         f_lo, f_hi = _FIELD_CARDS[field]
         needs_ms = field in ("hour", "minute", "second")
@@ -273,7 +280,8 @@ def _plan_time_extraction(dspec: S.DimensionSpec, ds: Datasource,
             return T.extract_field(field, day_build(ctx),
                                    ms_build(ctx)) - f_lo
         return DimPlan(dspec.output_name, f_hi - f_lo + 1, build,
-                       lambda idx: np.asarray(idx, np.int64) + f_lo, (name,))
+                       lambda idx: np.asarray(idx, np.int64) + f_lo, (name,),
+                       reads_days)
     raise EngineFallback(f"time extraction field {field}")
 
 
@@ -310,7 +318,8 @@ def plan_granularity_dim(gran: S.Granularity, ds: Datasource, min_day: int,
 
     decode = lambda idx: np.array([decode1(i) for i in np.asarray(idx)],
                                   dtype="datetime64[ms]")
-    return DimPlan("timestamp", card, build, decode, (tname,))
+    return DimPlan("timestamp", card, build, decode, (tname,),
+                   reads_days=True)
 
 
 def _plan_expr_extraction(dspec: S.DimensionSpec, ds: Datasource,
@@ -702,6 +711,7 @@ class QueryEngine:
         self._programs: Dict[tuple, object] = {}   # compile cache
         self._compiling: Dict[tuple, object] = {}  # sig -> in-flight Event
         self._compact_overflowed: set = set()      # shapes whose budget blew
+        self._literal_plans: Dict[tuple, tuple] = {}  # spec -> its literals
         self._device_arrays: Dict[tuple, object] = {}
         self._device_bytes = 0
         self._cancel_flags: Dict[str, object] = {}
@@ -1133,6 +1143,8 @@ class QueryEngine:
                 routes = self._plan_agg(ds, seg_idx, dimensions,
                                         aggregations, granularity,
                                         filter_spec, intervals)
+        lits, days = self._plan_literals(q, ds, all_dim_plans, min_day,
+                                         max_day)
         cards = [p.card for p in all_dim_plans]
 
         if bool(self.config.get(SHAREDSCAN_FUSION_ENABLED)):
@@ -1170,7 +1182,7 @@ class QueryEngine:
             return self._run_agg_hashed(
                 q, ds, seg_idx, all_dim_plans, agg_plans, names, min_day,
                 max_day, post_aggregations, having, limit, filter_spec,
-                intervals, t0, no_topk=no_topk)
+                intervals, t0, no_topk=no_topk, lits=lits, days=days)
 
         sharded = self._should_shard(q, ds, seg_idx)
         n_dev = mesh_size(self.mesh) if sharded else 1
@@ -1200,8 +1212,10 @@ class QueryEngine:
         n_out = topk[1] if topk else n_keys
 
         top_idx = None
-        base_sig = (ds.name, id(ds), _cache_repr(q), s_pad, ds.padded_rows,
-                    min_day, max_day, sharded, n_dev, tuple(names),
+        # the statement's SHAPE, not its literal values; the selected
+        # segments' day range only where the program is built from it
+        base_sig = (ds.name, id(ds), lits.shape, s_pad, ds.padded_rows,
+                    days, sharded, n_dev, tuple(names),
                     self.config.get(TZ_ID),
                     self.config.get(GROUPBY_MATMUL_MAX_KEYS),
                     self.config.get(HLL_LOG2M),
@@ -1217,10 +1231,9 @@ class QueryEngine:
             progA = self._cached_program(
                 sigA, lambda: self._build_agg_table_program(
                     ds, all_dim_plans, agg_plans, filter_spec, intervals,
-                    min_day, max_day, n_keys, sharded, routes,
-                    having_dev))
+                    days, n_keys, sharded, routes, having_dev, lits))
             dev_arrays = self._bind_arrays(ds, names, seg_idx, s_pad,
-                                           sharded)
+                                           sharded, lits)
             if t0 is not None:
                 self._stage_check(q, t0)
             self._tick()
@@ -1263,20 +1276,21 @@ class QueryEngine:
                                              n_dev=n_dev,
                                              allow_sharded=True,
                                              n_keys=n_keys)
-            if compact_m and ("agg", base_sig, topk) \
+            if compact_m and ("agg", base_sig, topk, _cache_repr(q)) \
                     in self._compact_overflowed:
-                compact_m = None     # this shape overflowed before: the
-                # estimate is structurally off for it, don't re-pay the
-                # double execution on every warm run
+                compact_m = None     # this statement overflowed before: the
+                # estimate is structurally off for its values (learned
+                # state is keyed by them, not by the shape), don't re-pay
+                # the double execution on every warm run
             for cm in ((compact_m, None) if compact_m else (None,)):
                 prog_fn, unpack = self._cached_program(
                     ("agg", base_sig, topk, cm),
                     lambda cm=cm: self._build_agg_program(
                         ds, all_dim_plans, agg_plans, filter_spec,
-                        intervals, min_day, max_day, n_keys, sharded,
-                        routes, topk=topk, compact_m=cm))
+                        intervals, days, n_keys, sharded,
+                        routes, topk=topk, compact_m=cm, lits=lits))
                 dev_arrays = self._bind_arrays(ds, names, seg_idx, s_pad,
-                                               sharded)
+                                               sharded, lits)
                 if t0 is not None:
                     self._stage_check(q, t0)  # pre-dispatch boundary
                 self._tick()
@@ -1295,7 +1309,8 @@ class QueryEngine:
                 # to the uncompacted program
                 self.last_stats["compact_overflow"] = \
                     int(np.asarray(over).reshape(-1)[0])
-                self._compact_overflowed.add(("agg", base_sig, topk))
+                self._compact_overflowed.add(
+                    ("agg", base_sig, topk, _cache_repr(q)))
             finals = _finals_from_out(out, routes, n_out, sketch_plans)
             if topk:
                 top_idx = np.asarray(out["__topk_idx__"]).astype(np.int64)
@@ -1310,24 +1325,26 @@ class QueryEngine:
             compact_m = self._plan_compact_m(
                 ds, seg_idx[:spw], cheap_f0, sharded, routes=routes,
                 n_dev=n_dev, allow_sharded=True, n_keys=n_keys)
-            if compact_m and ("aggw", base_sig) in self._compact_overflowed:
+            if compact_m and ("aggw", base_sig, _cache_repr(q)) \
+                    in self._compact_overflowed:
                 compact_m = None
             for cm in ((compact_m, None) if compact_m else (None,)):
                 prog_fn, unpack = self._cached_program(
                     ("agg", base_sig, None, cm),
                     lambda cm=cm: self._build_agg_program(
                         ds, all_dim_plans, agg_plans, filter_spec,
-                        intervals, min_day, max_day, n_keys, sharded,
-                        routes, topk=None, compact_m=cm))
+                        intervals, days, n_keys, sharded,
+                        routes, topk=None, compact_m=cm, lits=lits))
                 finals, wave_over = self._run_waves(
                     q, ds, names, seg_idx, spw, sharded, prog_fn, unpack,
-                    routes, n_keys, sketch_plans, t0)
+                    routes, n_keys, sketch_plans, t0, lits)
                 if not wave_over:
                     if cm:
                         self.last_stats["compact_m"] = int(cm)
                     break
                 self.last_stats["compact_overflow"] = int(wave_over)
-                self._compact_overflowed.add(("aggw", base_sig))
+                self._compact_overflowed.add(
+                    ("aggw", base_sig, _cache_repr(q)))
 
         # --- decode -----------------------------------------------------------
         with PH.phase("decode"):
@@ -1406,6 +1423,39 @@ class QueryEngine:
             "topk_device": int(topk[1]) if topk else 0,
             "having_device": int(n_out) if having_dev else 0})
         return QueryResult(columns, data)
+
+    def _plan_literals(self, q, ds, dim_plans, min_day, max_day):
+        """(the statement's literal plan, the day range its program's
+        signature carries or None). Filter literals are resolved on the
+        host here (``bind.operands``) and reach the program as an
+        operand, so the signature holds the statement's shape
+        (``lits.shape``) and every draw of a template is one program.
+        The selected segments' day range stays in the signature only
+        where something traced is built from it: a time-derived
+        dimension, or any session that is not UTC (instants shift
+        through a per-day offset table)."""
+        tz = self.config.get(TZ_ID)
+        with PH.phase("bind"), PH.phase("bind.operands"):
+            # a repeated text reaches here as the statement memo's plan
+            # (the same objects under a new per-request context), so its
+            # resolved literals, shape and packed words are kept with it
+            key = _spec_identity(q)
+            held = (q, ds, self.store.datasource_version(ds.name), tz,
+                    min_day, max_day)
+            hit = self._literal_plans.get(key)
+            if hit is not None and hit[0][1] is ds \
+                    and hit[0][2:] == held[2:]:
+                lits = hit[1]
+            else:
+                lits = L.LiteralPlan(ds, q.filter, q.aggregations,
+                                     q.intervals, tz, min_day, max_day)
+                lits.shape = lits.shape_repr(q)
+                if key is not None:
+                    _memo_put_bounded(self._literal_plans, key,
+                                      (held, lits), _LITERAL_PLANS_MAX)
+        days = (min_day, max_day) if not TZ.is_utc(tz) \
+            or any(p.reads_days for p in dim_plans) else None
+        return lits, days
 
     @staticmethod
     def _split_filter_staged(f):
@@ -1603,7 +1653,8 @@ class QueryEngine:
     # -- hashed high-cardinality aggregation path -----------------------------
     def _run_agg_hashed(self, q, ds, seg_idx, dim_plans, agg_plans, names,
                         min_day, max_day, post_aggregations, having, limit,
-                        filter_spec, intervals, t0, no_topk: bool = False):
+                        filter_spec, intervals, t0, no_topk: bool = False,
+                        *, lits, days):
         """Group-by above the dense key-space ceiling: fixed-size device hash
         table per chip/wave (ops/hash_groupby.py), partials merged by *key*
         on host. Table overflow retries at 4x slots, then falls back.
@@ -1716,8 +1767,8 @@ class QueryEngine:
                 routes = G.plan_routes(
                     metas, T, self.config.get(GROUPBY_MATMUL_MAX_KEYS),
                     n_rows=n_rows_dev)
-            sig = ("hashagg", ds.name, id(ds), _cache_repr(q), s_pad,
-                   ds.padded_rows, min_day, max_day, sharded, n_dev, T,
+            sig = ("hashagg", ds.name, id(ds), lits.shape, s_pad,
+                   ds.padded_rows, days, sharded, n_dev, T,
                    tuple(names), topk, compact, lm, sorted_run,
                    self.config.get(TZ_ID),
                    self.config.get(GROUPBY_MATMUL_MAX_KEYS),
@@ -1730,12 +1781,13 @@ class QueryEngine:
                 if compact or exch:
                     return self._build_hash_table_program(
                         ds, dim_plans, parts, agg_plans, filter_spec,
-                        intervals, min_day, max_day, T, sharded, routes,
-                        compact_m=lm, sorted_run=sorted_run)
+                        intervals, days, T, sharded, routes,
+                        compact_m=lm, sorted_run=sorted_run, lits=lits)
                 return self._build_hash_program(
                     ds, dim_plans, parts, agg_plans, filter_spec,
-                    intervals, min_day, max_day, T, sharded, routes,
-                    topk=topk, compact_m=lm, sorted_run=sorted_run)
+                    intervals, days, T, sharded, routes,
+                    topk=topk, compact_m=lm, sorted_run=sorted_run,
+                    lits=lits)
 
             prog = self._cached_program(sig, build)
 
@@ -1743,13 +1795,13 @@ class QueryEngine:
 
             def bind(i):
                 return self._bind_wave(ds, names, wave_segs[i], s_pad,
-                                       sharding, multihost)
+                                       sharding, multihost, lits)
 
             # cold tier: start loading wave 1's chunks while wave 0
             # binds and computes (load-behind-compute)
             self._tier_prefetch(ds, names, wave_segs, 1)
-            cur = self._bind_arrays(ds, names, seg_idx, s_pad, sharded) \
-                if n_waves == 1 else bind(0)
+            cur = self._bind_arrays(ds, names, seg_idx, s_pad, sharded,
+                                    lits) if n_waves == 1 else bind(0)
             for i in range(len(wave_segs)):
                 if t0 is not None:
                     self._stage_check(q, t0)
@@ -1879,7 +1931,7 @@ class QueryEngine:
                 return self._run_agg_hashed(
                     q, ds, seg_idx, dim_plans, agg_plans, names, min_day,
                     max_day, post_aggregations, having, limit, filter_spec,
-                    intervals, t0, no_topk=True)
+                    intervals, t0, no_topk=True, lits=lits, days=days)
 
         self.last_stats.update({
             "datasource": ds.name, "segments": int(n_seg_sel),
@@ -1924,8 +1976,8 @@ class QueryEngine:
         return (oc.name, _topk_slack(limit), bool(oc.ascending))
 
     def _hash_core(self, ds, dim_plans, parts, agg_plans, filter_spec,
-                   intervals, min_day, max_day, T, routes,
-                   compact_m=None, sorted_run=False):
+                   intervals, days, T, routes,
+                   compact_m=None, sorted_run=False, *, lits):
         """The shared hash scan body: scan -> filter -> per-dim codes ->
         two-part key -> slot claim -> exact scatter aggregation into [T]
         buffers. Returns the raw out dict incl. '__tkhi__'/'__tklo__' key
@@ -1938,11 +1990,13 @@ class QueryEngine:
         cheap_f, exp_f = (self._split_filter_staged(filter_spec)
                           if compact_m else (filter_spec, None))
         fuse_cse = bool(self.config.get(SHAREDSCAN_FUSION_ENABLED))
+        min_day, max_day = days or (None, None)
 
         @jax.named_scope("sdot_hashed_groupby")
         def core(arrays):
+            operands = _operands(lits, arrays)
             ctx = ScanContext(ds, arrays, min_day, max_day,
-                              tz=self.config.get(TZ_ID))
+                              tz=self.config.get(TZ_ID), operands=operands)
             # same trace-time predicate CSE as the dense core
             cse = FU.CSECache(ctx) if fuse_cse else None
             base = ctx.row_valid()
@@ -1964,7 +2018,8 @@ class QueryEngine:
                 n_over = jnp.maximum(
                     n_live - jnp.int32(compact_m), 0).astype(jnp.int32)
                 ctx = CompactScanContext(ds, arrays, min_day, max_day,
-                                         self.config.get(TZ_ID), keep=keep)
+                                         self.config.get(TZ_ID),
+                                         operands=operands, keep=keep)
                 cse = FU.CSECache(ctx) if fuse_cse else None
                 base = flat[keep]
                 if exp_f is not None:
@@ -2122,17 +2177,18 @@ class QueryEngine:
         return named_jit(name, smfn)
 
     def _build_hash_program(self, ds, dim_plans, parts, agg_plans,
-                            filter_spec, intervals, min_day, max_day, T,
+                            filter_spec, intervals, days, T,
                             sharded, routes, topk=None, compact_m=None,
-                            sorted_run=False):
+                            sorted_run=False, *, lits):
         """Single-dispatch hash program (full-table or topk-gathered
         transfer). Outputs stay per-chip in sharded mode (slot layouts
         differ per chip; the key-wise merge is host-side). With ``topk``
         only the top-scored ``k_sel`` slots per chip travel (see
         _plan_device_topk_hashed)."""
         core = self._hash_core(ds, dim_plans, parts, agg_plans, filter_spec,
-                               intervals, min_day, max_day, T, routes,
-                               compact_m=compact_m, sorted_run=sorted_run)
+                               intervals, days, T, routes,
+                               compact_m=compact_m, sorted_run=sorted_run,
+                               lits=lits)
         k_out = topk[1] if topk else T
         pack, unpack = self._hash_packers(agg_plans, routes, k_out, True,
                                           with_score=bool(topk))
@@ -2152,15 +2208,16 @@ class QueryEngine:
                                 P(SEGMENT_AXIS)), unpack
 
     def _build_hash_table_program(self, ds, dim_plans, parts, agg_plans,
-                                  filter_spec, intervals, min_day, max_day,
+                                  filter_spec, intervals, days,
                                   T, sharded, routes, compact_m=None,
-                                  sorted_run=False):
+                                  sorted_run=False, *, lits):
         """Compaction dispatch 1 of 2: build the table, leave it DEVICE-
         RESIDENT, transfer only '__stats__' = [unresolved, occupied] per
         chip. The host sizes the gather dispatch from the occupancy."""
         core = self._hash_core(ds, dim_plans, parts, agg_plans, filter_spec,
-                               intervals, min_day, max_day, T, routes,
-                               compact_m=compact_m, sorted_run=sorted_run)
+                               intervals, days, T, routes,
+                               compact_m=compact_m, sorted_run=sorted_run,
+                               lits=lits)
 
         def run(arrays):
             out = core(arrays)
@@ -2342,7 +2399,7 @@ class QueryEngine:
                                 P(SEGMENT_AXIS)), unpack
 
     def _run_waves(self, q, ds, names, seg_idx, spw, sharded, prog_fn,
-                   unpack, routes, n_keys, sketch_plans, t0):
+                   unpack, routes, n_keys, sketch_plans, t0, lits):
         """Execute the scan in bounded segment waves (double-buffered: the
         next wave's host->device transfer overlaps the current wave's
         compute), merging each wave's [K] finals on host. ≈ the reference's
@@ -2356,7 +2413,8 @@ class QueryEngine:
 
         def bind(w):
             # no caching: wave mode exists because the scan exceeds HBM
-            return self._bind_wave(ds, names, w, spw, sharding, multihost)
+            return self._bind_wave(ds, names, w, spw, sharding, multihost,
+                                   lits)
 
         finals = None
         # cold tier: wave 1's chunks load while wave 0 binds + computes
@@ -2457,17 +2515,26 @@ class QueryEngine:
         if n_keys > self.config.get(GROUPBY_DENSE_MAX_KEYS):
             raise EngineFallback(
                 f"core build is dense-only (key cardinality {n_keys})")
+        lits, days = self._plan_literals(q, ds, dim_plans, min_day, max_day)
         n_dev = mesh_size(self.mesh)
         s_pad = _pad_segments(len(seg_idx), n_dev)
         arrays = {k: _build_array_checked(ds, k, seg_idx, s_pad)
                   for k in names}
+        if lits.count:
+            arrays[L.LITERALS_KEY] = lits.pack()
         fn = self._make_core(ds, dim_plans, agg_plans, q.filter, q.intervals,
-                             min_day, max_day, n_keys, routes)
+                             days, n_keys, routes, lits=lits)
         return fn, arrays
 
     def _make_core(self, ds, dim_plans, agg_plans, filter_spec,
-                   intervals, min_day, max_day, n_keys, routes,
-                   compact_m=None):
+                   intervals, days, n_keys, routes,
+                   compact_m=None, *, lits):
+        """``days``: the selected segments' (min_day, max_day), or None
+        where the signature does not carry them — then nothing traced
+        may read them. ``lits``: the building statement's literal plan;
+        the filters read their literals from the operand bound under
+        ``L.LITERALS_KEY``."""
+        min_day, max_day = days or (None, None)
         matmul_max = self.config.get(GROUPBY_MATMUL_MAX_KEYS)
         log2m = self.config.get(HLL_LOG2M)
         kll_lanes = self.config.get(QUANTILE_LANES)
@@ -2482,8 +2549,9 @@ class QueryEngine:
         fuse_cse = bool(self.config.get(SHAREDSCAN_FUSION_ENABLED))
 
         def core(arrays):
+            operands = _operands(lits, arrays)
             ctx = ScanContext(ds, arrays, min_day, max_day,
-                              tz=self.config.get(TZ_ID))
+                              tz=self.config.get(TZ_ID), operands=operands)
             # trace-time predicate CSE: one query's tree can repeat
             # sub-predicates (OR-of-bounds over one column, a selector
             # shared by every filtered aggregation) — memoized lowering
@@ -2514,7 +2582,8 @@ class QueryEngine:
                 n_over = jnp.maximum(
                     n_live - jnp.int32(compact_m), 0).astype(jnp.int32)
                 ctx = CompactScanContext(ds, arrays, min_day, max_day,
-                                         self.config.get(TZ_ID), keep=keep)
+                                         self.config.get(TZ_ID),
+                                         operands=operands, keep=keep)
                 # the compacted context changes every mask's shape: the
                 # full-width CSE entries must never leak past this point
                 cse = FU.CSECache(ctx) if fuse_cse else None
@@ -2568,8 +2637,8 @@ class QueryEngine:
         return core
 
     def _build_agg_program(self, ds, dim_plans, agg_plans, filter_spec,
-                           intervals, min_day, max_day, n_keys, sharded,
-                           routes, topk=None, compact_m=None):
+                           intervals, days, n_keys, sharded,
+                           routes, topk=None, compact_m=None, *, lits):
         """Returns (jit_fn, unpack).
 
         The program packs outputs into TWO flat device buffers so the host
@@ -2592,8 +2661,8 @@ class QueryEngine:
         topN threshold).
         """
         core = self._make_core(ds, dim_plans, agg_plans, filter_spec,
-                               intervals, min_day, max_day, n_keys, routes,
-                               compact_m=compact_m)
+                               intervals, days, n_keys, routes,
+                               compact_m=compact_m, lits=lits)
         hll_plans = [p for p in agg_plans if p.kind == "hll"]
         theta_plans = [p for p in agg_plans if p.kind == "theta"]
         kll_plans = [p for p in agg_plans if p.kind == "kll"]
@@ -2686,6 +2755,7 @@ class QueryEngine:
         A second thread wanting the SAME signature waits on the owner's
         event instead of compiling twice."""
         prog = self._programs.get(sig)
+        built = False
         while prog is None:
             with self._compile_lock:
                 prog = self._programs.get(sig)
@@ -2702,6 +2772,7 @@ class QueryEngine:
                         prog = build()
                     with self._compile_lock:
                         self._programs[sig] = prog
+                    built = True
                 finally:
                     with self._compile_lock:
                         self._compiling.pop(sig, None)
@@ -2710,6 +2781,15 @@ class QueryEngine:
             ev.wait()
             prog = self._programs.get(sig)
             # owner failed (exception): loop claims ownership and retries
+        # the statement's record names its (first, scan) program by the
+        # signature's digest and says whether this statement built any
+        st = self.last_stats.get("program")
+        if st is None:
+            st = self.last_stats["program"] = {
+                "sig": hashlib.blake2s(repr(sig).encode(),
+                                       digest_size=4).hexdigest(),
+                "operands": 0, "built": False}
+        st["built"] = st["built"] or built
         return prog
 
     def _plan_device_having(self, having, routes, agg_plans, n_keys,
@@ -2771,14 +2851,15 @@ class QueryEngine:
         return m & occ
 
     def _build_agg_table_program(self, ds, dim_plans, agg_plans,
-                                 filter_spec, intervals, min_day, max_day,
-                                 n_keys, sharded, routes, having_dev):
+                                 filter_spec, intervals, days,
+                                 n_keys, sharded, routes, having_dev,
+                                 lits):
         """HAVING-compaction dispatch 1 of 2: scan + merge, leave the
         finals DEVICE-RESIDENT, compute the exact having mask and transfer
         only its count. ≈ Druid evaluating HavingSpec on the data node
         instead of shipping every group to the broker."""
         core = self._make_core(ds, dim_plans, agg_plans, filter_spec,
-                               intervals, min_day, max_day, n_keys, routes)
+                               intervals, days, n_keys, routes, lits=lits)
         hll_plans = [p for p in agg_plans if p.kind == "hll"]
         theta_plans = [p for p in agg_plans if p.kind == "theta"]
         kll_plans = [p for p in agg_plans if p.kind == "kll"]
@@ -3272,7 +3353,8 @@ class QueryEngine:
         self.last_stats["cost_sharded"] = est.sharded_cost
         return est.recommend_sharded
 
-    def _bind_wave(self, ds, names, w, s_pad, sharding, multihost):
+    def _bind_wave(self, ds, names, w, s_pad, sharding, multihost,
+                   lits=None):
         """Uncached per-wave bind (wave mode exists because the scan
         exceeds the device budget). Multi-host: each process provides only
         the shards its devices own — the wave layout is host-blocked
@@ -3290,12 +3372,34 @@ class QueryEngine:
                     out[k] = MH.put_sharded_blocks(
                         lambda ids, k=k: build_array_blocks(ds, k, ids),
                         w, ds.padded_rows, dt, sharding)
-                return out
-            return {k: _device_put_retry(
-                _build_array_checked(ds, k, w, s_pad), sharding)
-                for k in names}
+            else:
+                out = {k: _device_put_retry(
+                    _build_array_checked(ds, k, w, s_pad), sharding)
+                    for k in names}
+            return self._bind_literals(out, lits, sharding, multihost)
 
-    def _bind_arrays(self, ds, names, seg_idx, s_pad, sharded):
+    def _bind_literals(self, out, lits, sharding, multihost):
+        """``bind.operands``: the statement's literal operand, packed and
+        put beside the columns. On one device the packed words go to the
+        program call as they are and its launch uploads them (+0.12 ms of
+        ``dispatch.launch`` on a v5e; a ``jax.device_put`` of their own
+        costs 0.25 ms of host time and 0.53 ms until ready — PERF.md §6,
+        PR 28). A mesh gets the row once per shard, placed like the
+        columns, so their partition spec fits it too. A few dozen bytes;
+        not one of the column uploads ``n_transfer`` counts."""
+        if lits is not None and lits.count:
+            with PH.phase("bind.operands"):
+                words = lits.pack()
+                if sharding is not None:
+                    rows = np.tile(words, (mesh_size(self.mesh), 1))
+                    words = jax.make_array_from_callback(
+                        rows.shape, sharding, lambda idx: rows[idx]) \
+                        if multihost else _device_put_retry(rows, sharding)
+                out[L.LITERALS_KEY] = words
+            self.last_stats["program"]["operands"] = lits.count
+        return out
+
+    def _bind_arrays(self, ds, names, seg_idx, s_pad, sharded, lits=None):
         """Fetch-or-build the device arrays a program binds. Cached per
         (datasource, array, segment selection, layout) so repeated dashboard
         queries never re-upload host data (≈ segments staying resident on
@@ -3307,8 +3411,11 @@ class QueryEngine:
         builder per locally-addressable device, so no process ever
         materializes (or ships) another host's rows."""
         with PH.phase("bind"):
-            return self._bind_arrays_inner(ds, names, seg_idx, s_pad,
-                                           sharded)
+            out = self._bind_arrays_inner(ds, names, seg_idx, s_pad,
+                                          sharded)
+            return self._bind_literals(
+                out, lits, NamedSharding(self.mesh, P(SEGMENT_AXIS, None))
+                if sharded else None, sharded and MH.is_multihost())
 
     def _bind_arrays_inner(self, ds, names, seg_idx, s_pad, sharded):
         sharding = NamedSharding(self.mesh, P(SEGMENT_AXIS, None)) \
@@ -3377,6 +3484,7 @@ class QueryEngine:
         with self._compile_lock:
             self._programs.clear()
             self._compact_overflowed.clear()
+            self._literal_plans.clear()
             self._device_arrays.clear()
             self._device_bytes = 0
         self.result_cache.clear()
@@ -3396,6 +3504,42 @@ def _cache_repr(q) -> str:
         return repr(dataclasses.replace(q, context=None))
     except Exception:  # noqa: BLE001 — non-dataclass/frozen edge
         return repr(q)
+
+
+_LITERAL_PLANS_MAX = 512     # specs whose literal plan is kept (a few KB each)
+
+
+def _spec_identity(q) -> Optional[tuple]:
+    """Which spec this is, apart from its per-request context: the
+    session stamps a query id on a copy of the memoised plan, and the
+    copy's other fields are the plan's own objects. An entry that holds
+    ``q`` keeps them alive, so an id names one object for as long as it
+    is a key."""
+    try:
+        return (type(q),) + tuple(
+            id(getattr(q, f.name)) for f in dataclasses.fields(q)
+            if f.name != "context")
+    except TypeError:        # not a dataclass: resolve every time
+        return None
+
+
+def _memo_put_bounded(memo: dict, key, value, bound: int) -> None:
+    """Insert, dropping the oldest entries past ``bound``. Lock-free:
+    statements run in parallel and two may evict at once."""
+    memo[key] = value
+    while len(memo) > bound:
+        try:
+            memo.pop(next(iter(memo)), None)
+        except (StopIteration, RuntimeError):   # emptied / resized under us
+            break
+
+
+def _operands(lits, arrays):
+    """The trace-time reader of a program's literal operand, or None
+    where the statement has no slotted literal."""
+    if not lits.count:
+        return None
+    return L.Operands(lits, arrays[L.LITERALS_KEY])
 
 
 _COMPILE_MARKERS = ("mosaic", "compil")

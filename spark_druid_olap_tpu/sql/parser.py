@@ -16,11 +16,23 @@ Table aliases are tracked on the relations themselves.
 
 from __future__ import annotations
 
+import calendar
+import datetime as _dt
 from typing import List, Optional, Tuple
 
 from spark_druid_olap_tpu.ir import expr as E
 from spark_druid_olap_tpu.sql import ast as A
 from spark_druid_olap_tpu.sql.lexer import SqlSyntaxError, Token, tokenize
+
+
+def _shift_date(d: _dt.date, n: int, days: bool, months: int) -> _dt.date:
+    """``d`` + ``n`` days, or + ``months`` months with the day of the
+    month clamped to the target month's length (``add_months``, as
+    ``utils/host_eval.py`` and ``ops/expr_compile.py`` compute it)."""
+    if days:
+        return d + _dt.timedelta(days=n)
+    y, m = divmod(d.year * 12 + d.month - 1 + months, 12)
+    return _dt.date(y, m + 1, min(d.day, calendar.monthrange(y, m + 1)[1]))
 
 AGG_FUNCS = {"sum", "min", "max", "avg", "count"}
 
@@ -602,10 +614,18 @@ class Parser:
             unit = right.args[1].value
             if op == "-":
                 n = -n
+            months = n * (12 if unit == "year" else 1)
+            if isinstance(left, E.Literal) \
+                    and type(left.value) is _dt.date and isinstance(n, int):
+                # a date literal +/- an interval IS a date literal: the
+                # planner then sees `col <= date` (a bound it can prune
+                # segments by, and whose value a program takes as an
+                # operand) instead of an opaque expression
+                return E.Literal(_shift_date(left.value, n, unit == "day",
+                                             months))
             if unit == "day":
                 return E.Func("date_add", (left, E.Literal(n)))
-            return E.Func("add_months",
-                          (left, E.Literal(n * (12 if unit == "year" else 1))))
+            return E.Func("add_months", (left, E.Literal(months)))
         return E.BinaryOp(op, left, right)
 
     def parse_multiplicative(self) -> E.Expr:

@@ -325,6 +325,146 @@ def setup_context(ctx, sf: float = 0.01, seed: int = 20260729,
     return tables, flat
 
 
+# -- query templates (TPC-H spec clause 2.4.x.3: substitution parameters) -----
+
+# The five power-stream statements with named holes. ``QUERIES[q]`` is
+# ``render(q, VALIDATION_PARAMETERS[q])``: the spec's validation values
+# give the fixed spelling every other user of ``QUERIES`` sees.
+TEMPLATES: Dict[str, str] = {
+    "q1": """
+        select l_returnflag, l_linestatus,
+               sum(l_quantity) as sum_qty,
+               sum(l_extendedprice) as sum_base_price,
+               sum(l_extendedprice * (1 - l_discount)) as sum_disc_price,
+               sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) as sum_charge,
+               avg(l_quantity) as avg_qty,
+               avg(l_extendedprice) as avg_price,
+               avg(l_discount) as avg_disc,
+               count(*) as count_order
+        from lineitem
+        where l_shipdate <= date '1998-12-01' - interval '{delta}' day
+        group by l_returnflag, l_linestatus
+        order by l_returnflag, l_linestatus
+    """,
+    "q3": """
+        select o_orderkey, sum(l_extendedprice * (1 - l_discount)) as revenue,
+               o_orderdate, o_shippriority
+        from customer c join orders o on c.c_custkey = o.o_custkey
+             join lineitem l on l.l_orderkey = o.o_orderkey
+        where c_mktsegment = '{segment}'
+              and o_orderdate < date '{date}'
+              and l_shipdate > date '{date}'
+        group by o_orderkey, o_orderdate, o_shippriority
+        order by revenue desc, o_orderdate
+        limit 10
+    """,
+    "q5": """
+        select sn_name, sum(l_extendedprice * (1 - l_discount)) as revenue
+        from customer c join orders o on c.c_custkey = o.o_custkey
+             join lineitem l on l.l_orderkey = o.o_orderkey
+             join supplier s on l.l_suppkey = s.s_suppkey
+             join suppnation n on s.s_nationkey = n.sn_nationkey
+             join suppregion r on n.sn_regionkey = r.sr_regionkey
+        where sr_name = '{region}'
+              and o_orderdate >= date '{date}'
+              and o_orderdate < date '{date_end}'
+        group by sn_name
+        order by revenue desc
+    """,
+    "q6": """
+        select sum(l_extendedprice * l_discount) as revenue
+        from lineitem
+        where l_shipdate >= date '{date}'
+              and l_shipdate < date '{date_end}'
+              and l_discount between {discount_lo} and {discount_hi}
+              and l_quantity < {quantity}
+    """,
+    "q12": """
+        select l_shipmode,
+               sum(case when o_orderpriority = '1-URGENT'
+                        or o_orderpriority = '2-HIGH' then 1 else 0 end)
+                   as high_line_count,
+               sum(case when o_orderpriority <> '1-URGENT'
+                        and o_orderpriority <> '2-HIGH' then 1 else 0 end)
+                   as low_line_count
+        from orders o join lineitem l on o.o_orderkey = l.l_orderkey
+        where l_shipmode in ('{shipmode1}', '{shipmode2}')
+              and l_receiptdate >= date '{date}'
+              and l_receiptdate < date '{date_end}'
+        group by l_shipmode
+        order by l_shipmode
+    """,
+}
+
+# the spec's validation values (clauses 2.4.1.3, 2.4.3.3, 2.4.5.3, 2.4.6.3,
+# 2.4.12.3)
+VALIDATION_PARAMETERS: Dict[str, Dict[str, object]] = {
+    "q1": {"delta": 90},
+    "q3": {"segment": "BUILDING", "date": "1995-03-15"},
+    "q5": {"region": "ASIA", "date": "1994-01-01"},
+    "q6": {"date": "1994-01-01", "discount": 0.06, "quantity": 24},
+    "q12": {"shipmode1": "MAIL", "shipmode2": "SHIP", "date": "1994-01-01"},
+}
+
+
+def substitution_parameters(template: str, rng) -> Dict[str, object]:
+    """One draw of ``template``'s substitution parameters by the spec's
+    rules (what ``qgen`` does per stream); ``rng`` is a
+    ``random.Random``. DATE of q5/q6/q12 is the first of January of a
+    year in [1993, 1997]."""
+    if template == "q1":
+        return {"delta": rng.randint(60, 120)}
+    if template == "q3":
+        return {"segment": rng.choice(SEGMENTS),
+                "date": f"1995-03-{rng.randint(1, 31):02d}"}
+    if template == "q5":
+        return {"region": rng.choice(REGIONS),
+                "date": f"{rng.randint(1993, 1997)}-01-01"}
+    if template == "q6":
+        return {"date": f"{rng.randint(1993, 1997)}-01-01",
+                "discount": rng.randint(2, 9) / 100,
+                "quantity": rng.randint(24, 25)}
+    if template == "q12":
+        m1, m2 = rng.sample(SHIPMODES, 2)
+        return {"shipmode1": m1, "shipmode2": m2,
+                "date": f"{rng.randint(1993, 1997)}-01-01"}
+    raise KeyError(f"no template {template!r} (have {sorted(TEMPLATES)})")
+
+
+def render(template: str, params: Dict[str, object]) -> str:
+    """The statement text of one draw. Derived holes: the interval's end
+    (DATE + 1 year) and q6's ``between DISCOUNT - 0.01 and DISCOUNT +
+    0.01``, spelled with two decimals as the spec spells them."""
+    p = dict(params)
+    if "date" in p and template != "q3":
+        year, rest = p["date"].split("-", 1)
+        p["date_end"] = f"{int(year) + 1}-{rest}"
+    if "discount" in p:
+        p["discount_lo"] = f"{p['discount'] - 0.01:.2f}"
+        p["discount_hi"] = f"{p['discount'] + 0.01:.2f}"
+    return TEMPLATES[template].format(**p)
+
+
+def draw_statements(seed: int, draws: int = 3):
+    """``draws`` statements of every template, drawn in template order
+    from ``random.Random(seed)``: [(class, template, params, sql)] with
+    classes ``<template>_p<i>`` — a pool of texts for a driver that binds
+    one text to one class (``benchmarks/statements/tpch_qgen16.json`` is
+    this list for its recorded seed)."""
+    import random
+    rng = random.Random(seed)
+    out = []
+    for t in TEMPLATES:
+        for i in range(draws):
+            params = substitution_parameters(t, rng)
+            out.append((f"{t}_p{i}", t, params, render(t, params)))
+    return out
+
+
+def _validation(template: str) -> str:
+    return render(template, VALIDATION_PARAMETERS[template])
+
+
 # -- benchmark queries (altered TPC-H, reference BenchMarkDetails.org:69-78) --
 
 QUERIES: Dict[str, str] = {
@@ -357,54 +497,10 @@ QUERIES: Dict[str, str] = {
               and l_shipdate <= date '1995-01-01'
         group by s_nation
     """,
-    "q1": """
-        select l_returnflag, l_linestatus,
-               sum(l_quantity) as sum_qty,
-               sum(l_extendedprice) as sum_base_price,
-               sum(l_extendedprice * (1 - l_discount)) as sum_disc_price,
-               sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) as sum_charge,
-               avg(l_quantity) as avg_qty,
-               avg(l_extendedprice) as avg_price,
-               avg(l_discount) as avg_disc,
-               count(*) as count_order
-        from lineitem
-        where l_shipdate <= date '1998-12-01' - interval '90' day
-        group by l_returnflag, l_linestatus
-        order by l_returnflag, l_linestatus
-    """,
-    "q3": """
-        select o_orderkey, sum(l_extendedprice * (1 - l_discount)) as revenue,
-               o_orderdate, o_shippriority
-        from customer c join orders o on c.c_custkey = o.o_custkey
-             join lineitem l on l.l_orderkey = o.o_orderkey
-        where c_mktsegment = 'BUILDING'
-              and o_orderdate < date '1995-03-15'
-              and l_shipdate > date '1995-03-15'
-        group by o_orderkey, o_orderdate, o_shippriority
-        order by revenue desc, o_orderdate
-        limit 10
-    """,
-    "q5": """
-        select sn_name, sum(l_extendedprice * (1 - l_discount)) as revenue
-        from customer c join orders o on c.c_custkey = o.o_custkey
-             join lineitem l on l.l_orderkey = o.o_orderkey
-             join supplier s on l.l_suppkey = s.s_suppkey
-             join suppnation n on s.s_nationkey = n.sn_nationkey
-             join suppregion r on n.sn_regionkey = r.sr_regionkey
-        where sr_name = 'ASIA'
-              and o_orderdate >= date '1994-01-01'
-              and o_orderdate < date '1995-01-01'
-        group by sn_name
-        order by revenue desc
-    """,
-    "q6": """
-        select sum(l_extendedprice * l_discount) as revenue
-        from lineitem
-        where l_shipdate >= date '1994-01-01'
-              and l_shipdate < date '1995-01-01'
-              and l_discount between 0.05 and 0.07
-              and l_quantity < 24
-    """,
+    "q1": _validation("q1"),
+    "q3": _validation("q3"),
+    "q5": _validation("q5"),
+    "q6": _validation("q6"),
     "q7": """
         select sn_name, cn_name, year(l_shipdate) as l_year,
                sum(l_extendedprice * (1 - l_discount)) as revenue
@@ -451,21 +547,7 @@ QUERIES: Dict[str, str] = {
         order by revenue desc
         limit 20
     """,
-    "q12": """
-        select l_shipmode,
-               sum(case when o_orderpriority = '1-URGENT'
-                        or o_orderpriority = '2-HIGH' then 1 else 0 end)
-                   as high_line_count,
-               sum(case when o_orderpriority <> '1-URGENT'
-                        and o_orderpriority <> '2-HIGH' then 1 else 0 end)
-                   as low_line_count
-        from orders o join lineitem l on o.o_orderkey = l.l_orderkey
-        where l_shipmode in ('MAIL', 'SHIP')
-              and l_receiptdate >= date '1994-01-01'
-              and l_receiptdate < date '1995-01-01'
-        group by l_shipmode
-        order by l_shipmode
-    """,
+    "q12": _validation("q12"),
     "q14": """
         select 100.00 * sum(case when p_type like 'PROMO%'
                                  then l_extendedprice * (1 - l_discount)

@@ -94,6 +94,7 @@ PHASES = {
     "tier.fault": "tiered-store faults on the demand path",
     "tier.decode": "encoded-chunk decode on the demand path",
     "bind": "host->device array binding",
+    "bind.operands": "filter literals -> the program's small operand",
     "dispatch": "device execution + result fetch",
     "dispatch.launch": "enqueue: the compiled program + its outputs' D2H",
     "dispatch.wait": "block_until_ready on what the launch returned",

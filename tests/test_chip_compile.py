@@ -23,6 +23,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from spark_druid_olap_tpu.ir import expr as E
 from spark_druid_olap_tpu.ir import spec as S
 from spark_druid_olap_tpu.ops import groupby as G
 from spark_druid_olap_tpu.ops import pallas_groupby as PG
@@ -260,3 +261,49 @@ def test_wave_program_compiles_under_shard_map(chip_mode, topo, store):
     _assert_kernel(compiled, "sdot_wave")
     assert "all-reduce" in compiled.as_text(), \
         "no interconnect merge in the program"
+
+
+# -- a solo program that takes its filter literals as an operand --------------
+
+def test_literal_operand_program_compiles(chip_mode, one_chip, store):
+    """TPC-H q6's shape on the solo path — an interval on the time
+    column, a float ``between``, an integer bound, one global sum — as
+    the engine builds it since literals became operands: the program's
+    parameters are the columns plus ONE small int32 vector
+    (``ops/literals.py``), the dense kernel reads masks built from it,
+    and the chip's compiler accepts both."""
+    from spark_druid_olap_tpu.ops import literals as L
+    from spark_druid_olap_tpu.ops.scan import array_dtype
+    eng = QueryEngine(store, config=Config({"sdot.wlm.enabled": False}))
+    day = T.MILLIS_PER_DAY
+    lo = int(np.datetime64("2015-03-01").astype("datetime64[D]")
+             .astype(np.int64)) * day
+    q = S.TimeseriesQuerySpec(
+        "sales",
+        (S.AggregationSpec("doublesum", "revenue", expr=E.BinaryOp(
+            "*", E.Column("price"), E.Column("discount"))),),
+        filter=S.LogicalFilter("and", (
+            S.BoundFilter("discount", lower=0.05, upper=0.07, numeric=True),
+            S.BoundFilter("qty", upper=24, upper_strict=True,
+                          numeric=True))),
+        intervals=((lo, lo + 365 * day),))
+    ds = store.get("sales")
+    seg_idx = ds.prune_segments(q.intervals, q.filter)
+    dim_plans, agg_plans, min_day, max_day, n_keys, names, routes = \
+        eng._plan_agg(ds, seg_idx, [], q.aggregations, q.granularity,
+                      q.filter, q.intervals)
+    lits, days = eng._plan_literals(q, ds, dim_plans, min_day, max_day)
+    assert lits.count == 7 and days is None
+    assert "0.05" not in lits.shape and "24" not in lits.shape
+    fn, _ = eng._build_agg_program(
+        ds, dim_plans, agg_plans, q.filter, q.intervals, days, n_keys,
+        False, routes, lits=lits)
+    words = lits.pack()
+    shapes = {k: jax.ShapeDtypeStruct((6, 1 << 20), array_dtype(ds, k),
+                                      sharding=one_chip) for k in names}
+    shapes[L.LITERALS_KEY] = jax.ShapeDtypeStruct(
+        words.shape, words.dtype, sharding=one_chip)
+    compiled = fn.lower(shapes).compile()
+    text = compiled.as_text()
+    assert f"s32[1,{words.shape[1]}]" in text, "no literal operand"
+    _assert_kernel(compiled, "sdot_dense_groupby")

@@ -1,0 +1,534 @@
+"""Filter literals are a program's operand, and a statement's shape — not
+its values — is the program cache's key (``ops/literals.py``).
+
+The differential half: TPC-H templates drawn by the spec's substitution
+rules (``tools/tpch.py``) against the parameterised pandas oracles of
+``benchmarks/references/tpch_qgen.py`` — an independent implementation,
+given the parameters as arguments, that calls nothing of the engine —
+at SF 0.002, under the tolerances of ``benchmarks/configs/
+tpch_sf1_adhoc.json`` (floats 1e-6, the rest exact). The counting half:
+a later draw of a template adds no program and compiles nothing.
+"""
+
+import dataclasses
+import importlib.util
+import json
+import os
+import random
+
+import jax
+import numpy as np
+import pandas as pd
+import pytest
+
+import spark_druid_olap_tpu as sdot
+from spark_druid_olap_tpu.ir import spec as S
+from spark_druid_olap_tpu.ops import literals as L
+from spark_druid_olap_tpu.tools import tpch
+
+from conftest import assert_frames_equal
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RTOL = 1e-6         # benchmarks/configs/tpch_sf1_adhoc.json float_rtol
+TEMPLATES = list(tpch.TEMPLATES)
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _load(os.path.join(REPO, "benchmarks", "references", "tpch_qgen.py"),
+            "tpch_qgen_reference")
+
+
+def _bench_json(*parts):
+    with open(os.path.join(REPO, "benchmarks", *parts)) as f:
+        return json.load(f)
+
+
+def _tpch_context(settings=None):
+    ctx = sdot.Context(settings or {})
+    tables, flat = tpch.setup_context(ctx, sf=0.002, target_rows=4096)
+    data = dict(tables)
+    data.update(tpch.nation_region_views(tables))
+    data["tpch_flat"] = flat
+    return ctx, data
+
+
+@pytest.fixture(scope="module")
+def tenv():
+    return _tpch_context()
+
+
+def _run(ctx, sql):
+    """(frame, the engine's stats of the statement)."""
+    df = ctx.sql(sql).to_pandas()
+    st = ctx.history.entries()[-1].stats
+    assert st["mode"] == "engine", st
+    return df, st
+
+
+def _check(got, want, sort_by=None):
+    assert list(got.columns) == list(want.columns)
+    assert_frames_equal(got, want, sort_by=sort_by, rtol=RTOL, atol=0.0)
+
+
+# -- the templates -------------------------------------------------------------
+
+@pytest.mark.parametrize("template", TEMPLATES)
+def test_draws_match_the_oracle_and_share_their_program(tenv, template):
+    """Six draws a template: every answer equals the oracle's; a draw
+    whose program another draw has built compiles nothing and adds
+    nothing to the program cache. q1, q3, q5 and q12 select the same
+    segments whatever is drawn, so theirs is ONE program; q6's year
+    selects its segments, so it has at most one per selection."""
+    ctx, data = tenv
+    eng = ctx.engine
+    rng = random.Random(2800 + TEMPLATES.index(template))
+    seen, selections = set(), set()
+    for i in range(6):
+        params = tpch.substitution_parameters(template, rng)
+        n0 = len(eng._programs)
+        got, st = _run(ctx, tpch.render(template, params))
+        _check(got, REF.ORACLES[template](data, **params))
+        prog = st["program"]
+        assert prog["operands"] > 0, (params, prog)
+        assert prog["built"] == (prog["sig"] not in seen), (params, prog)
+        if not prog["built"]:
+            assert len(eng._programs) == n0, params
+            assert "compile" not in st["phases"], st["phases"]
+        assert "bind.operands" in {s[0] for s in st["spans"]}
+        seen.add(prog["sig"])
+        selections.add(st["segments"])
+    if template == "q6":
+        assert len(seen) <= len(selections), (seen, selections)
+    else:
+        assert len(seen) == 1, seen
+
+
+def test_validation_draw_is_the_fixed_text_and_its_answer(tenv):
+    """``QUERIES[q]`` is the template at the spec's validation values:
+    the fixed-text statements run the same programs as their draws."""
+    ctx, data = tenv
+    for t in TEMPLATES:
+        _, warm = _run(ctx, tpch.render(
+            t, tpch.substitution_parameters(t, random.Random(1))))
+        got, st = _run(ctx, tpch.QUERIES[t])
+        _check(got, REF.ORACLES[t](data, **tpch.VALIDATION_PARAMETERS[t]))
+        if st["segments"] == warm["segments"]:
+            assert st["program"]["sig"] == warm["program"]["sig"], t
+
+
+def test_absent_dictionary_value_is_an_empty_answer_from_the_same_program(
+        tenv):
+    ctx, _ = tenv
+    _, warm = _run(ctx, tpch.render(
+        "q3", {"segment": "MACHINERY", "date": "1995-03-10"}))
+    got, st = _run(ctx, tpch.render(
+        "q3", {"segment": "NO SUCH SEGMENT", "date": "1995-03-10"}))
+    assert len(got) == 0
+    assert list(got.columns) == ["o_orderkey", "revenue", "o_orderdate",
+                                 "o_shippriority"]
+    assert st["program"] == {**warm["program"], "built": False}
+
+
+@pytest.mark.parametrize("discount", [0.02, 0.09])
+def test_q6_discount_range_ends(tenv, discount):
+    """``between 0.01 and 0.03`` / ``between 0.08 and 0.10``: the stored
+    f32 discount against an f32 operand is the comparison against the
+    weak-typed constant it replaces, boundary rows included."""
+    ctx, data = tenv
+    params = {"date": "1995-01-01", "discount": discount, "quantity": 25}
+    got, st = _run(ctx, tpch.render("q6", params))
+    want = REF.oracle_q6(data, **params)
+    assert want.revenue[0] > 0
+    _check(got, want)
+    # the boundary values themselves select rows
+    li = data["lineitem"]
+    edge = li.l_discount.round(2).isin(
+        [round(discount - 0.01, 2), round(discount + 0.01, 2)])
+    assert edge.any()
+
+
+def test_in_list_of_another_arity_is_a_second_program(tenv):
+    ctx, data = tenv
+    two = tpch.render("q12", {"shipmode1": "MAIL", "shipmode2": "SHIP",
+                              "date": "1994-01-01"})
+    three = two.replace("('MAIL', 'SHIP')", "('MAIL', 'SHIP', 'RAIL')")
+    assert three != two
+    got2, st2 = _run(ctx, two)
+    got3, st3 = _run(ctx, three)
+    assert st2["program"]["sig"] != st3["program"]["sig"]
+    assert st3["program"]["operands"] == st2["program"]["operands"] + 1
+    _check(got2, REF.oracle_q12(data, "MAIL", "SHIP", "1994-01-01"))
+    df = data["orders"].merge(data["lineitem"], left_on="o_orderkey",
+                              right_on="l_orderkey")
+    df = df[df.l_shipmode.isin(["MAIL", "SHIP", "RAIL"])
+            & (df.l_receiptdate >= pd.Timestamp("1994-01-01"))
+            & (df.l_receiptdate < pd.Timestamp("1995-01-01"))]
+    high = df.o_orderpriority.isin(["1-URGENT", "2-HIGH"])
+    want = pd.DataFrame({
+        "l_shipmode": df.l_shipmode, "high_line_count": high.astype(float),
+        "low_line_count": (~high).astype(float)}) \
+        .groupby("l_shipmode", as_index=False).sum()
+    _check(got3, want)
+    # the same arity, other modes: the three-mode program again
+    _, st3b = _run(ctx, three.replace("'RAIL'", "'FOB'"))
+    assert st3b["program"] == {**st3["program"], "built": False}
+
+
+# -- what stays keyed by values -------------------------------------------------
+
+def test_result_cache_on_two_draws_two_answers():
+    """The result cache keys on the statement's VALUES: another draw is
+    another answer, the same draw again is a hit."""
+    ctx, data = _tpch_context({"sdot.cache.enabled": True})
+    a = {"date": "1994-01-01", "discount": 0.06, "quantity": 24}
+    b = {"date": "1994-01-01", "discount": 0.03, "quantity": 25}
+    got_a = ctx.sql(tpch.render("q6", a)).to_pandas()
+    assert ctx.history.entries()[-1].stats.get("cache") == "miss"
+    got_b = ctx.sql(tpch.render("q6", b)).to_pandas()
+    assert ctx.history.entries()[-1].stats.get("cache") == "miss"
+    _check(got_a, REF.oracle_q6(data, **a))
+    _check(got_b, REF.oracle_q6(data, **b))
+    assert got_a.revenue[0] != got_b.revenue[0]
+    again = ctx.sql(tpch.render("q6", a)).to_pandas()
+    assert ctx.history.entries()[-1].stats.get("cache") not in (None, "miss")
+    _check(again, got_a)
+
+
+def test_statement_memo_keys_on_the_text(tenv):
+    """The planning memo holds one plan per statement TEXT: a new draw
+    is planned, the same draw again is a memo hit — and both run the one
+    program."""
+    ctx, _ = tenv
+    a = tpch.render("q5", {"region": "EUROPE", "date": "1995-01-01"})
+    b = tpch.render("q5", {"region": "AFRICA", "date": "1995-01-01"})
+    _, st_a = _run(ctx, a)
+    _, st_b = _run(ctx, b)
+    _, st_a2 = _run(ctx, a)
+    assert st_b["plan_memo"] == {"hit": False}
+    assert st_a2["plan_memo"] == {"hit": True}
+    assert st_a["program"]["sig"] == st_b["program"]["sig"] \
+        == st_a2["program"]["sig"]
+
+
+def test_repeated_text_keeps_its_resolved_literals(tenv, monkeypatch):
+    """A text the statement memo has planned comes back as the same spec
+    object: its literal plan — resolved values, shape, packed words — is
+    kept with it and not rebuilt; another draw resolves its own."""
+    ctx, data = tenv
+    built = []
+    init = L.LiteralPlan.__init__
+
+    def counting(self, *a, **k):
+        built.append(1)
+        init(self, *a, **k)
+    monkeypatch.setattr(L.LiteralPlan, "__init__", counting)
+    a = {"date": "1995-01-01", "discount": 0.05, "quantity": 25}
+    b = {"date": "1995-01-01", "discount": 0.07, "quantity": 24}
+    got_a, st_a = _run(ctx, tpch.render("q6", a))
+    n = len(built)
+    again, st_again = _run(ctx, tpch.render("q6", a))
+    assert len(built) == n and st_again["plan_memo"] == {"hit": True}
+    got_b, st_b = _run(ctx, tpch.render("q6", b))
+    assert len(built) == n + 1
+    _check(got_a, REF.oracle_q6(data, **a))
+    _check(again, got_a)
+    _check(got_b, REF.oracle_q6(data, **b))
+    assert st_a["program"]["sig"] == st_again["program"]["sig"] \
+        == st_b["program"]["sig"]
+    assert st_again["program"]["operands"] == st_a["program"]["operands"]
+    assert "bind.operands" in {s[0] for s in st_again["spans"]}
+    assert not any(lits.pack().flags.writeable
+                   for _, lits in ctx.engine._literal_plans.values())
+
+
+def test_kept_literals_do_not_outlive_their_datasource():
+    """The kept plan holds dictionary CODES: the same spec object over a
+    re-ingested datasource (another dictionary) resolves anew."""
+    ctx = sdot.Context({"sdot.cache.enabled": False})
+    t = pd.Timestamp("2020-01-01")
+
+    def ingest(tags):
+        ctx.ingest_dataframe("ev", pd.DataFrame({
+            "ts": [t] * len(tags), "tag": tags,
+            "v": np.arange(len(tags), dtype=np.int64)}), time_column="ts")
+    q = S.TimeseriesQuerySpec(
+        "ev", (S.AggregationSpec("longsum", "v", field="v"),
+               S.AggregationSpec("count", "n")),
+        filter=S.SelectorFilter("tag", "m"))
+    ingest(["a", "m", "z"])
+    assert ctx.execute(q).to_pandas().v.tolist() == [1]
+    # the server's copy of a memoised plan under its own query id
+    stamped = dataclasses.replace(q, context=S.QueryContext(query_id="x"))
+    assert ctx.execute(stamped).to_pandas().v.tolist() == [1]
+    assert len(ctx.engine._literal_plans) == 1
+    ingest(["a", "b", "c", "m", "m"])          # "m" has another code now
+    got = ctx.execute(q).to_pandas()
+    assert got.v.tolist() == [7] and got.n.tolist() == [2]
+
+
+def test_learned_compaction_overflow_is_per_values(monkeypatch):
+    """A draw whose survivor budget overflowed goes uncompacted from
+    then on; another draw of the same shape still gets its own try
+    (``_compact_overflowed`` holds the statement's values)."""
+    from spark_druid_olap_tpu.parallel import cost as C
+    ctx, data = _tpch_context({"sdot.engine.scan.compact.min.rows": 0})
+    sel = {"v": 1e-5}
+    monkeypatch.setattr(C, "_filter_selectivity", lambda f, ds: sel["v"])
+    sql = ("select l_returnflag, count(*) as n from lineitem "
+           "where l_quantity < {} group by l_returnflag "
+           "order by l_returnflag")
+
+    def want(q):
+        li = data["lineitem"]
+        return li[li.l_quantity < q].groupby("l_returnflag").size() \
+            .reset_index(name="n")
+
+    got, st = _run(ctx, sql.format(40))
+    assert st.get("compact_overflow", 0) > 0
+    _check(got, want(40))
+    _, st = _run(ctx, sql.format(40))          # remembered: no second try
+    assert "compact_overflow" not in st and "compact_m" not in st
+    sel["v"] = 0.05                             # an honest estimate now
+    got, st = _run(ctx, sql.format(3))          # same shape, other value
+    assert st.get("compact_m", 0) > 0, st
+    _check(got, want(3))
+
+
+# -- shapes ----------------------------------------------------------------------
+
+def _fusion_context():
+    ctx, data = _tpch_context({"sdot.sharedscan.fusion.enabled": True})
+    return ctx, data["lineitem"]
+
+
+def test_equal_leaves_share_a_slot_and_unequal_ones_do_not():
+    """Trace-time predicate CSE shares the mask of two EQUAL leaves, so
+    which leaves are equal is part of the shape: a draw in which they
+    differ is another program, and both answer correctly."""
+    ctx, li = _fusion_context()
+    sql = ("select count(*) as n from lineitem where l_quantity = {} "
+           "or (l_linenumber = 1 and l_quantity = {})")
+
+    def want(a, b):
+        return int(((li.l_quantity == a)
+                    | ((li.l_linenumber == 1) & (li.l_quantity == b))).sum())
+
+    got, st_same = _run(ctx, sql.format(5, 5))
+    assert got.n[0] == want(5, 5)
+    got, st_diff = _run(ctx, sql.format(5, 7))
+    assert got.n[0] == want(5, 7) != want(5, 5)
+    assert st_diff["program"]["sig"] != st_same["program"]["sig"]
+    assert st_diff["program"]["operands"] > st_same["program"]["operands"]
+    got, st = _run(ctx, sql.format(9, 9))
+    assert got.n[0] == want(9, 9)
+    assert st["program"] == {**st_same["program"], "built": False}
+    got, st = _run(ctx, sql.format(30, 2))
+    assert got.n[0] == want(30, 2)
+    assert st["program"] == {**st_diff["program"], "built": False}
+
+
+def test_day_range_is_in_the_signature_only_where_a_plan_reads_it(tenv):
+    """Another year's segments: the same program for a plain group-by,
+    another for a time-derived key (its buckets are built from the
+    selected segments' day range)."""
+    ctx, data = tenv
+    li = data["lineitem"]
+    plain = ("select l_returnflag, count(*) as n from lineitem "
+             "where l_shipdate >= date '{0}-01-01' "
+             "and l_shipdate < date '{0}-07-01' group by l_returnflag "
+             "order by l_returnflag")
+    keyed = ("select year(l_shipdate) as y, count(*) as n from lineitem "
+             "where l_shipdate >= date '{0}-01-01' "
+             "and l_shipdate < date '{0}-07-01' group by year(l_shipdate) "
+             "order by y")
+    runs = {}
+    for year in (1993, 1994, 1995, 1996):
+        got, st = _run(ctx, plain.format(year))
+        sel = li[(li.l_shipdate >= pd.Timestamp(f"{year}-01-01"))
+                 & (li.l_shipdate < pd.Timestamp(f"{year}-07-01"))]
+        _check(got, sel.groupby("l_returnflag").size()
+               .reset_index(name="n"))
+        runs.setdefault(st["segments"], []).append(st["program"]["sig"])
+        got, st = _run(ctx, keyed.format(year))
+        assert got.y.tolist() == [year] and got.n[0] == len(sel)
+    # the same number of segments selected -> the same program, whatever
+    # days they cover
+    assert any(len(v) > 1 for v in runs.values()), runs
+    for sigs in runs.values():
+        assert len(set(sigs)) == 1, runs
+
+
+def test_constants_that_stay_constants(tenv):
+    """A pattern's mask is a function of the whole dictionary and an
+    expression's literal is no filter literal: both stay in the key."""
+    ctx, data = tenv
+    li = data["lineitem"]
+    like = "select count(*) as n from lineitem where l_shipmode like '{}%'"
+    got, a = _run(ctx, like.format("R"))
+    assert got.n[0] == int(li.l_shipmode.str.startswith("R").sum())
+    got, b = _run(ctx, like.format("A"))
+    assert got.n[0] == int(li.l_shipmode.str.startswith("A").sum())
+    assert a["program"]["sig"] != b["program"]["sig"]
+    assert a["program"]["operands"] == 0
+    expr = "select count(*) as n from lineitem where l_quantity * 2 < {}"
+    got, a = _run(ctx, expr.format(20))
+    assert got.n[0] == int((li.l_quantity * 2 < 20).sum())
+    got, b = _run(ctx, expr.format(30))
+    assert got.n[0] == int((li.l_quantity * 2 < 30).sum())
+    assert a["program"]["sig"] != b["program"]["sig"]
+
+
+def test_date_arithmetic_on_a_literal_folds_at_parse_time(tenv):
+    """``date '1998-12-01' - interval '90' day`` is a date literal, so
+    q1's bound reaches the filter lowering at all (and prunes
+    segments); the spec's ``+ interval '1' year`` spelling folds too."""
+    ctx, data = tenv
+    li = data["lineitem"]
+    sql = ("select count(*) as n from lineitem where l_shipdate >= "
+           "date '{}' and l_shipdate < date '{}' + interval '{}' {}")
+    for start, n, unit, end in (("1994-01-01", 1, "year", "1995-01-01"),
+                                ("1995-01-31", 1, "month", "1995-02-28"),
+                                ("1996-02-29", 12, "month", "1997-02-28"),
+                                ("1995-03-01", 45, "day", "1995-04-15")):
+        got, st = _run(ctx, sql.format(start, start, n, unit))
+        assert got.n[0] == int(((li.l_shipdate >= pd.Timestamp(start))
+                                & (li.l_shipdate < pd.Timestamp(end))).sum())
+        assert st["program"]["operands"] == 4       # one interval
+
+
+# -- the operand itself ------------------------------------------------------------
+
+def test_operand_packs_and_reads_every_compare_dtype():
+    plan = L.LiteralPlan.__new__(L.LiteralPlan)
+    plan._values, plan._words, plan.slots, plan.intervals = [], 0, {}, None
+    vals = [(-7, np.dtype(np.int32)), (0.07, np.dtype(np.float32)),
+            (-(2 ** 40) - 3, np.dtype(np.int64)),
+            (0.1 + 0.2, np.dtype(np.float64)), (2 ** 31 - 1,
+                                                np.dtype(np.int32))]
+    at = tuple(plan._add(v, dt) for v, dt in vals)
+    plan.slots["leaf"] = at
+    words = plan.pack()
+    assert words.dtype == np.int32 and words.shape == (1, 7)
+
+    class Leaf:
+        def __repr__(self):
+            return "leaf"
+
+    got = jax.jit(lambda w: L.Operands(plan, w).of(Leaf()))(words)
+    for g, (v, dt) in zip(got, vals):
+        assert g.dtype == dt
+        assert np.asarray(g) == np.array(v, dt)
+    with pytest.raises(OverflowError):
+        plan._add(2 ** 31, np.dtype(np.int32))
+
+
+def test_shape_keeps_structure_and_drops_values(tenv):
+    ctx, _ = tenv
+    ds = ctx.store.get("lineitem")
+
+    def shape(lower, upper, modes, strict=True):
+        f = S.LogicalFilter("and", (
+            S.BoundFilter("l_quantity", lower=lower, upper=upper,
+                          upper_strict=strict, numeric=True),
+            S.InFilter("l_shipmode", modes),
+            S.SelectorFilter("l_returnflag", None)))
+        q = S.TimeseriesQuerySpec(
+            "lineitem", (S.AggregationSpec("count", "n"),), filter=f,
+            intervals=((0, 10 ** 12),))
+        p = L.LiteralPlan(ds, q.filter, q.aggregations, q.intervals, "UTC",
+                          9000, 10000)
+        return p.shape_repr(q), p
+
+    base, plan = shape(1, 24, ("MAIL", "SHIP"))
+    assert plan.count == 2 + 2 + 4
+    assert "24" not in base and "MAIL" not in base and "$" in base
+    assert "value=None" in base                     # a NULL is structural
+    assert shape(3, 25, ("AIR", "NO SUCH MODE"))[0] == base
+    assert shape(None, 24, ("MAIL", "SHIP"))[0] != base    # absent bound
+    assert shape(1, 24, ("MAIL", "SHIP"), strict=False)[0] != base
+    assert shape(1, 24, ("MAIL", "SHIP", "AIR"))[0] != base  # arity
+    long = tuple(f"m{i}" for i in range(L.IN_OPERAND_MAX + 1))
+    s_long, p_long = shape(1, 24, long)
+    assert "m3" in s_long and p_long.count == 2 + 4  # stays a constant
+
+
+# -- the generator and the committed statement file ---------------------------------
+
+@pytest.mark.parametrize("template", TEMPLATES)
+def test_queries_are_the_templates_at_the_validation_values(template):
+    text = tpch.render(template, tpch.VALIDATION_PARAMETERS[template])
+    assert tpch.QUERIES[template] == text
+    # ... and that text is the one the accepted benchmark sends
+    power6 = _bench_json("statements", "tpch_power6.json")["classes"]
+    assert power6[template]["sql"] == text
+
+
+@pytest.mark.parametrize("template", TEMPLATES)
+def test_substitution_parameters_follow_the_specification(template):
+    rng = random.Random(7)
+    draws = [tpch.substitution_parameters(template, rng)
+             for _ in range(300)]
+    years = {str(y) for y in range(1993, 1998)}
+    for p in draws:
+        if template == "q1":
+            assert 60 <= p["delta"] <= 120
+        if template == "q3":
+            assert p["segment"] in tpch.SEGMENTS
+            assert "1995-03-01" <= p["date"] <= "1995-03-31"
+        if template == "q5":
+            assert p["region"] in tpch.REGIONS
+        if template == "q6":
+            assert p["discount"] in [x / 100 for x in range(2, 10)]
+            assert p["quantity"] in (24, 25)
+        if template == "q12":
+            assert p["shipmode1"] != p["shipmode2"]
+            assert {p["shipmode1"], p["shipmode2"]} <= set(tpch.SHIPMODES)
+        if template in ("q5", "q6", "q12"):
+            assert p["date"][:4] in years and p["date"][4:] == "-01-01"
+    # every value of every range is drawn
+    for key in draws[0]:
+        n = len({p[key] for p in draws})
+        assert n >= {"delta": 55, "segment": 5, "region": 5, "discount": 8,
+                     "quantity": 2, "shipmode1": 7, "shipmode2": 7,
+                     "date": 31 if template == "q3" else 5}[key], (key, n)
+    with pytest.raises(KeyError):
+        tpch.substitution_parameters("q2", rng)
+
+
+def test_committed_statement_file_is_what_the_generator_yields():
+    doc = _bench_json("statements", "tpch_qgen16.json")
+    drawn = tpch.draw_statements(doc["seed"])
+    assert [c for c in doc["classes"]] == [c for c, *_ in drawn] + ["acd"]
+    for cls, template, params, sql in drawn:
+        st = doc["classes"][cls]
+        assert (st["template"], st["params"], st["sql"]) \
+            == (template, params, sql), cls
+        assert st["reference"] == f"tpch_qgen:{cls}"
+        assert getattr(REF, cls).keywords == params
+    power6 = _bench_json("statements", "tpch_power6.json")["classes"]
+    assert doc["classes"]["acd"] == power6["acd"]
+
+
+def _repeated_year(drawn):
+    """The templates among q5, q6, q12 whose draws repeat a year."""
+    return [t for t in ("q5", "q6", "q12")
+            if len({p["date"] for _, tt, p, _ in drawn if tt == t}) < 3]
+
+
+def test_statement_file_seed_is_the_first_from_28_that_covers_three_years():
+    """The issue's rule for the pool's seed: ``random.Random(28)`` and,
+    where the three q5, q6 or q12 draws do not cover three different
+    years, the next seed that does — each seed before the recorded one
+    is rejected for that reason and no other."""
+    seed = _bench_json("statements", "tpch_qgen16.json")["seed"]
+    rejected = {s: _repeated_year(tpch.draw_statements(s))
+                for s in range(28, seed)}
+    assert all(rejected.values()), rejected
+    assert len(rejected) == 37 and seed == 65
+    assert _repeated_year(tpch.draw_statements(seed)) == []
