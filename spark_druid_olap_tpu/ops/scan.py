@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -109,29 +110,53 @@ class ScanContext:
 @dataclasses.dataclass
 class CompactScanContext(ScanContext):
     """Late-materialization view over a parent scan: after the filter
-    mask is evaluated on the full [S, R] arrays, surviving row positions
-    are sorted to a static [M] prefix (``keep``) and every later column
-    access gathers through it — so group-key building, value derivation,
-    and aggregation all run at O(M) instead of O(N). This is the
-    columnar-engine move Druid's historicals make with bitmap-index row
-    lists; the TPU form keeps shapes static via a planner-chosen budget
-    with on-device overflow detection (host retries uncompacted).
+    mask is evaluated on the full [S, R] arrays, the survivors are brought
+    to a static [M] prefix in ascending row order (``compact_scan``) and
+    every later column access reads that prefix — so group-key building,
+    value derivation, and aggregation all run at O(M) instead of O(N).
+    This is the columnar-engine move Druid's historicals make with
+    bitmap-index row lists; the TPU form keeps shapes static via a
+    planner-chosen budget with on-device overflow detection (host
+    retries uncompacted).
 
-    Gathers are 1D [M]-probe (`take1d` cost model: ~7ms per million
-    probes on v5e), so a selective filter turns a 6M-row scan's
-    downstream work into single-digit milliseconds."""
+    Two forms serve a read, one per context, chosen by ``carries_by_sort``
+    from the static shapes:
+
+    - ``taken``: the arrays rode the compaction sort as payloads; a read
+      is the first M entries of its sorted operand. An array that did not
+      ride raises — there is no silent gather behind it.
+    - ``keep``: int32 [M] flat row positions; each array is gathered
+      through it on first read (one 1D [M]-probe gather an array, ~8 ns a
+      probe on a v5e: the form for a budget far under the scanned rows).
+
+    Both hold the same rows in the same order, so whatever is computed
+    from them is bit-identical."""
 
     keep: object = None                # int32 [M] flat row positions
+    taken: Optional[Dict[str, object]] = None   # name -> [M] sorted prefix
 
     def __post_init__(self):
         self._cache = {}
 
     def _gather(self, name: str, arr):
+        if self.taken is not None:
+            if name not in self.taken:
+                raise LookupError(
+                    f"{name!r} is read after compaction but did not ride "
+                    f"the compaction sort (carried: {sorted(self.taken)})")
+            return self.taken[name]
         hit = self._cache.get(name)
         if hit is None:
             flat = arr.reshape(-1)
             hit = self._cache[name] = flat[self.keep]
         return hit
+
+    def carried(self):
+        """(form, arrays brought to the prefix so far) for the statement
+        record's ``compact_carry`` / ``compact_cols``."""
+        if self.taken is not None:
+            return "sort", len(self.taken)
+        return "gather", len(self._cache)
 
     def col(self, name: str):
         return self._gather(name, super().col(name))
@@ -147,6 +172,111 @@ class CompactScanContext(ScanContext):
         nv = super().null_valid(name)
         return None if nv is None else self._gather(
             NULL_VALID_PREFIX + name, nv)
+
+
+@dataclasses.dataclass
+class Compaction:
+    """One program's late materialization: the survivor budget and unit
+    costs it was built under (``carries_by_sort``) and, once it has been
+    traced, how the survivors' arrays reached the prefix — what the
+    statement record says of it."""
+
+    m: int
+    payload_row_s: float
+    probe_s: float
+    carry: Optional[str] = None        # "sort" | "gather"
+    cols: int = 0                      # arrays that rode or were gathered
+
+
+@dataclasses.dataclass
+class _ReadRecorder(CompactScanContext):
+    """Stands in for the compacted context while the post-compaction body
+    is traced once under ``jax.eval_shape``: remembers the key of every
+    array the body reads, in order, and answers with [M] zeros of the
+    dtype ``_gather`` is handed."""
+
+    m: int = 0
+
+    def __post_init__(self):
+        self.reads: Dict[str, None] = {}
+
+    def _gather(self, name: str, arr):
+        self.reads[name] = None
+        return jnp.zeros((self.m,), arr.dtype)
+
+
+def _full_width(ctx: ScanContext, key: str):
+    """The array under ``key`` as the compacted accessors hand it to
+    ``_gather`` (narrow codes widened: decoding stays where it is)."""
+    if key == ROW_VALID_KEY:
+        return ctx.row_valid()
+    if key == TIME_MS_KEY:
+        return ctx.time_ms()
+    if key.startswith(NULL_VALID_PREFIX):
+        return ctx.null_valid(key[len(NULL_VALID_PREFIX):])
+    return ctx.col(key)
+
+
+def carries_by_sort(n_rows: int, m: int, payload_row_s: float,
+                    probe_s: float) -> bool:
+    """Which form brings one array's survivors to the [M] prefix cheaper:
+    riding the compaction sort over all ``n_rows`` scanned rows (a further
+    sort operand, ``sort.payload.seconds.per.row``) or a gather of ``m``
+    probes (``gather.seconds.per.probe``). Static shapes and the backend's
+    unit costs only — on a v5e's constants the sort carries where
+    ``m * 13 > n_rows`` (measured there, PR 29: q3's five columns out of
+    4.0M rows into 2^20 as payloads 12.2 ms, gathered 57; into 2^15 out of
+    6.0M 25.2 against 5.9); on the CPU fallback's, where
+    ``m > n_rows * 50``: never, for a budget is at most half the rows."""
+    return n_rows * payload_row_s < m * probe_s
+
+
+def compact_scan(ctx: ScanContext, mask, compact_m: int, body,
+                 payload_row_s: float, probe_s: float):
+    """The ONE late-materialization stanza. ``mask`` is the survivor mask
+    over ``ctx``'s full-width arrays, ``body(cctx, base)`` what the
+    program goes on to compute from the compacted context. Returns
+    ``(cctx, base, n_over)``: the compacted context, the [M] mask of its
+    live rows, and how many survivors did not fit (the caller reports it;
+    the host retries uncompacted).
+
+    One int32 key, ``row + N * dead``, sorts the survivors first and each
+    side in ascending row order. It is unique, so the sort needs no
+    stability — a stable sort rides a hidden index operand, 5.8 against
+    2.6 ms over 4.0M rows on a v5e — and it is its own row index. In the
+    payload form (``carries_by_sort``) ``body`` is traced once against a
+    recorder — under ``jax.eval_shape``, so that pass leaves nothing in
+    the program — to find the arrays that ride: what only the cheap
+    filter read stays behind. Booleans ride as int8."""
+    flat = mask.reshape(-1)
+    n, m = flat.shape[0], int(compact_m)
+    if 2 * n > np.iinfo(np.int32).max:
+        raise ValueError(f"late materialization over {n} rows a shard: "
+                         f"the int32 sort key holds 2^30")
+    okey = jnp.arange(n, dtype=jnp.int32) \
+        + jnp.where(flat, jnp.int32(0), jnp.int32(n))
+    n_live = jnp.sum(flat.astype(jnp.int32))
+    n_over = jnp.maximum(n_live - jnp.int32(m), 0).astype(jnp.int32)
+    # survivors sort first, so which prefix rows are live needs no read
+    base = jnp.arange(m, dtype=jnp.int32) < n_live
+    parent = (ctx.ds, ctx.arrays, ctx.min_day, ctx.max_day, ctx.tz,
+              ctx.operands)
+    if not carries_by_sort(n, m, payload_row_s, probe_s):
+        sidx = jax.lax.slice_in_dim(
+            jax.lax.sort(okey, is_stable=False), 0, m)
+        keep = jnp.where(sidx >= n, sidx - n, sidx)
+        return CompactScanContext(*parent, keep=keep), base, n_over
+    rec = _ReadRecorder(*parent, m=m)
+    jax.eval_shape(lambda: body(rec, jnp.zeros((m,), jnp.bool_)))
+    ride = [_full_width(ctx, key).reshape(-1) for key in rec.reads]
+    _, *rode = jax.lax.sort(
+        (okey, *(a.astype(jnp.int8) if a.dtype == jnp.bool_ else a
+                 for a in ride)), num_keys=1, is_stable=False)
+    taken = {}
+    for key, a, s in zip(rec.reads, ride, rode):
+        s = jax.lax.slice_in_dim(s, 0, m)
+        taken[key] = s != 0 if a.dtype == jnp.bool_ else s
+    return CompactScanContext(*parent, taken=taken), base, n_over
 
 
 def array_names(ds: Datasource, columns, need_time_ms: bool):

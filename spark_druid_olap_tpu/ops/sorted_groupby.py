@@ -42,12 +42,16 @@ around ``block_until_ready``):
   ``sort.payload.seconds.per.row`` 6.7e-10 holds);
 - ``cumsum`` 0.8 ms, one segmented max scan 2.9 ms (micro);
 - the compaction sort, 1 key + 6 columns: 4.0 ms (micro), 2.5 ms (trace);
-  the whole program 71.3 ms a q3, 51 of them late materialization's six
-  gathers of 2^20 survivors out of 4.0M rows, before this module runs;
+  the whole program 71.3 ms a q3 then, 51 of them late materialization's
+  six gathers of 2^20 survivors out of 4.0M rows, before this module
+  runs; since PR 29 those columns ride late materialization's own sort
+  (``ops.scan.compact_scan``: 15 ms a q3, trace) and the program takes
+  29 ms;
 - what it replaced, a binary search for the run end of EVERY slot
   (``T`` x 21 rounds of ``T``-probe gathers in a ``fori_loop``, then six
   ``[T]``-wide takes): 316 ms + 139 ms of q3's 524 ms (trace; 501 ms as a
-  micro) — 7.1 ns a probe, as ``gather.seconds.per.probe`` 7e-9 says. It
+  micro) — 7.1 ns a probe, as ``gather.seconds.per.probe`` said (7e-9
+  then; 9e-9 since PR 29, fit at sorted positions). It
   was written for ``T << n`` ("log2(N) x T probes versus N scatter
   updates"); late materialization sizes ``T`` at twice the rows that
   survive it, so the search ran over two million slots of which 99.4 %

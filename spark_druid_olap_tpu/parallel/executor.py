@@ -47,8 +47,9 @@ from spark_druid_olap_tpu.ops import theta as TH
 from spark_druid_olap_tpu.ops import time_ops as T
 from spark_druid_olap_tpu.ops import timezone as TZ
 from spark_druid_olap_tpu.ops.scan import (
-    CompactScanContext,
+    Compaction,
     ScanContext,
+    compact_scan,
     array_dtype,
     array_names,
     build_array,
@@ -1282,13 +1283,14 @@ class QueryEngine:
                 # estimate is structurally off for its values (learned
                 # state is keyed by them, not by the shape), don't re-pay
                 # the double execution on every warm run
-            for cm in ((compact_m, None) if compact_m else (None,)):
-                prog_fn, unpack = self._cached_program(
+            late = self._late(compact_m)
+            for cm in ((late, None) if late else (None,)):
+                prog_fn, unpack, compact = self._cached_program(
                     ("agg", base_sig, topk, cm),
                     lambda cm=cm: self._build_agg_program(
                         ds, all_dim_plans, agg_plans, filter_spec,
                         intervals, days, n_keys, sharded,
-                        routes, topk=topk, compact_m=cm, lits=lits))
+                        routes, topk=topk, late=cm, lits=lits))
                 dev_arrays = self._bind_arrays(ds, names, seg_idx, s_pad,
                                                sharded, lits)
                 if t0 is not None:
@@ -1301,8 +1303,7 @@ class QueryEngine:
                     self._stage_check(q, t0)  # post-device boundary
                 over = out.pop("__over__", None)
                 if over is None or int(np.asarray(over).reshape(-1)[0]) == 0:
-                    if cm:
-                        self.last_stats["compact_m"] = int(cm)
+                    self._note_compaction(compact)
                     break
                 # est. selectivity too optimistic: retry uncompacted and
                 # remember this program shape so warm runs skip straight
@@ -1328,19 +1329,19 @@ class QueryEngine:
             if compact_m and ("aggw", base_sig, _cache_repr(q)) \
                     in self._compact_overflowed:
                 compact_m = None
-            for cm in ((compact_m, None) if compact_m else (None,)):
-                prog_fn, unpack = self._cached_program(
+            late = self._late(compact_m)
+            for cm in ((late, None) if late else (None,)):
+                prog_fn, unpack, compact = self._cached_program(
                     ("agg", base_sig, None, cm),
                     lambda cm=cm: self._build_agg_program(
                         ds, all_dim_plans, agg_plans, filter_spec,
                         intervals, days, n_keys, sharded,
-                        routes, topk=None, compact_m=cm, lits=lits))
+                        routes, topk=None, late=cm, lits=lits))
                 finals, wave_over = self._run_waves(
                     q, ds, names, seg_idx, spw, sharded, prog_fn, unpack,
                     routes, n_keys, sketch_plans, t0, lits)
                 if not wave_over:
-                    if cm:
-                        self.last_stats["compact_m"] = int(cm)
+                    self._note_compaction(compact)
                     break
                 self.last_stats["compact_overflow"] = int(wave_over)
                 self._compact_overflowed.add(
@@ -1523,7 +1524,8 @@ class QueryEngine:
         compaction sort costs ``rows * sort_c``; it saves the downstream
         per-row aggregation work — scatter updates (or the fused kernel's
         streamed pass under an 'ffl' route) on the rows it removes — and
-        re-buys ``m`` gather probes per touched column. All unit costs are
+        re-buys, per touched column, ``m`` gather probes or one more sort
+        operand over ``rows``, whichever is less. All unit costs are
         per-backend measurements (``cost.unit_cost``; tools/calibrate.py
         refits them on the live backend). On TPU sort ≈ scatter/30 so the
         gate engages for any selective filter; on the CPU fallback the
@@ -1552,8 +1554,11 @@ class QueryEngine:
                 n_ops = max(1, len(routes)) if routes is not None else 4
             n_ops = min(int(n_ops), 8)
             sort_s = rows * C.unit_cost(self.config, CF.COST_SORT_ROW)
-            gather_s = m * n_ops * C.unit_cost(self.config,
-                                               CF.COST_GATHER_PROBE)
+            # each touched column reaches the prefix the cheaper way, as
+            # the program will (ops.scan.carries_by_sort)
+            gather_s = n_ops * min(
+                rows * C.unit_cost(self.config, CF.COST_SORT_PAYLOAD_ROW),
+                m * C.unit_cost(self.config, CF.COST_GATHER_PROBE))
             if routes is not None and any(
                     getattr(r, "tag", None) == "ffl"
                     for r in routes.values()):
@@ -1575,6 +1580,24 @@ class QueryEngine:
             if sort_s + gather_s >= saved:
                 return None
         return int(m)
+
+    def _late(self, compact_m):
+        """What a compacting program is built under and cached by: the
+        budget and the two unit costs that choose, with the traced
+        shapes, how the survivors' arrays reach the prefix
+        (``ops.scan.carries_by_sort``). None without a budget."""
+        if not compact_m:
+            return None
+        from spark_druid_olap_tpu.utils import config as CF
+        return (int(compact_m),
+                C.unit_cost(self.config, CF.COST_SORT_PAYLOAD_ROW),
+                C.unit_cost(self.config, CF.COST_GATHER_PROBE))
+
+    def _note_compaction(self, compact):
+        if compact:
+            self.last_stats.update({"compact_m": compact.m,
+                                    "compact_carry": compact.carry,
+                                    "compact_cols": compact.cols})
 
     def _plan_device_topk(self, limit, having, agg_plans, n_keys):
         """Decide whether the ordered-limit epilogue can run on device:
@@ -1767,9 +1790,10 @@ class QueryEngine:
                 routes = G.plan_routes(
                     metas, T, self.config.get(GROUPBY_MATMUL_MAX_KEYS),
                     n_rows=n_rows_dev)
+            late_key = self._late(lm)
             sig = ("hashagg", ds.name, id(ds), lits.shape, s_pad,
                    ds.padded_rows, days, sharded, n_dev, T,
-                   tuple(names), topk, compact, lm, sorted_run,
+                   tuple(names), topk, compact, late_key, sorted_run,
                    self.config.get(TZ_ID),
                    self.config.get(GROUPBY_MATMUL_MAX_KEYS),
                    self.config.get(HLL_LOG2M),
@@ -1777,19 +1801,23 @@ class QueryEngine:
                    jax.default_backend(), bool(jax.config.jax_enable_x64),
                    bool(self.config.get(SHAREDSCAN_FUSION_ENABLED)))
 
-            def build(lm=lm):
+            def build(late_key=late_key):
+                # the program and, under a budget, its Compaction: the
+                # record reads the form it ran in from there
+                late = Compaction(*late_key) if late_key else None
                 if compact or exch:
                     return self._build_hash_table_program(
                         ds, dim_plans, parts, agg_plans, filter_spec,
                         intervals, days, T, sharded, routes,
-                        compact_m=lm, sorted_run=sorted_run, lits=lits)
+                        compact=late, sorted_run=sorted_run,
+                        lits=lits), late
                 return self._build_hash_program(
                     ds, dim_plans, parts, agg_plans, filter_spec,
                     intervals, days, T, sharded, routes,
-                    topk=topk, compact_m=lm, sorted_run=sorted_run,
-                    lits=lits)
+                    topk=topk, compact=late, sorted_run=sorted_run,
+                    lits=lits), late
 
-            prog = self._cached_program(sig, build)
+            prog, late = self._cached_program(sig, build)
 
             partials, unresolved = [], 0
 
@@ -1878,8 +1906,7 @@ class QueryEngine:
                     partials.extend(
                         _hash_chip_partials(raw, routes, k_out, n_dev))
             if not unresolved:
-                if lm:
-                    self.last_stats["compact_m"] = int(lm)
+                self._note_compaction(late)
                 break
             if lm:
                 # the late-materialization budget may be what overflowed
@@ -1975,20 +2002,51 @@ class QueryEngine:
             return None
         return (oc.name, _topk_slack(limit), bool(oc.ascending))
 
+    def _compacted(self, ctx, base, compact, tail, exp_f, fuse_cse):
+        """Late materialization between a core's cheap filter and its
+        ``tail(ctx, base, cse)``: the survivors of ``base`` come to a
+        static [compact.m] prefix (``ops.scan.compact_scan``: as payloads
+        of the compaction sort or by a gather an array, whichever the
+        shapes price lower) and the tail runs at O(survivors). Returns
+        (the tail's outputs, the survivors over budget) and notes on
+        ``compact`` the form that ran. The position sort is 0.7ms/M rows
+        on a v5e and each column it carries 0.5-0.7 — far below one
+        6M-row scatter (~40ms)."""
+        def staged(cctx, live):
+            # the compacted context changes every mask's shape: the
+            # full-width CSE entries must never leak past this point
+            cse = FU.CSECache(cctx) if fuse_cse else None
+            if exp_f is not None:
+                # staged: gather-heavy conjuncts (membership sets,
+                # keyed lookups) evaluate on the survivors only
+                em = cse.lower(exp_f) if cse is not None \
+                    else F.lower_filter(exp_f, cctx)
+                if em is not None:
+                    live = live & em
+            return tail(cctx, live, cse)
+
+        cctx, live, n_over = compact_scan(
+            ctx, base, compact.m, staged, compact.payload_row_s,
+            compact.probe_s)
+        out = staged(cctx, live)
+        compact.carry, compact.cols = cctx.carried()
+        return out, n_over
+
     def _hash_core(self, ds, dim_plans, parts, agg_plans, filter_spec,
                    intervals, days, T, routes,
-                   compact_m=None, sorted_run=False, *, lits):
+                   compact=None, sorted_run=False, *, lits):
         """The shared hash scan body: scan -> filter -> per-dim codes ->
         two-part key -> slot claim -> exact scatter aggregation into [T]
         buffers. Returns the raw out dict incl. '__tkhi__'/'__tklo__' key
-        tables and '__unres__' (shape [1]). With ``compact_m``, late
-        materialization (same machinery as the dense path) runs the key
-        build + aggregation at O(survivors); a budget overflow folds into
-        '__unres__' (the host first retries uncompacted, then grows T)."""
+        tables and '__unres__' (shape [1]). With ``compact`` (a
+        ``Compaction``), late materialization (same machinery as the dense
+        path) runs the key build + aggregation at O(survivors); a budget
+        overflow folds into '__unres__' (the host first retries
+        uncompacted, then grows T)."""
         matmul_max = self.config.get(GROUPBY_MATMUL_MAX_KEYS)
         cards = [p.card for p in dim_plans]
         cheap_f, exp_f = (self._split_filter_staged(filter_spec)
-                          if compact_m else (filter_spec, None))
+                          if compact else (filter_spec, None))
         fuse_cse = bool(self.config.get(SHAREDSCAN_FUSION_ENABLED))
         min_day, max_day = days or (None, None)
 
@@ -2007,26 +2065,15 @@ class QueryEngine:
             im = F.interval_mask(intervals, ctx)
             if im is not None:
                 base = base & im
-            n_over = None
-            if compact_m:
-                flat = base.reshape(-1)
-                ridx = jnp.arange(flat.shape[0], dtype=jnp.int32)
-                okey = jnp.where(flat, jnp.int32(0), jnp.int32(1))
-                _, sidx = jax.lax.sort((okey, ridx), num_keys=1)
-                keep = jax.lax.slice_in_dim(sidx, 0, compact_m)
-                n_live = jnp.sum(flat.astype(jnp.int32))
-                n_over = jnp.maximum(
-                    n_live - jnp.int32(compact_m), 0).astype(jnp.int32)
-                ctx = CompactScanContext(ds, arrays, min_day, max_day,
-                                         self.config.get(TZ_ID),
-                                         operands=operands, keep=keep)
-                cse = FU.CSECache(ctx) if fuse_cse else None
-                base = flat[keep]
-                if exp_f is not None:
-                    em = cse.lower(exp_f) if cse is not None \
-                        else F.lower_filter(exp_f, ctx)
-                    if em is not None:
-                        base = base & em
+            if not compact:
+                return tail(ctx, base, cse)
+            out, n_over = self._compacted(ctx, base, compact, tail, exp_f,
+                                          fuse_cse)
+            out["__unres__"] = (out["__unres__"].reshape(-1)[0]
+                                + n_over).reshape(1)
+            return out
+
+        def tail(ctx, base, cse):
             codes = [p.build(ctx) for p in dim_plans]
             khi = H.fuse_part(codes, cards, parts[0])
             klo = H.fuse_part(codes, cards, parts[1]) if len(parts) > 1 \
@@ -2041,19 +2088,15 @@ class QueryEngine:
                 # sorted-run tier: the slot sort rides the agg values as
                 # payloads; prefix scans + run-boundary reads replace
                 # every per-agg scatter (ops/sorted_groupby.py)
-                out = SG.sorted_hash_groupby(khi, klo, base, T, inputs,
-                                             routes)
-            else:
-                slot, tk_hi, tk_lo, unresolved = H.build_slots(
-                    khi, klo, base, T)
-                out = G.dense_groupby(slot, base, T, inputs, routes,
-                                      matmul_max)
-                out["__tkhi__"] = tk_hi
-                out["__tklo__"] = tk_lo
-                out["__unres__"] = unresolved.reshape(1)
-            if n_over is not None:
-                out["__unres__"] = (out["__unres__"].reshape(-1)[0]
-                                    + n_over).reshape(1)
+                return SG.sorted_hash_groupby(khi, klo, base, T, inputs,
+                                              routes)
+            slot, tk_hi, tk_lo, unresolved = H.build_slots(
+                khi, klo, base, T)
+            out = G.dense_groupby(slot, base, T, inputs, routes,
+                                  matmul_max)
+            out["__tkhi__"] = tk_hi
+            out["__tklo__"] = tk_lo
+            out["__unres__"] = unresolved.reshape(1)
             return out
 
         return core
@@ -2178,7 +2221,7 @@ class QueryEngine:
 
     def _build_hash_program(self, ds, dim_plans, parts, agg_plans,
                             filter_spec, intervals, days, T,
-                            sharded, routes, topk=None, compact_m=None,
+                            sharded, routes, topk=None, compact=None,
                             sorted_run=False, *, lits):
         """Single-dispatch hash program (full-table or topk-gathered
         transfer). Outputs stay per-chip in sharded mode (slot layouts
@@ -2187,7 +2230,7 @@ class QueryEngine:
         _plan_device_topk_hashed)."""
         core = self._hash_core(ds, dim_plans, parts, agg_plans, filter_spec,
                                intervals, days, T, routes,
-                               compact_m=compact_m, sorted_run=sorted_run,
+                               compact=compact, sorted_run=sorted_run,
                                lits=lits)
         k_out = topk[1] if topk else T
         pack, unpack = self._hash_packers(agg_plans, routes, k_out, True,
@@ -2209,14 +2252,14 @@ class QueryEngine:
 
     def _build_hash_table_program(self, ds, dim_plans, parts, agg_plans,
                                   filter_spec, intervals, days,
-                                  T, sharded, routes, compact_m=None,
+                                  T, sharded, routes, compact=None,
                                   sorted_run=False, *, lits):
         """Compaction dispatch 1 of 2: build the table, leave it DEVICE-
         RESIDENT, transfer only '__stats__' = [unresolved, occupied] per
         chip. The host sizes the gather dispatch from the occupancy."""
         core = self._hash_core(ds, dim_plans, parts, agg_plans, filter_spec,
                                intervals, days, T, routes,
-                               compact_m=compact_m, sorted_run=sorted_run,
+                               compact=compact, sorted_run=sorted_run,
                                lits=lits)
 
         def run(arrays):
@@ -2528,12 +2571,15 @@ class QueryEngine:
 
     def _make_core(self, ds, dim_plans, agg_plans, filter_spec,
                    intervals, days, n_keys, routes,
-                   compact_m=None, *, lits):
+                   compact=None, *, lits):
         """``days``: the selected segments' (min_day, max_day), or None
         where the signature does not carry them — then nothing traced
         may read them. ``lits``: the building statement's literal plan;
         the filters read their literals from the operand bound under
-        ``L.LITERALS_KEY``."""
+        ``L.LITERALS_KEY``. ``compact``: the program's ``Compaction`` —
+        late materialization between the cheap filter and everything
+        after it; an overflow of its budget surfaces as '__over__' and
+        the host retries without."""
         min_day, max_day = days or (None, None)
         matmul_max = self.config.get(GROUPBY_MATMUL_MAX_KEYS)
         log2m = self.config.get(HLL_LOG2M)
@@ -2545,7 +2591,7 @@ class QueryEngine:
                        if p.kind not in ("hll", "theta", "kll")]
 
         cheap_f, exp_f = (self._split_filter_staged(filter_spec)
-                          if compact_m else (filter_spec, None))
+                          if compact else (filter_spec, None))
         fuse_cse = bool(self.config.get(SHAREDSCAN_FUSION_ENABLED))
 
         def core(arrays):
@@ -2565,36 +2611,14 @@ class QueryEngine:
             im = F.interval_mask(intervals, ctx)
             if im is not None:
                 base = base & im
-            n_over = None
-            if compact_m:
-                # late materialization: survivors sort to a static [M]
-                # prefix; group keys / values / aggregation all run at
-                # O(M). Overflow (est. selectivity too optimistic)
-                # surfaces as '__over__' and the host retries without
-                # compaction. A 2-operand sort is ~0.2ms/M rows on v5e
-                # — far below one 6M-row scatter (~40ms).
-                flat = base.reshape(-1)
-                ridx = jnp.arange(flat.shape[0], dtype=jnp.int32)
-                okey = jnp.where(flat, jnp.int32(0), jnp.int32(1))
-                _, sidx = jax.lax.sort((okey, ridx), num_keys=1)
-                keep = jax.lax.slice_in_dim(sidx, 0, compact_m)
-                n_live = jnp.sum(flat.astype(jnp.int32))
-                n_over = jnp.maximum(
-                    n_live - jnp.int32(compact_m), 0).astype(jnp.int32)
-                ctx = CompactScanContext(ds, arrays, min_day, max_day,
-                                         self.config.get(TZ_ID),
-                                         operands=operands, keep=keep)
-                # the compacted context changes every mask's shape: the
-                # full-width CSE entries must never leak past this point
-                cse = FU.CSECache(ctx) if fuse_cse else None
-                base = flat[keep]
-                if exp_f is not None:
-                    # staged: gather-heavy conjuncts (membership sets,
-                    # keyed lookups) evaluate on the survivors only
-                    em = cse.lower(exp_f) if cse is not None \
-                        else F.lower_filter(exp_f, ctx)
-                    if em is not None:
-                        base = base & em
+            if not compact:
+                return tail(ctx, base, cse)
+            out, n_over = self._compacted(ctx, base, compact, tail, exp_f,
+                                          fuse_cse)
+            out["__over__"] = n_over.reshape(1)
+            return out
+
+        def tail(ctx, base, cse):
             if dim_plans:
                 codes = [p.build(ctx) for p in dim_plans]
                 key, _ = G.fuse_keys(codes, [p.card for p in dim_plans])
@@ -2630,16 +2654,16 @@ class QueryEngine:
                 tcol = ctx.col(ds.time.name) if ds.time is not None else None
                 out[p.spec.name] = KLL.kll_registers(
                     key, m, vals, tcol, n_keys, kll_lanes)
-            if n_over is not None:
-                out["__over__"] = n_over.reshape(1)
             return out
 
         return core
 
     def _build_agg_program(self, ds, dim_plans, agg_plans, filter_spec,
                            intervals, days, n_keys, sharded,
-                           routes, topk=None, compact_m=None, *, lits):
-        """Returns (jit_fn, unpack).
+                           routes, topk=None, late=None, *, lits):
+        """Returns (jit_fn, unpack, compact): ``compact`` is the program's
+        ``Compaction`` under a ``late`` budget (``_late``; the statement
+        record reads the form it ran in from there), else None.
 
         The program packs outputs into TWO flat device buffers so the host
         pays at most two device->host transfers (each buffer is its own
@@ -2660,16 +2684,17 @@ class QueryEngine:
         ``QuerySpecTransforms.scala`` topN + ``DruidQueryCostModel``
         topN threshold).
         """
+        compact = Compaction(*late) if late else None
         core = self._make_core(ds, dim_plans, agg_plans, filter_spec,
                                intervals, days, n_keys, routes,
-                               compact_m=compact_m, lits=lits)
+                               compact=compact, lits=lits)
         hll_plans = [p for p in agg_plans if p.kind == "hll"]
         theta_plans = [p for p in agg_plans if p.kind == "theta"]
         kll_plans = [p for p in agg_plans if p.kind == "kll"]
         pack, unpack = self._agg_meta_packers(
             agg_plans, routes, topk[1] if topk else n_keys,
             with_idx=bool(topk), with_score=bool(topk),
-            with_over=bool(compact_m))
+            with_over=bool(late))
 
         def topk_gather(out, axis_name=None):
             """Select k_sel candidate keys by score, gather every output."""
@@ -2745,7 +2770,7 @@ class QueryEngine:
                                  check_vma=False)
             fn = named_jit("sdot_agg_dense", smfn)
 
-        return fn, unpack
+        return fn, unpack, compact
 
     def _cached_program(self, sig, build):
         """Program-cache fetch with PER-SIGNATURE compile ownership: warm

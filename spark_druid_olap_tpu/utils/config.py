@@ -143,16 +143,19 @@ COST_PER_BYTE_INTERCONNECT = _entry(
     "so wide outputs on small scans correctly prefer single-device "
     "execution.", float)
 COST_SORT_ROW = _entry(
-    "sdot.querycostmodel.sort.seconds.per.row", 2.2e-10,
-    "Measured seconds per row of a 2-operand device lax.sort (the "
-    "compaction position sort / hashed slot sort). Default = v5e "
-    "measurement (1.3ms / 6M rows); tools/calibrate.py refits it on the "
-    "live backend — the CPU fallback's x64 sort is ~1000x this, which "
-    "is what flips the compaction and sorted-run gates there.", float)
+    "sdot.querycostmodel.sort.seconds.per.row", 7e-10,
+    "Measured seconds per row of late materialization's position sort: "
+    "one int32 operand, a unique key, no stability (ops.scan."
+    "compact_scan). Default = v5e measurement (2.6ms / 4.0M rows, 4.4ms "
+    "/ 6.0M; a STABLE 2-operand sort of the same rows is 5.8 / 10.3ms); "
+    "tools/calibrate.py refits it on the live backend — the CPU "
+    "fallback's x64 sort is ~400x this, which is what flips the "
+    "compaction gate there.", float)
 COST_SORT_PAYLOAD_ROW = _entry(
     "sdot.querycostmodel.sort.payload.seconds.per.row", 6.7e-10,
     "Measured seconds per row per EXTRA sort payload operand "
-    "(v5e: +4ms / 6M rows each). Fit by tools/calibrate.py.", float)
+    "(v5e: +4.1ms / 6.0M rows each, +1.8ms / 4.0M). Fit by "
+    "tools/calibrate.py.", float)
 COST_SCATTER_UPDATE = _entry(
     "sdot.querycostmodel.scatter.seconds.per.update", 6.7e-9,
     "Measured seconds per update of an XLA scatter/segment-sum into a "
@@ -175,15 +178,18 @@ COST_TABLE_CACHE_BYTES = _entry(
     "big-table constant (≈ the host LLC on the CPU fallback; irrelevant "
     "on TPU where both constants are equal).", int)
 COST_GATHER_PROBE = _entry(
-    "sdot.querycostmodel.gather.seconds.per.probe", 7e-9,
+    "sdot.querycostmodel.gather.seconds.per.probe", 9e-9,
     "Measured seconds per probe of a flattened 1D device gather "
-    "(v5e: ~7ms / M probes). Fit by tools/calibrate.py.", float)
+    "(v5e: 7.1ns at random positions, 8.2-9.2ns at late "
+    "materialization's sorted ones, up to 23ns for 2^20 of them). Fit by "
+    "tools/calibrate.py.", float)
 COST_FUSED_ROW = _entry(
-    "sdot.querycostmodel.fused.seconds.per.row", 2.3e-9,
+    "sdot.querycostmodel.fused.seconds.per.row", 3.3e-10,
     "Measured seconds per row of the fused Pallas small-K group-by "
-    "kernel's single streamed pass (v5e: ~2.3ms / M rows). Governs the "
-    "ffl-route compaction ceiling: below it, compact-then-re-gather "
-    "loses to just streaming every row through the kernel.", float)
+    "kernel's single streamed pass (v5e: 1.0-2.0ms of device time for "
+    "6.0M rows, TPC-H q5/q12/q1). Governs the ffl-route compaction "
+    "ceiling: the kernel streams a row faster than the compaction sort "
+    "orders it, so an ffl statement compacts only when told to.", float)
 # --- engine knobs (TPU-specific; no reference analog) -------------------------
 SEGMENT_ROWS = _entry(
     "sdot.segment.target.rows", 1 << 20,

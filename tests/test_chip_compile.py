@@ -295,7 +295,7 @@ def test_literal_operand_program_compiles(chip_mode, one_chip, store):
     lits, days = eng._plan_literals(q, ds, dim_plans, min_day, max_day)
     assert lits.count == 7 and days is None
     assert "0.05" not in lits.shape and "24" not in lits.shape
-    fn, _ = eng._build_agg_program(
+    fn, *_ = eng._build_agg_program(
         ds, dim_plans, agg_plans, q.filter, q.intervals, days, n_keys,
         False, routes, lits=lits)
     words = lits.pack()

@@ -7,6 +7,13 @@ CompactScanContext). These tests force the path at test scale via
 `sdot.engine.scan.compact.min.rows` and diff against the uncompacted
 engine: identical results, including the overflow-retry route when the
 selectivity estimate is wildly wrong.
+
+The survivors' arrays reach the prefix in one of two forms, chosen from
+static shapes and the backend's unit costs (ops.scan.carries_by_sort):
+as payloads of the compaction sort, or by a gather each. The CPU's
+constants always gather, so the cases below pin each form through
+`sdot.querycostmodel.gather.seconds.per.probe` — the one constant only
+this stage reads — and hold the two forms to bit-equal answers.
 """
 
 import numpy as np
@@ -28,12 +35,32 @@ def _df(n=6000, seed=7):
     })
 
 
-def _ctx(compact: bool):
-    c = sdot.Context()
-    c.config.set("sdot.engine.scan.compact", compact)
-    if compact:
+FORMS = ("sort", "gather")
+PROBE_KEY = "sdot.querycostmodel.gather.seconds.per.probe"
+
+
+def _pin(c, form):
+    """``form`` None: no compaction; "sort" / "gather": compaction at
+    test scale in that form (a probe priced at a second makes every
+    payload cheaper, one priced at nothing makes every gather cheaper)."""
+    c.config.set("sdot.engine.scan.compact", form is not None)
+    if form is not None:
         c.config.set("sdot.engine.scan.compact.min.rows", 0)
-    c.ingest_dataframe("sales", _df(), time_column="ts", target_rows=1024)
+        c.config.set(PROBE_KEY, {"sort": 1.0, "gather": 1e-15}[form])
+    return c
+
+
+def _ctx(compact, df=None):
+    """``compact``: False / True (the backend's own form) or a form."""
+    c = sdot.Context()
+    if compact in FORMS:
+        _pin(c, compact)
+    else:
+        c.config.set("sdot.engine.scan.compact", compact)
+        if compact:
+            c.config.set("sdot.engine.scan.compact.min.rows", 0)
+    c.ingest_dataframe("sales", _df() if df is None else df,
+                       time_column="ts", target_rows=1024)
     return c
 
 
@@ -230,3 +257,332 @@ def test_wave_mode_compaction_overflow_retries(monkeypatch):
     assert st.get("compact_overflow", 0) > 0
     ref = _wave_ctx(False).sql(WAVE_SQL).to_pandas()
     pd.testing.assert_frame_equal(got, ref, check_dtype=False, atol=1e-6)
+
+
+# -- the two forms of late materialization ------------------------------------
+#
+# Every compacting shape above, in both forms: each form against the
+# uncompacted program, the two forms against each other bit for bit, and
+# the record naming the form that ran and how many arrays it brought.
+
+def _sql_case(sql, prepare=None):
+    def run(form, monkeypatch):
+        c = _ctx(form or False)
+        if prepare is not None:
+            prepare(c, monkeypatch)
+        got = c.sql(sql).to_pandas()
+        return got, dict(c.history.entries()[-1].stats)
+    return run
+
+
+def _hashed(sortedrun):
+    def prepare(c, monkeypatch):
+        c.config.set("sdot.engine.groupby.dense.max.keys", 8)
+        c.config.set("sdot.engine.groupby.hash.sortedrun", sortedrun)
+    return prepare
+
+
+def _lying_estimate(c, monkeypatch):
+    from spark_druid_olap_tpu.parallel import cost as C
+    monkeypatch.setattr(C, "_filter_selectivity", lambda f, ds: 1e-5)
+
+
+def _sharded_case(form, monkeypatch):
+    """Per-shard late materialization on the 8-device mesh: N and M are
+    a shard's, so the form is chosen from per-shard shapes."""
+    import dataclasses as _dc
+    from spark_druid_olap_tpu.ir import spec as S
+    from spark_druid_olap_tpu.parallel.mesh import make_mesh
+    from spark_druid_olap_tpu.planner import builder as B
+    from spark_druid_olap_tpu.sql.parser import parse_select
+    c = _pin(sdot.Context(mesh=make_mesh()), form)
+    c.ingest_dataframe("sales", _df(12000), time_column="ts",
+                       target_rows=1024)
+    q = B.build(c, parse_select(
+        "select region, sum(qty) as s, avg(price) as ap, count(*) as n "
+        "from sales where sku = 'sku007' group by region "
+        "order by region")).specs[0]
+    q = _dc.replace(q, context=_dc.replace(
+        q.context or S.QueryContext(), prefer_sharded=True))
+    got = c.engine.execute(q).to_pandas()
+    st = dict(c.engine.last_stats)
+    assert st["sharded"] is True
+    return got.sort_values("region").reset_index(drop=True), st
+
+
+def _wave_case(form, monkeypatch):
+    c = _pin(_wave_ctx(False), form)
+    got = c.sql(WAVE_SQL).to_pandas()
+    st = dict(c.history.entries()[-1].stats)
+    assert st.get("waves", 1) > 1, f"wave mode not engaged: {st}"
+    return got, st
+
+
+def _nullable_time_case(form, monkeypatch):
+    """A nullable column and a sub-day time column: the validity array
+    rides as int8 and comes back the mask it was, `__time_ms__` rides
+    beside the day column (4 arrays: ts, __time_ms__, price and
+    __nulls__price)."""
+    rng = np.random.default_rng(5)
+    df = _df(seed=5)
+    df["ts"] = pd.Timestamp("2020-01-01") + pd.to_timedelta(
+        rng.integers(0, 90 * 86400, len(df)), unit="s")
+    df["price"] = df["price"].where(rng.random(len(df)) > 0.3)
+    c = _ctx(form or False, df)
+    got = c.sql("select extract(hour from ts) as h, count(price) as np, "
+                "sum(price) as p, count(*) as n from sales "
+                "where sku = 'sku007' group by 1 order by 1").to_pandas()
+    assert 0 < got["np"].sum() < got["n"].sum()
+    return got, dict(c.history.entries()[-1].stats)
+
+
+HASHED_SQL = ("select sku, sum(qty) as s, sum(price) as p, count(*) as n "
+              "from sales where region = 'east' and qty = 7 "
+              "group by sku order by sku limit 30")
+
+# name -> (runner, arrays the compacted body reads; None: the budget
+# overflows and the statement is answered uncompacted)
+FORM_CASES = {
+    "dense_selector": (_sql_case(QUERIES[0]), 2),
+    "dense_in_expr": (_sql_case(QUERIES[1]), 2),
+    "dense_global_minmax": (_sql_case(QUERIES[2]), 2),
+    "dense_time_bucket": (_sql_case(QUERIES[3]), 3),
+    "hashed_scatter": (_sql_case(HASHED_SQL, _hashed("off")), 3),
+    "hashed_sorted_run": (_sql_case(HASHED_SQL, _hashed("on")), 3),
+    "staged_membership": (_sql_case(
+        "select region, count(*) as n, sum(qty) as s from sales "
+        "where sku = 'sku007' and qty * 100 + 1 in ("
+        + ", ".join(str(k) for k in range(1, 5000, 83)) + ") "
+        "group by region order by region"), 2),
+    "sketches": (_sql_case(
+        "select region, approx_count_distinct(sku) as d from sales "
+        "where sku in ('sku001','sku002','sku003','sku004','sku005') "
+        "group by region order by region"), 2),
+    "all_rows_filtered": (_sql_case(
+        "select count(*) as n, sum(qty) as s, max(price) as mx from sales "
+        "where sku = 'sku001' and qty > 98 and qty < 1"), 2),
+    "overflow_retry": (_sql_case(
+        "select region, count(*) as n, sum(price) as p from sales "
+        "where qty >= 0 group by region order by region",
+        _lying_estimate), None),
+    "nullable_time": (_nullable_time_case, 4),
+    "sharded8": (_sharded_case, 3),
+    "wave": (_wave_case, 3),
+}
+
+
+@pytest.fixture(scope="module")
+def form_runs():
+    """(case, form) -> (frame, record), each run once for the module:
+    the per-form and the form-against-form tests read the same runs."""
+    return {}
+
+
+def _form_run(form_runs, case, form, monkeypatch):
+    if (case, form) not in form_runs:
+        form_runs[case, form] = FORM_CASES[case][0](form, monkeypatch)
+    return form_runs[case, form]
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("case", sorted(FORM_CASES))
+def test_form_matches_uncompacted(case, form, form_runs, monkeypatch):
+    got, st = _form_run(form_runs, case, form, monkeypatch)
+    want, plain = _form_run(form_runs, case, None, monkeypatch)
+    assert "compact_m" not in plain and "compact_carry" not in plain
+    pd.testing.assert_frame_equal(got, want, check_dtype=False, atol=1e-6)
+    cols = FORM_CASES[case][1]
+    if cols is None:
+        assert st.get("compact_overflow", 0) > 0
+        assert "compact_m" not in st and "compact_carry" not in st
+    else:
+        assert st.get("compact_m", 0) > 0, st
+        assert (st["compact_carry"], st["compact_cols"]) == (form, cols)
+
+
+@pytest.mark.parametrize("case", sorted(FORM_CASES))
+def test_forms_bit_equal(case, form_runs, monkeypatch):
+    """The prefix holds the same rows in the same order either way, so
+    every float sum adds in the same order: not a bit may differ."""
+    a, _ = _form_run(form_runs, case, "sort", monkeypatch)
+    b, _ = _form_run(form_runs, case, "gather", monkeypatch)
+    pd.testing.assert_frame_equal(a, b, check_exact=True)
+
+
+# -- the stage alone: ops.scan.compact_scan -----------------------------------
+
+def test_shape_rule_picks_the_form():
+    """Sort where N * payload < M * probe: on the v5e's constants where
+    M * 13 > N (q3 carries, a 2^15 budget over 6.0M rows gathers); on
+    the CPU's never — a budget is at most half the rows."""
+    import jax
+    from spark_druid_olap_tpu.ops.scan import carries_by_sort
+    from spark_druid_olap_tpu.parallel import cost as C
+    from spark_druid_olap_tpu.utils import config as CF
+    pay = float(CF.COST_SORT_PAYLOAD_ROW.default)      # the v5e's
+    probe = float(CF.COST_GATHER_PROBE.default)
+    assert carries_by_sort(4_001_792, 1 << 20, pay, probe)        # q3
+    assert not carries_by_sort(6_002_688, 1 << 15, pay, probe)
+    edge = probe / pay                  # M * edge > N carries
+    assert 5 < edge < 25
+    assert carries_by_sort(1_000_000, int(1_000_000 / edge) + 1000,
+                           pay, probe)
+    assert not carries_by_sort(1_000_000, int(1_000_000 / edge) - 1000,
+                               pay, probe)
+    assert jax.default_backend() == "cpu"
+    cfg = sdot.Context().config
+    pay = C.unit_cost(cfg, CF.COST_SORT_PAYLOAD_ROW)
+    probe = C.unit_cost(cfg, CF.COST_GATHER_PROBE)
+    for n, m in ((4_001_792, 1 << 20), (6_002_688, 1 << 15), (1000, 500)):
+        assert not carries_by_sort(n, m, pay, probe)
+
+
+def _stage_arrays(n_seg=4, rows=512, seed=3):
+    from spark_druid_olap_tpu.ops import scan as SC
+    rng = np.random.default_rng(seed)
+    shape = (n_seg, rows)
+    return {
+        "code": rng.integers(-100, 100, shape).astype(np.int16),
+        "val": rng.random(shape).astype(np.float32),
+        SC.NULL_VALID_PREFIX + "val": rng.random(shape) > 0.4,
+        SC.TIME_MS_KEY: rng.integers(0, 86_400_000, shape).astype(np.int32),
+        "cheap_only": rng.integers(0, 10, shape).astype(np.int32),
+        SC.ROW_VALID_KEY: rng.random(shape) > 0.05,
+    }
+
+
+def _stage_body(ctx, base):
+    return {"code": ctx.col("code"), "val": ctx.col("val"),
+            "nulls": ctx.null_valid("val"), "ms": ctx.time_ms(),
+            "base": base}
+
+
+def _stage(arrays, mask, m, sort):
+    """The stage run eagerly over ``arrays``: a payload priced at nothing
+    carries by sort, at a second gathers."""
+    from spark_druid_olap_tpu.ops import scan as SC
+    ctx = SC.ScanContext(None, arrays, 0, 0)
+    cctx, base, n_over = SC.compact_scan(
+        ctx, mask, m, _stage_body, 1e-15 if sort else 1.0, 1e-9)
+    return _stage_body(cctx, base), n_over, cctx.carried()
+
+
+@pytest.mark.parametrize("live", ["under", "exact", "over"])
+def test_stage_prefix_and_base(live):
+    """Both forms hold the survivors in row order in the [M] prefix; the
+    validity array rides as int8 and returns a bool mask; `base` — now an
+    iota compare — equals the old read ``flat[keep]``; overflow counts."""
+    import jax.numpy as jnp
+    m = 256
+    arrays = _stage_arrays()
+    n = arrays["code"].size
+    want_live = {"under": 100, "exact": m, "over": m + 37}[live]
+    rng = np.random.default_rng(9)
+    mask = np.zeros(n, bool)
+    mask[rng.choice(n, want_live, replace=False)] = True
+    mask = mask.reshape(arrays["code"].shape)
+    dev = {k: jnp.asarray(v) for k, v in arrays.items()}
+    # what the parent program read: survivors first under a stable sort
+    keep = np.argsort(~mask.reshape(-1), kind="stable")[:m]
+    old_base = mask.reshape(-1)[keep]
+    k = min(want_live, m)
+    outs = {}
+    for sort in (True, False):
+        out, n_over, carried = _stage(dev, jnp.asarray(mask), m, sort)
+        assert carried == ("sort" if sort else "gather", 4)
+        assert int(n_over) == max(want_live - m, 0)
+        base = np.asarray(out["base"])
+        assert base.dtype == np.bool_
+        np.testing.assert_array_equal(base, old_base)
+        assert out["nulls"].dtype == jnp.bool_
+        assert out["code"].dtype == jnp.int32       # widened as col() does
+        for key, src in (("code", "code"), ("val", "val"),
+                         ("nulls", "__nulls__val"), ("ms", "__time_ms__")):
+            np.testing.assert_array_equal(
+                np.asarray(out[key])[:k], arrays[src].reshape(-1)[keep][:k])
+        outs[sort] = out
+    for key in ("code", "val", "nulls", "ms"):     # live rows, bit for bit
+        np.testing.assert_array_equal(np.asarray(outs[True][key])[:k],
+                                      np.asarray(outs[False][key])[:k])
+
+
+def test_stage_carries_only_what_the_body_reads():
+    """The cheap filter's column and the row-validity array are read
+    before compaction only: they do not ride."""
+    import jax
+    import jax.numpy as jnp
+    from spark_druid_olap_tpu.ops import scan as SC
+    arrays = {k: jnp.asarray(v) for k, v in _stage_arrays().items()}
+
+    def run(arrays):
+        ctx = SC.ScanContext(None, arrays, 0, 0)
+        mask = ctx.row_valid() & (ctx.col("cheap_only") < 3)
+        cctx, base, _ = SC.compact_scan(ctx, mask, 128, _stage_body,
+                                        1e-15, 1e-9)
+        assert sorted(cctx.taken) == sorted(
+            ["code", "val", "__nulls__val", "__time_ms__"])
+        return _stage_body(cctx, base)
+
+    text = jax.jit(run).lower(arrays).as_text()
+    assert text.count("stablehlo.sort") == 1
+    assert "gather" not in text
+
+
+def test_read_outside_the_carried_set_raises_at_trace_time():
+    """A column the recording pass did not see must fail loudly when the
+    program is traced — never fall back to a silent gather."""
+    import jax
+    import jax.numpy as jnp
+    from spark_druid_olap_tpu.ops import scan as SC
+    arrays = {k: jnp.asarray(v) for k, v in _stage_arrays().items()}
+
+    def run(arrays):
+        ctx = SC.ScanContext(None, arrays, 0, 0)
+        cctx, base, _ = SC.compact_scan(ctx, ctx.row_valid(), 128,
+                                        _stage_body, 1e-15, 1e-9)
+        return cctx.col("cheap_only")
+
+    with pytest.raises(LookupError, match="cheap_only.*did not ride"):
+        jax.jit(run).lower(arrays)
+    # the gather form has no carried set: the same read is a gather
+    def gathers(arrays):
+        ctx = SC.ScanContext(None, arrays, 0, 0)
+        cctx, base, _ = SC.compact_scan(ctx, ctx.row_valid(), 128,
+                                        _stage_body, 1.0, 1e-9)
+        return cctx.col("cheap_only")
+    assert jax.jit(gathers)(arrays).shape == (128,)
+
+
+def test_gate_prices_the_form_the_program_runs(monkeypatch):
+    """``_plan_compact_m`` charges each touched column the cheaper of a
+    sort payload over the rows and a gather of the budget, on the v5e's
+    constants: TPC-H q3's shape at SF1 (hashed, 4.0M rows, a 2^20
+    budget) compacts; q5's (6.0M rows through the fused kernel, an 'ffl'
+    route, which streams a row faster than a sort orders it) does not —
+    at the old `fused.seconds.per.row` the payload price would have let
+    it in."""
+    from types import SimpleNamespace as NS
+    from spark_druid_olap_tpu.parallel import cost as C
+    from spark_druid_olap_tpu.utils import config as CF
+    eng = sdot.Context().engine
+    for e in (CF.COST_SORT_ROW, CF.COST_SORT_PAYLOAD_ROW,
+              CF.COST_GATHER_PROBE, CF.COST_SCATTER_UPDATE,
+              CF.COST_SCATTER_UPDATE_BIG, CF.COST_FUSED_ROW):
+        eng.config.set(e.key, e.default)        # the chip's, on any backend
+
+    def ds(n_seg):
+        return NS(segments=[NS(num_rows=1_000_448)] * n_seg)
+
+    monkeypatch.setattr(C, "_filter_selectivity", lambda f, d: 0.097)
+    q3 = eng._plan_compact_m(ds(4), range(4), object(), False,
+                             n_keys=1 << 21, n_ops=3)
+    assert q3 == 1 << 20
+    monkeypatch.setattr(C, "_filter_selectivity", lambda f, d: 0.0634)
+    ffl = {k: NS(tag="ffl", outputs=lambda k: [("x", 2, "f32")])
+           for k in ("revenue", "__rows__")}
+    assert eng._plan_compact_m(ds(6), range(6), object(), False,
+                               routes=ffl, n_keys=25) is None
+    eng.config.set(CF.COST_FUSED_ROW.key, 2.3e-9)
+    eng.config.set(CF.COST_SORT_ROW.key, 2.2e-10)
+    assert eng._plan_compact_m(ds(6), range(6), object(), False,
+                               routes=ffl, n_keys=25) == 1 << 20
