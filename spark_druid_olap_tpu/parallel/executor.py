@@ -824,6 +824,56 @@ class QueryEngine:
                               for b in jax.tree_util.tree_leaves(bufs)))
             return unpack(bufs)
 
+    def _run_program(self, prog, args, unpack):
+        """One dispatch of a program whose operands are on the device
+        already (a table's second program, a row mask)."""
+        self._tick()
+        with PH.phase("dispatch"):
+            return self._fetch(unpack, self._wait(self._launch(prog, args)))
+
+    def _waves(self, q, t0, ds, names, wave_segs, s_pad, sharded, lits,
+               prog, unpack=None):
+        """The wave pipeline every tier runs, double-buffered; yields
+        each wave's fetched result after its ``dispatch`` span closed, so
+        what the consumer does with it — merge, a second program, stop —
+        is no part of that span. In the span, in this order: the launch;
+        behind it wave i+2's cold chunks start loading and wave i+1
+        binds (a ``bind`` span inside wave i's ``dispatch``: the transfer
+        overlaps the compute); the wait; the fetch through ``unpack``.
+        Without ``unpack`` the program returns a table that stays on the
+        device for a second program: only its ``__stats__`` travel, and
+        the wave yields (table, stats). One wave binds through the
+        device cache (``_bind_arrays``: the arrays stay resident between
+        statements); several bind uncached, wave mode existing because
+        the scan exceeds the device budget."""
+        sharding = NamedSharding(self.mesh, P(SEGMENT_AXIS, None)) \
+            if sharded else None
+        multihost = sharded and MH.is_multihost()
+
+        def bind(i):
+            return self._bind_wave(ds, names, wave_segs[i], s_pad, sharding,
+                                   multihost, lits)
+
+        # cold tier: wave 1's chunks load while wave 0 binds + computes
+        self._tier_prefetch(ds, names, wave_segs, 1)
+        cur = self._bind_arrays(ds, names, wave_segs[0], s_pad, sharded,
+                                lits) if len(wave_segs) == 1 else bind(0)
+        for i in range(len(wave_segs)):
+            if t0 is not None:
+                self._stage_check(q, t0)     # per-wave boundary
+            self._tick()
+            with PH.phase("dispatch"):
+                bufs = self._launch(prog, cur, fetch=unpack is not None)
+                self._tier_prefetch(ds, names, wave_segs, i + 2)
+                cur = bind(i + 1) if i + 1 < len(wave_segs) else None
+                if unpack is not None:
+                    out = self._fetch(unpack, self._wait(bufs))
+                else:
+                    table = self._wait(dict(bufs))
+                    out = table, self._fetch(np.asarray,
+                                             table.pop("__stats__"))
+            yield out
+
     # -- cancellation / timeout ----------------------------------------------
     def register_query(self, query_id: str) -> None:
         """Register a cancellable id BEFORE planning starts, so a cancel
@@ -1082,19 +1132,12 @@ class QueryEngine:
 
     def _execute_inner(self, q: S.QuerySpec, t0: float) -> QueryResult:
         self._stage_check(q, t0)
-        if isinstance(q, S.GroupByQuerySpec):
-            r = self._run_agg(q, list(q.dimensions), q.aggregations,
-                              q.post_aggregations, q.having, q.limit,
-                              q.granularity, q.filter, q.intervals, t0)
-        elif isinstance(q, S.TimeseriesQuerySpec):
-            r = self._run_agg(q, [], q.aggregations, q.post_aggregations,
-                              None, None, q.granularity, q.filter,
+        shape = _agg_shape(q)
+        if shape is not None:
+            dims, having, limit = shape
+            r = self._run_agg(q, dims, q.aggregations, q.post_aggregations,
+                              having, limit, q.granularity, q.filter,
                               q.intervals, t0)
-        elif isinstance(q, S.TopNQuerySpec):
-            limit = S.topn_limit(q)
-            r = self._run_agg(q, [q.dimension], q.aggregations,
-                              q.post_aggregations, None, limit,
-                              q.granularity, q.filter, q.intervals, t0)
         elif isinstance(q, S.SelectQuerySpec):
             r = self._run_select(q)
         elif isinstance(q, S.SearchQuerySpec):
@@ -1146,7 +1189,6 @@ class QueryEngine:
                                         filter_spec, intervals)
         lits, days = self._plan_literals(q, ds, all_dim_plans, min_day,
                                          max_day)
-        cards = [p.card for p in all_dim_plans]
 
         if bool(self.config.get(SHAREDSCAN_FUSION_ENABLED)):
             # solo-path CSE accounting, at PLAN time so warm program-
@@ -1215,16 +1257,8 @@ class QueryEngine:
         top_idx = None
         # the statement's SHAPE, not its literal values; the selected
         # segments' day range only where the program is built from it
-        base_sig = (ds.name, id(ds), lits.shape, s_pad, ds.padded_rows,
-                    days, sharded, n_dev, tuple(names),
-                    self.config.get(TZ_ID),
-                    self.config.get(GROUPBY_MATMUL_MAX_KEYS),
-                    self.config.get(HLL_LOG2M),
-                    self.config.get(QUANTILE_LANES),
-                    bool(self.config.get(ENCODE_ENABLED)),
-                    jax.default_backend(),
-                    bool(jax.config.jax_enable_x64),
-                    bool(self.config.get(SHAREDSCAN_FUSION_ENABLED)))
+        base_sig = (self._sig_base(ds), lits.shape, s_pad, days, sharded,
+                    n_dev, tuple(names))
         if having_dev:
             # two dispatches: finals stay device-resident, only the mask
             # count then the passing groups travel
@@ -1233,17 +1267,11 @@ class QueryEngine:
                 sigA, lambda: self._build_agg_table_program(
                     ds, all_dim_plans, agg_plans, filter_spec, intervals,
                     days, n_keys, sharded, routes, having_dev, lits))
-            dev_arrays = self._bind_arrays(ds, names, seg_idx, s_pad,
-                                           sharded, lits)
-            if t0 is not None:
-                self._stage_check(q, t0)
-            self._tick()
-            with PH.phase("dispatch"):
-                # the table stays on the device; only its count travels
-                table = self._wait(dict(self._launch(progA, dev_arrays,
-                                                     fetch=False)))
-                cnt = int(self._fetch(np.asarray,
-                                      table.pop("__stats__"))[0])
+            # one wave (_plan_device_having): the table stays on the
+            # device, only its count travels
+            (table, stats), = self._waves(q, t0, ds, names, [seg_idx],
+                                          s_pad, sharded, lits, progA)
+            cnt = int(stats[0])
             n_out = min(n_keys,
                         1 << max(6, (max(cnt, 1) - 1).bit_length()))
             # most groups pass: the [n_keys] top_k sort costs more than
@@ -1255,153 +1283,50 @@ class QueryEngine:
                 (sigA, "gather", n_out, full),
                 lambda: self._build_agg_gather_program(
                     agg_plans, routes, n_out, n_keys, sharded, full=full))
-            self._tick()
-            with PH.phase("dispatch"):
-                out = self._fetch(unpackB,
-                                  self._wait(self._launch(gfn, table)))
-            if t0 is not None:
-                self._stage_check(q, t0)
+            out = self._run_program(gfn, table, unpackB)
             finals = _finals_from_out(out, routes, n_out, sketch_plans)
             if not full:
                 top_idx = np.asarray(out["__topk_idx__"]) \
                     .astype(np.int64)
             # full mode: rows travel in key order — decode's identity
             # path (top_idx None) already maps sel -> key ids
-        elif n_waves == 1:
-            # budget from the CHEAP conjuncts only: staged gather-heavy
-            # conjuncts apply after compaction and don't shrink what the
-            # prefix must hold
-            cheap_f0, _ = self._split_filter_staged(filter_spec)
-            compact_m = self._plan_compact_m(ds, seg_idx, cheap_f0,
-                                             sharded, routes=routes,
-                                             n_dev=n_dev,
-                                             allow_sharded=True,
-                                             n_keys=n_keys)
-            if compact_m and ("agg", base_sig, topk, _cache_repr(q)) \
-                    in self._compact_overflowed:
-                compact_m = None     # this statement overflowed before: the
-                # estimate is structurally off for its values (learned
-                # state is keyed by them, not by the shape), don't re-pay
-                # the double execution on every warm run
-            late = self._late(compact_m)
-            for cm in ((late, None) if late else (None,)):
-                prog_fn, unpack, compact = self._cached_program(
-                    ("agg", base_sig, topk, cm),
-                    lambda cm=cm: self._build_agg_program(
-                        ds, all_dim_plans, agg_plans, filter_spec,
-                        intervals, days, n_keys, sharded,
-                        routes, topk=topk, late=cm, lits=lits))
-                dev_arrays = self._bind_arrays(ds, names, seg_idx, s_pad,
-                                               sharded, lits)
-                if t0 is not None:
-                    self._stage_check(q, t0)  # pre-dispatch boundary
-                self._tick()
-                with PH.phase("dispatch"):
-                    out = self._fetch(unpack, self._wait(
-                        self._launch(prog_fn, dev_arrays)))
-                if t0 is not None:
-                    self._stage_check(q, t0)  # post-device boundary
-                over = out.pop("__over__", None)
-                if over is None or int(np.asarray(over).reshape(-1)[0]) == 0:
-                    self._note_compaction(compact)
-                    break
-                # est. selectivity too optimistic: retry uncompacted and
-                # remember this program shape so warm runs skip straight
-                # to the uncompacted program
-                self.last_stats["compact_overflow"] = \
-                    int(np.asarray(over).reshape(-1)[0])
-                self._compact_overflowed.add(
-                    ("agg", base_sig, topk, _cache_repr(q)))
-            finals = _finals_from_out(out, routes, n_out, sketch_plans)
-            if topk:
-                top_idx = np.asarray(out["__topk_idx__"]).astype(np.int64)
         else:
-            # wave-mode late materialization (VERDICT r3 item 9): the
-            # same compact block runs INSIDE each wave's program with a
-            # per-wave survivor budget (first wave's rows stand in for
-            # all — waves are equal-sized splits); any wave overflowing
-            # its budget folds into '__over__' and the whole scan
-            # re-runs uncompacted, exactly the single-wave protocol
+            # late materialization: the compact block runs INSIDE each
+            # wave's program under a per-wave survivor budget (the first
+            # wave's rows stand in for all — waves are equal-sized
+            # splits), priced from the CHEAP conjuncts only: staged
+            # gather-heavy conjuncts apply after compaction and don't
+            # shrink what the prefix must hold
             cheap_f0, _ = self._split_filter_staged(filter_spec)
             compact_m = self._plan_compact_m(
                 ds, seg_idx[:spw], cheap_f0, sharded, routes=routes,
                 n_dev=n_dev, allow_sharded=True, n_keys=n_keys)
-            if compact_m and ("aggw", base_sig, _cache_repr(q)) \
-                    in self._compact_overflowed:
-                compact_m = None
-            late = self._late(compact_m)
-            for cm in ((late, None) if late else (None,)):
+
+            def run(late):
                 prog_fn, unpack, compact = self._cached_program(
-                    ("agg", base_sig, None, cm),
-                    lambda cm=cm: self._build_agg_program(
+                    ("agg", base_sig, topk, late),
+                    lambda: self._build_agg_program(
                         ds, all_dim_plans, agg_plans, filter_spec,
                         intervals, days, n_keys, sharded,
-                        routes, topk=None, late=cm, lits=lits))
-                finals, wave_over = self._run_waves(
-                    q, ds, names, seg_idx, spw, sharded, prog_fn, unpack,
-                    routes, n_keys, sketch_plans, t0, lits)
-                if not wave_over:
-                    self._note_compaction(compact)
-                    break
-                self.last_stats["compact_overflow"] = int(wave_over)
-                self._compact_overflowed.add(
-                    ("aggw", base_sig, _cache_repr(q)))
+                        routes, topk=topk, late=late, lits=lits))
+                finals, n_over, out = self._run_waves(
+                    q, ds, names, seg_idx, s_pad, sharded, prog_fn, unpack,
+                    routes, n_out, sketch_plans, t0, lits)
+                return (finals, out), n_over, compact
 
-        # --- decode -----------------------------------------------------------
+            late = self._late(compact_m)
+            # (the key holds the statement's repr: built under a budget only)
+            finals, out = self._retry_uncompacted(
+                late and ("agg", base_sig, topk, _cache_repr(q)), late, run)
+            if topk:
+                top_idx = np.asarray(out["__topk_idx__"]).astype(np.int64)
+        if t0 is not None:
+            self._stage_check(q, t0)      # post-device boundary
+
         with PH.phase("decode"):
-            rows = finals["__rows__"]
-            sel = np.nonzero(rows > 0)[0]
-            # a GLOBAL aggregate (no dims, no time bucketing) over zero
-            # matching rows yields ONE identity row — SQL semantics (and
-            # Druid's default timeseries behavior, minus its sum-is-0
-            # quirk: we emit NULL sums)
-            global_empty = (not all_dim_plans and gran_kind == "all"
-                            and len(sel) == 0)
-            if global_empty:
-                sel = np.zeros(1, dtype=np.int64)
-            data: Dict[str, np.ndarray] = {}
-            columns: List[str] = []
-            if all_dim_plans:
-                key_ids = top_idx[sel] if top_idx is not None else sel
-                code_lists = G.unfuse_key(key_ids, cards)
-                for p, codes in zip(all_dim_plans, code_lists):
-                    data[p.output_name] = p.decode(codes)
-                    columns.append(p.output_name)
-            for p in agg_plans:
-                name = p.spec.name
-                if p.kind in ("hll", "theta", "kll"):
-                    regs = finals[name]
-                    if self.partial_sketches:
-                        # cluster historical mode: ship the raw [G, m]
-                        # register block; the broker merges registers
-                        # across shards (max/min/minsum) and finalizes the
-                        # estimate once (cluster/merge.py) — that is what
-                        # makes the distributed estimate EQUAL the
-                        # single-engine one, not merely close
-                        data[name] = np.asarray(regs)[sel]
-                        columns.append(name)
-                        continue
-                    if p.kind == "kll":
-                        data[name] = KLL.estimate(
-                            regs, p.spec.fraction or 0.5)[sel]
-                        columns.append(name)
-                        continue
-                    est = (HLL.estimate(regs) if p.kind == "hll"
-                           else TH.estimate(regs))[sel]
-                    data[name] = np.round(est).astype(np.int64)
-                    columns.append(name)
-                    continue
-                r = routes[name]
-                v = finals[name][sel]
-                data[name] = _decode_agg_value(ds, p, r, v)
-                columns.append(name)
-            if global_empty:
-                data.update(_identity_row(
-                    {p.spec.name: p.kind for p in agg_plans
-                     if p.kind in ("sum", "min", "max")}))
-
-            data = self._agg_epilogue(data, columns, post_aggregations, having,
-                                      limit)
+            result, n_groups = self._decode_dense(
+                ds, all_dim_plans, agg_plans, routes, finals, gran_kind,
+                post_aggregations, having, limit, top_idx)
 
         if topk and not isinstance(q, S.TopNQuerySpec):
             # exact-contract GroupBy: the candidate selection is
@@ -1409,7 +1334,7 @@ class QueryEngine:
             # or re-run with the full-table transfer (ADVICE r2)
             scores = np.asarray(out["__topk_score__"], np.float64)
             if not _topk_selection_exact(limit, topk, routes[topk[0]],
-                                         scores, data):
+                                         scores, result.data):
                 return self._run_agg(q, dimensions, aggregations,
                                      post_aggregations, having, limit,
                                      granularity, filter_spec, intervals,
@@ -1417,13 +1342,67 @@ class QueryEngine:
 
         self.last_stats.update({
             "datasource": ds.name, "segments": int(n_seg_sel),
-            "sharded": sharded, "groups": int(len(sel)),
+            "sharded": sharded, "groups": n_groups,
             "rows_scanned": int(ds.num_rows), "waves": int(n_waves),
             "segments_per_wave": int(spw),
             "bytes_scanned": int(seg_bytes) * int(n_seg_sel),
             "topk_device": int(topk[1]) if topk else 0,
             "having_device": int(n_out) if having_dev else 0})
-        return QueryResult(columns, data)
+        return result
+
+    def _decode_dense(self, ds, dim_plans, agg_plans, routes, finals,
+                      gran_kind, post_aggregations, having, limit,
+                      top_idx=None):
+        """Dense finals -> (QueryResult, groups selected): group
+        selection, dictionary decode, sketch estimates, the global
+        aggregate's identity row, the host epilogue. The caller names the
+        span: ``decode`` for a solo statement, ``demux`` for a lane of a
+        fused group. ``top_idx`` maps a device top-k's rows to key ids;
+        None where rows travel in key order."""
+        sel = np.nonzero(finals["__rows__"] > 0)[0]
+        # a GLOBAL aggregate (no dims, no time bucketing) over zero
+        # matching rows yields ONE identity row — SQL semantics (and
+        # Druid's default timeseries behavior, minus its sum-is-0
+        # quirk: we emit NULL sums)
+        global_empty = (not dim_plans and gran_kind == "all"
+                        and len(sel) == 0)
+        if global_empty:
+            sel = np.zeros(1, dtype=np.int64)
+        data: Dict[str, np.ndarray] = {}
+        columns: List[str] = []
+        if dim_plans:
+            key_ids = top_idx[sel] if top_idx is not None else sel
+            code_lists = G.unfuse_key(key_ids, [p.card for p in dim_plans])
+            for p, codes in zip(dim_plans, code_lists):
+                data[p.output_name] = p.decode(codes)
+                columns.append(p.output_name)
+        for p in agg_plans:
+            name = p.spec.name
+            columns.append(name)
+            if p.kind not in ("hll", "theta", "kll"):
+                data[name] = _decode_agg_value(ds, p, routes[name],
+                                               finals[name][sel])
+            elif self.partial_sketches:
+                # cluster historical mode: ship the raw [G, m] register
+                # block; the broker merges registers across shards
+                # (max/min/minsum) and finalizes the estimate once
+                # (cluster/merge.py) — that is what makes the distributed
+                # estimate EQUAL the single-engine one, not merely close
+                data[name] = np.asarray(finals[name])[sel]
+            elif p.kind == "kll":
+                data[name] = KLL.estimate(finals[name],
+                                          p.spec.fraction or 0.5)[sel]
+            else:
+                est = (HLL.estimate(finals[name]) if p.kind == "hll"
+                       else TH.estimate(finals[name]))[sel]
+                data[name] = np.round(est).astype(np.int64)
+        if global_empty:
+            data.update(_identity_row(
+                {p.spec.name: p.kind for p in agg_plans
+                 if p.kind in ("sum", "min", "max")}))
+        data = self._agg_epilogue(data, columns, post_aggregations, having,
+                                  limit)
+        return QueryResult(columns, data), len(sel)
 
     def _plan_literals(self, q, ds, dim_plans, min_day, max_day):
         """(the statement's literal plan, the day range its program's
@@ -1593,6 +1572,26 @@ class QueryEngine:
                 C.unit_cost(self.config, CF.COST_SORT_PAYLOAD_ROW),
                 C.unit_cost(self.config, CF.COST_GATHER_PROBE))
 
+    def _retry_uncompacted(self, key, late, run):
+        """Late materialization's overflow protocol. ``run(late)`` runs
+        the scan under the budget ``late`` (None: uncompacted, and
+        ``key`` is not looked at) and returns (result, rows over the
+        budget, the program's Compaction). The estimate was too optimistic where rows are
+        over: the overflow is recorded (``compact_overflow``), the
+        statement remembered under ``key`` — its VALUES, for which the
+        estimate is structurally off, not its shape — and the scan run
+        again uncompacted; a remembered statement goes straight there
+        and does not pay the double execution on every warm run."""
+        if late and key in self._compact_overflowed:
+            late = None
+        result, n_over, compact = run(late)
+        if late and n_over:
+            self.last_stats["compact_overflow"] = int(n_over)
+            self._compact_overflowed.add(key)
+            result, _, compact = run(None)
+        self._note_compaction(compact)
+        return result
+
     def _note_compaction(self, compact):
         if compact:
             self.last_stats.update({"compact_m": compact.m,
@@ -1734,8 +1733,6 @@ class QueryEngine:
                 ds, seg_idx, n_waves, seg_bytes)
         wave_segs = [seg_idx[i: i + s_pad]
                      for i in range(0, len(seg_idx), s_pad)]
-        sharding = NamedSharding(self.mesh, P(SEGMENT_AXIS, None)) \
-            if sharded else None
 
         # no '__rows__' occupancy count here: occupied slots are read off
         # the key table (khi != EMPTY) directly
@@ -1791,15 +1788,9 @@ class QueryEngine:
                     metas, T, self.config.get(GROUPBY_MATMUL_MAX_KEYS),
                     n_rows=n_rows_dev)
             late_key = self._late(lm)
-            sig = ("hashagg", ds.name, id(ds), lits.shape, s_pad,
-                   ds.padded_rows, days, sharded, n_dev, T,
-                   tuple(names), topk, compact, late_key, sorted_run,
-                   self.config.get(TZ_ID),
-                   self.config.get(GROUPBY_MATMUL_MAX_KEYS),
-                   self.config.get(HLL_LOG2M),
-                   bool(self.config.get(ENCODE_ENABLED)),
-                   jax.default_backend(), bool(jax.config.jax_enable_x64),
-                   bool(self.config.get(SHAREDSCAN_FUSION_ENABLED)))
+            sig = ("hashagg", self._sig_base(ds), lits.shape, s_pad, days,
+                   sharded, n_dev, T, tuple(names), topk, compact,
+                   late_key, sorted_run)
 
             def build(late_key=late_key):
                 # the program and, under a budget, its Compaction: the
@@ -1820,84 +1811,13 @@ class QueryEngine:
             prog, late = self._cached_program(sig, build)
 
             partials, unresolved = [], 0
-
-            def bind(i):
-                return self._bind_wave(ds, names, wave_segs[i], s_pad,
-                                       sharding, multihost, lits)
-
-            # cold tier: start loading wave 1's chunks while wave 0
-            # binds and computes (load-behind-compute)
-            self._tier_prefetch(ds, names, wave_segs, 1)
-            cur = self._bind_arrays(ds, names, seg_idx, s_pad, sharded,
-                                    lits) if n_waves == 1 else bind(0)
-            for i in range(len(wave_segs)):
-                if t0 is not None:
-                    self._stage_check(q, t0)
-                if compact or exch:
-                    self._tick()
-                    with PH.phase("dispatch"):
-                        # table stays on device
-                        table = dict(self._launch(prog, cur, fetch=False))
-                        # wave i+2's cold chunks load behind wave i's
-                        # compute and wave i+1's (synchronous) bind
-                        self._tier_prefetch(ds, names, wave_segs, i + 2)
-                        nxt = bind(i + 1) if i + 1 < len(wave_segs) \
-                            else None
-                        self._wait(table)
-                        stats = self._fetch(
-                            np.asarray,
-                            table.pop("__stats__")).reshape(-1, 2)
-                    cur = nxt
-                    unresolved += int(stats[:, 0].sum())
-                    if unresolved:
-                        break
-                    if exch:
-                        metric, k_sel, ascending = exch
-                        # sums need wider per-chip candidate lists (a
-                        # key large in total can rank lower locally);
-                        # min/max are exact with k_sel alone
-                        mplan = next(p for p in agg_plans
-                                     if p.spec.name == metric)
-                        k_cand = k_sel if mplan.kind in ("min", "max") \
-                            else min(T, max(4 * k_sel, 1024))
-                        kg_used = max(kg_used, k_sel)
-                        gfn, unpackB = self._cached_program(
-                            (sig, "exchange", exch, k_cand),
-                            lambda: self._build_hash_topk_exchange_program(
-                                agg_plans, routes, metric, ascending,
-                                k_cand, k_sel, T))
-                        self._tick()
-                        with PH.phase("dispatch"):
-                            raw = self._fetch(unpackB, self._wait(
-                                self._launch(gfn, table)))
-                        partials.extend(
-                            _hash_chip_partials(raw, routes, k_sel, n_dev))
-                        continue
-                    occ_max = max(1, int(stats[:, 1].max()))
-                    kg = min(T, 1 << max(6, (occ_max - 1).bit_length()))
-                    kg_used = max(kg_used, kg)
-                    gfn, unpackB = self._cached_program(
-                        (sig, "gather", kg),
-                        lambda kg=kg: self._build_hash_gather_program(
-                            agg_plans, routes, kg, T, sharded))
-                    self._tick()
-                    with PH.phase("dispatch"):
-                        raw = self._fetch(unpackB, self._wait(
-                            self._launch(gfn, table)))
-                    partials.extend(
-                        _hash_chip_partials(raw, routes, kg, n_dev))
-                else:
-                    prog_fn, unpack = prog
-                    self._tick()
-                    with PH.phase("dispatch"):
-                        buf = self._launch(prog_fn, cur)
-                        # double buffer: the next wave's transfer (a
-                        # ``bind`` span inside this one) overlaps compute
-                        self._tier_prefetch(ds, names, wave_segs, i + 2)
-                        nxt = bind(i + 1) if i + 1 < len(wave_segs) \
-                            else None
-                        raw = self._fetch(unpack, self._wait(buf))
-                    cur = nxt
+            # the table form's program has no unpack: its table stays on
+            # the device and a wave lands as (table, stats)
+            prog_fn, unpack = (prog, None) if compact or exch else prog
+            for landed in self._waves(q, t0, ds, names, wave_segs, s_pad,
+                                      sharded, lits, prog_fn, unpack):
+                if unpack is not None:
+                    raw = landed
                     unresolved += int(raw.pop("__unres__").sum())
                     if unresolved:
                         break
@@ -1905,6 +1825,38 @@ class QueryEngine:
                         tk_scores = raw.pop("__topk_score__")
                     partials.extend(
                         _hash_chip_partials(raw, routes, k_out, n_dev))
+                    continue
+                # the table's second program, a dispatch of its own: the
+                # exchanged top-k candidates or the occupied slots
+                table, stats = landed[0], landed[1].reshape(-1, 2)
+                unresolved += int(stats[:, 0].sum())
+                if unresolved:
+                    break
+                if exch:
+                    metric, k_sel, ascending = exch
+                    # sums need wider per-chip candidate lists (a
+                    # key large in total can rank lower locally);
+                    # min/max are exact with k_sel alone
+                    mplan = next(p for p in agg_plans
+                                 if p.spec.name == metric)
+                    k_cand = k_sel if mplan.kind in ("min", "max") \
+                        else min(T, max(4 * k_sel, 1024))
+                    kg = k_sel
+                    gfn, unpackB = self._cached_program(
+                        (sig, "exchange", exch, k_cand),
+                        lambda: self._build_hash_topk_exchange_program(
+                            agg_plans, routes, metric, ascending,
+                            k_cand, k_sel, T))
+                else:
+                    occ_max = max(1, int(stats[:, 1].max()))
+                    kg = min(T, 1 << max(6, (occ_max - 1).bit_length()))
+                    gfn, unpackB = self._cached_program(
+                        (sig, "gather", kg),
+                        lambda kg=kg: self._build_hash_gather_program(
+                            agg_plans, routes, kg, T, sharded))
+                kg_used = max(kg_used, kg)
+                raw = self._run_program(gfn, table, unpackB)
+                partials.extend(_hash_chip_partials(raw, routes, kg, n_dev))
             if not unresolved:
                 self._note_compaction(late)
                 break
@@ -2441,52 +2393,29 @@ class QueryEngine:
         return self._shard_wrap("sdot_hashed_gather", run, P(SEGMENT_AXIS),
                                 P(SEGMENT_AXIS)), unpack
 
-    def _run_waves(self, q, ds, names, seg_idx, spw, sharded, prog_fn,
-                   unpack, routes, n_keys, sketch_plans, t0, lits):
-        """Execute the scan in bounded segment waves (double-buffered: the
-        next wave's host->device transfer overlaps the current wave's
-        compute), merging each wave's [K] finals on host. ≈ the reference's
-        cost-model "waves" of segments-per-query bounding per-historical
-        work (DruidQueryCostModel.scala:309-314,444)."""
-        sharding = NamedSharding(self.mesh, P(SEGMENT_AXIS, None)) \
-            if sharded else None
-        multihost = sharded and MH.is_multihost()
-        wave_segs = [seg_idx[i: i + spw]
-                     for i in range(0, len(seg_idx), spw)]
-
-        def bind(w):
-            # no caching: wave mode exists because the scan exceeds HBM
-            return self._bind_wave(ds, names, w, spw, sharding, multihost,
-                                   lits)
-
+    def _run_waves(self, q, ds, names, seg_idx, s_pad, sharded, prog_fn,
+                   unpack, routes, n_out, sketch_plans, t0, lits):
+        """The dense tier's consumer of the wave pipeline: each wave's
+        [n_out] finals merge on the host. Returns (finals, rows over the
+        compaction budget, the last wave's unpacked outputs); a wave
+        over its budget stops the scan, which the caller re-runs
+        uncompacted. ≈ the reference's cost-model "waves" of
+        segments-per-query bounding per-historical work
+        (DruidQueryCostModel.scala:309-314,444)."""
+        wave_segs = [seg_idx[i: i + s_pad]
+                     for i in range(0, len(seg_idx), s_pad)]
         finals = None
-        # cold tier: wave 1's chunks load while wave 0 binds + computes
-        self._tier_prefetch(ds, names, wave_segs, 1)
-        cur = bind(wave_segs[0])
-        for i in range(len(wave_segs)):
-            if t0 is not None:
-                self._stage_check(q, t0)   # per-wave boundary
-            self._tick()
-            with PH.phase("dispatch"):
-                bufs = self._launch(prog_fn, cur)       # async dispatch
-                # wave i+2's cold chunks load behind wave i's compute and
-                # wave i+1's (synchronous) bind, a ``bind`` span in here
-                self._tier_prefetch(ds, names, wave_segs, i + 2)
-                nxt = bind(wave_segs[i + 1]) \
-                    if i + 1 < len(wave_segs) else None
-                out = self._fetch(unpack, self._wait(bufs))
+        for out in self._waves(q, t0, ds, names, wave_segs, s_pad, sharded,
+                               lits, prog_fn, unpack):
             over = out.pop("__over__", None)
-            if over is not None:
-                n_over = int(np.asarray(over).reshape(-1)[0])
-                if n_over:
-                    # this wave's compaction budget lied: stop burning
-                    # waves, the caller re-runs the scan uncompacted
-                    return None, n_over
-            f = _finals_from_out(out, routes, n_keys, sketch_plans)
+            n_over = 0 if over is None \
+                else int(np.asarray(over).reshape(-1)[0])
+            if n_over:
+                return None, n_over, out
+            f = _finals_from_out(out, routes, n_out, sketch_plans)
             finals = f if finals is None \
                 else _merge_wave_finals(finals, f, routes, sketch_plans)
-            cur = nxt
-        return finals, 0
+        return finals, 0, out
 
     def _plan_agg(self, ds, seg_idx, dimensions, aggregations, granularity,
                   filter_spec, intervals):
@@ -2771,6 +2700,20 @@ class QueryEngine:
             fn = named_jit("sdot_agg_dense", smfn)
 
         return fn, unpack, compact
+
+    def _sig_base(self, ds):
+        """What every program signature starts from: the store the
+        program is traced over and the environment it is traced in —
+        the config keys a build reads whatever the tier, the backend and
+        its integer width. The statement's shape follows at each site."""
+        return (ds.name, id(ds), ds.padded_rows,
+                self.config.get(TZ_ID),
+                self.config.get(GROUPBY_MATMUL_MAX_KEYS),
+                self.config.get(HLL_LOG2M),
+                self.config.get(QUANTILE_LANES),
+                bool(self.config.get(ENCODE_ENABLED)),
+                jax.default_backend(), bool(jax.config.jax_enable_x64),
+                bool(self.config.get(SHAREDSCAN_FUSION_ENABLED)))
 
     def _cached_program(self, sig, build):
         """Program-cache fetch with PER-SIGNATURE compile ownership: warm
@@ -3308,10 +3251,7 @@ class QueryEngine:
             # re-runs the mask program against resident arrays instead of
             # re-uploading the filter columns every call
             arrays = self._bind_arrays(ds, names, seg_idx, s_pad, False)
-            self._tick()
-            with PH.phase("dispatch"):
-                words = self._fetch(np.asarray, self._wait(
-                    self._launch(prog, arrays)))
+            words = self._run_program(prog, arrays, np.asarray)
         except (EngineFallback, EC.Unsupported):
             return None
         shifts = np.arange(32, dtype=np.uint32)
@@ -3518,6 +3458,19 @@ class QueryEngine:
 _LOST_MARKERS = ("unavailable", "deadline_exceeded", "deadline exceeded",
                  "connection", "socket", "transport", "unreachable",
                  "device or resource busy", "premature end")
+
+
+def _agg_shape(q):
+    """(dimensions, having, limit) an aggregation spec runs under — a
+    Timeseries has none, a TopN implies ORDER BY its metric DESC LIMIT
+    its threshold — or None for a spec that is no aggregation."""
+    if isinstance(q, S.GroupByQuerySpec):
+        return list(q.dimensions), q.having, q.limit
+    if isinstance(q, S.TimeseriesQuerySpec):
+        return [], None, None
+    if isinstance(q, S.TopNQuerySpec):
+        return [q.dimension], None, S.topn_limit(q)
+    return None
 
 
 def _cache_repr(q) -> str:

@@ -148,7 +148,7 @@ def layout_segments_waves(assignment: np.ndarray, seg_idx: np.ndarray,
     per-host-per-wave count that divides ``devs_per_host``. Returns
     ``(ordered, spw)``: ``ordered`` is [n_waves_eff * spw] with ``-1``
     padding; contiguous ``spw``-slices of it are exactly the per-wave
-    layouts the executor's wave loop already slices, so ``_run_waves``
+    layouts the executor's wave pipeline already slices, so ``_waves``
     needs no multi-host awareness beyond the shard-aware bind. Every
     process computes this identically from global metadata."""
     seg_idx = np.asarray(seg_idx, dtype=np.int64)

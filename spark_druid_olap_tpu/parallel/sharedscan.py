@@ -414,17 +414,11 @@ class SharedScanCoalescer:
                 and PW.wave_eligible(
                     lanes, int(eng.config.get(PALLAS_WAVE_MAX_LANES)))
 
-            sig = ("aggmulti", ds.name, id(ds), s_pad, ds.padded_rows,
-                   min_day, max_day, tuple(union_names),
-                   eng.config.get(TZ_ID),
-                   eng.config.get(GROUPBY_MATMUL_MAX_KEYS),
-                   eng.config.get(HLL_LOG2M),
-                   eng.config.get(QUANTILE_LANES), jax.default_backend(),
-                   bool(jax.config.jax_enable_x64), sigs,
+            sig = ("aggmulti", eng._sig_base(ds), s_pad, min_day, max_day,
+                   tuple(union_names), sigs,
                    # the fusion plan shapes the traced program: the token is
                    # a pure function of the sorted lane set (arrival-order
                    # independent), None when planning declined or failed
-                   bool(eng.config.get(SHAREDSCAN_FUSION_ENABLED)),
                    int(eng.config.get(SHAREDSCAN_FUSION_MAX_NODES)),
                    fplan.token() if fplan is not None else None,
                    # wave mega-kernel routing: eligibility is re-derived on
@@ -463,7 +457,7 @@ class SharedScanCoalescer:
         prog_fn, unpacks, wave_info = eng._cached_program(sig, _build)
 
         per_lane_finals = self._dispatch(ds, union_names, seg_u, s_pad,
-                                         spw, n_waves, prog_fn, unpacks,
+                                         n_waves, prog_fn, unpacks,
                                          lanes, live[0],
                                          wave_info=wave_info,
                                          mesh_dec=dec)
@@ -544,22 +538,16 @@ class SharedScanCoalescer:
     @staticmethod
     def _shape_member(eng, ds, q) -> Optional[_LanePlan]:
         """Map the spec to the engine's (dims, aggs, post, having, limit,
-        gran) shape (mirrors _execute_inner) + prune segments. None =
+        gran) shape (``executor._agg_shape``) + prune segments. None =
         this member runs solo (e.g. empty pruning takes the engine's own
         empty/identity-row path, which never touches the device)."""
-        from spark_druid_olap_tpu.parallel.executor import _cache_repr
+        from spark_druid_olap_tpu.parallel.executor import (_agg_shape,
+                                                            _cache_repr)
         try:
-            if isinstance(q, S.GroupByQuerySpec):
-                dims, having, limit = list(q.dimensions), q.having, q.limit
-            elif isinstance(q, S.TimeseriesQuerySpec):
-                dims, having, limit = [], None, None
-            elif isinstance(q, S.TopNQuerySpec):
-                dims, having = [q.dimension], None
-                limit = S.LimitSpec(
-                    (S.OrderByColumn(q.metric, ascending=False),),
-                    q.threshold)
-            else:
+            shape = _agg_shape(q)
+            if shape is None:
                 return None
+            dims, having, limit = shape
             seg = ds.prune_segments(q.intervals, q.filter)
             if len(seg) == 0:
                 return None
@@ -786,11 +774,11 @@ class SharedScanCoalescer:
                 f"{type(e).__name__}: {e}") from e
         return prog, unpacks, info
 
-    def _dispatch(self, ds, union_names, seg_u, s_pad, spw, n_waves,
+    def _dispatch(self, ds, union_names, seg_u, s_pad, n_waves,
                   prog_fn, unpacks, lanes: List[_LanePlan], leader,
                   wave_info=None, mesh_dec=None):
-        """One shared bind + ONE program dispatch per wave (double-
-        buffered like _run_waves); per-lane unpack -> finals -> cross-
+        """One shared bind + ONE program dispatch per wave (the engine's
+        wave pipeline, ``_waves``); per-lane unpack -> finals -> cross-
         wave merge. All device ticks land on the leader's thread —
         including the wave-kernel launch tick (dispatch_counts[2]) when
         the wave program is live. With a sharded mesh decision binds
@@ -815,59 +803,37 @@ class SharedScanCoalescer:
                                        lp.n_keys, sketch[i])
                     for i, lp in enumerate(lanes)]
 
-        if n_waves == 1:
-            dev = eng._bind_arrays(ds, union_names, seg_u, s_pad, sharded)
-            eng._stage_check(leader.q, leader.t0)
-            eng._tick()
-            tok = MX.LEDGER.acquire_partials(payload)
-            try:
-                with PH.phase("dispatch"):
-                    bufs = eng._wait(eng._launch(prog_fn, dev))
-                    return eng._fetch(lane_finals, bufs)
-            finally:
-                MX.LEDGER.release_partials(tok)
+        # several waves: each ordered so the mesh's per-device blocks
+        # carry balanced row loads; one wave keeps the selection's order,
+        # which is the bind-cache entry the solo path shares
         seg_rows = None
-        if sharded:
+        if sharded and n_waves > 1:
             try:
                 seg_rows = {int(s): int(ds.segments[int(s)].num_rows)
                             for s in seg_u}
             except Exception:  # noqa: BLE001 — handles without segment objects
                 seg_rows = None
-        wave_segs = FU.plan_device_waves(seg_u, spw, n_dev, seg_rows)
-        sharding = M.segment_sharding(eng.mesh) if sharded else None
+        wave_segs = FU.plan_device_waves(seg_u, s_pad, n_dev, seg_rows)
         finals: List[Optional[dict]] = [None] * len(lanes)
         # mesh-parallel cold-tier faults: open a devices-aware pin scope
         # so eviction sees the whole n_dev-wide wave as one pinned unit
         tier = getattr(ds, "tier", None)
         ptok = tier.acquire_pins(devices=n_dev) \
-            if (sharded and tier is not None) else None
+            if (sharded and tier is not None and n_waves > 1) else None
         try:
             tok = MX.LEDGER.acquire_partials(payload)
             try:
-                # cold tier: wave 1's chunks load while wave 0 binds+computes
-                eng._tier_prefetch(ds, union_names, wave_segs, 1)
-                cur = eng._bind_wave(ds, union_names, wave_segs[0], spw,
-                                     sharding, False)
-                for i in range(len(wave_segs)):
-                    eng._stage_check(leader.q, leader.t0)
-                    eng._tick()
-                    # leader-thread attribution; the overlapped
-                    # prefetch/bind are spans of their own inside this one
-                    with PH.phase("dispatch"):
-                        bufs = eng._launch(prog_fn, cur)    # async dispatch
-                        eng._tier_prefetch(ds, union_names, wave_segs,
-                                           i + 2)
-                        nxt = eng._bind_wave(ds, union_names,
-                                             wave_segs[i + 1], spw,
-                                             sharding, False) \
-                            if i + 1 < len(wave_segs) else None
-                        wave = eng._fetch(lane_finals, eng._wait(bufs))
-                        for li, lp in enumerate(lanes):
-                            finals[li] = wave[li] if finals[li] is None \
-                                else X._merge_wave_finals(
-                                    finals[li], wave[li], lp.routes,
-                                    sketch[li])
-                    cur = nxt
+                # leader-thread attribution: every wave's launch, the
+                # overlapped prefetch and bind, wait and fetch are spans
+                # of the leader's record
+                for wave in eng._waves(
+                        leader.q, leader.t0, ds, union_names, wave_segs,
+                        s_pad, sharded, None, prog_fn, lane_finals):
+                    for li, lp in enumerate(lanes):
+                        finals[li] = wave[li] if finals[li] is None \
+                            else X._merge_wave_finals(
+                                finals[li], wave[li], lp.routes,
+                                sketch[li])
             finally:
                 MX.LEDGER.release_partials(tok)
         finally:
@@ -877,63 +843,14 @@ class SharedScanCoalescer:
 
     @staticmethod
     def _decode_lane(eng, ds, lp: _LanePlan, finals) -> QueryResult:
-        """Host demultiplex of one lane: the solo dense decode (group
-        selection, dictionary decode, identity row, epilogue) minus the
-        device-topk/having specializations the fused tier never plans.
-        Charged to the ``demux`` phase of whichever statement's thread
-        runs the decode."""
+        """Host demultiplex of one lane: the engine's dense decode, which
+        the fused tier never hands a device top-k. Charged to the
+        ``demux`` phase of whichever statement's thread runs it."""
         with PH.phase("demux"):
-            return SharedScanCoalescer._decode_lane_inner(
-                eng, ds, lp, finals)
-
-    @staticmethod
-    def _decode_lane_inner(eng, ds, lp: _LanePlan, finals) -> QueryResult:
-        from spark_druid_olap_tpu.parallel import executor as X
-        rows = finals["__rows__"]
-        sel = np.nonzero(rows > 0)[0]
-        gran_kind = lp.gran.kind if lp.gran else "all"
-        global_empty = (not lp.dim_plans and gran_kind == "all"
-                        and len(sel) == 0)
-        if global_empty:
-            sel = np.zeros(1, dtype=np.int64)
-        data: Dict[str, np.ndarray] = {}
-        columns: List[str] = []
-        if lp.dim_plans:
-            code_lists = G.unfuse_key(sel, [p.card for p in lp.dim_plans])
-            for p, codes in zip(lp.dim_plans, code_lists):
-                data[p.output_name] = p.decode(codes)
-                columns.append(p.output_name)
-        for p in lp.agg_plans:
-            name = p.spec.name
-            if p.kind in ("hll", "theta", "kll"):
-                regs = finals[name]
-                if eng.partial_sketches:
-                    # cluster historical: ship the raw [G, m] register
-                    # block exactly like the solo decode — the broker
-                    # merges registers across shards and finalizes once
-                    data[name] = np.asarray(regs)[sel]
-                    columns.append(name)
-                    continue
-                if p.kind == "kll":
-                    data[name] = KLL.estimate(
-                        regs, p.spec.fraction or 0.5)[sel]
-                    columns.append(name)
-                    continue
-                est = (HLL.estimate(regs) if p.kind == "hll"
-                       else TH.estimate(regs))[sel]
-                data[name] = np.round(est).astype(np.int64)
-                columns.append(name)
-                continue
-            data[name] = X._decode_agg_value(ds, p, lp.routes[name],
-                                             finals[name][sel])
-            columns.append(name)
-        if global_empty:
-            data.update(X._identity_row(
-                {p.spec.name: p.kind for p in lp.agg_plans
-                 if p.kind in ("sum", "min", "max")}))
-        data = eng._agg_epilogue(data, columns, lp.post, lp.having,
-                                 lp.limit)
-        return QueryResult(columns, data)
+            return eng._decode_dense(
+                ds, lp.dim_plans, lp.agg_plans, lp.routes, finals,
+                lp.gran.kind if lp.gran else "all", lp.post, lp.having,
+                lp.limit)[0]
 
     def note_handoff(self) -> None:
         """Called by the WLM poll loop when a queued waiter bypasses its

@@ -5,7 +5,8 @@ Four cross-checks, all pure AST over the shared index:
 - **K1 compile-sig-missing-config** — every ``self._cached_program(sig,
   build)`` site: config keys read anywhere in the build closure
   (transitively, depth ≤ 4 through resolvable calls) must appear as
-  ``config.get(...)`` terms of the signature expression. A key read
+  ``config.get(...)`` terms of the signature expression, or of what a
+  helper called there returns. A key read
   during program build but absent from the sig means an operator ``SET``
   keeps serving the previously compiled program — stale results that
   only show up after a mid-session config change.
@@ -115,25 +116,34 @@ class _Registry:
 
 # -- K1: compile signatures ---------------------------------------------------
 
-def _sig_keys(fn: ast.AST, sig_expr: ast.expr) -> Set[str]:
+def _sig_keys(fn: ast.AST, sig_expr: ast.expr, resolve) -> Set[str]:
     """Config-key constants appearing in the sig expression, following
     Name bindings within the function (``sigA = ("aggtable", base_sig,
-    ...)`` nests one sig in another)."""
+    ...)`` nests one sig in another) and the return expressions of the
+    helpers it calls (``self._sig_base(ds)`` spells the signatures'
+    common part once); ``resolve(call)`` gives a call's function nodes."""
     bindings: Dict[str, List[ast.expr]] = {}
     for n in ast.walk(fn):
         if isinstance(n, ast.Assign) and len(n.targets) == 1 \
                 and isinstance(n.targets[0], ast.Name):
             bindings.setdefault(n.targets[0].id, []).append(n.value)
     keys: Set[str] = set()
-    frontier, seen_names = [sig_expr], set()
+    frontier, seen = [sig_expr], set()
     for _ in range(4):
         nxt: List[ast.expr] = []
         for e in frontier:
             keys.update(k for k, _ in _config_reads(e))
             for n in ast.walk(e):
-                if isinstance(n, ast.Name) and n.id not in seen_names:
-                    seen_names.add(n.id)
+                if isinstance(n, ast.Name) and n.id not in seen:
+                    seen.add(n.id)
                     nxt.extend(bindings.get(n.id, ()))
+                elif isinstance(n, ast.Call):
+                    for helper in resolve(n):
+                        if id(helper) not in seen:
+                            seen.add(id(helper))
+                            nxt.extend(r.value for r in ast.walk(helper)
+                                       if isinstance(r, ast.Return)
+                                       and r.value is not None)
         frontier = nxt
         if not frontier:
             break
@@ -190,15 +200,27 @@ def _k1(project: Project, reg: _Registry) -> List[Finding]:
     idx = project.index()
     out: List[Finding] = []
     for fid, fn in sorted(idx.functions.items()):
+        parts = fid[1].split(".")
+        if any((fid[0], ".".join(parts[:k])) in idx.functions
+               for k in range(1, len(parts))):
+            continue    # a nested def: walked with the function around
+            # it, whose bindings the signature's names resolve in
         mi = idx.modules[fid[0]]
         mod = project.modules[fid[0]]
         ci = idx.func_class[fid]
+        local = idx.local_types(mi, ci, fn)
+
+        def resolve(call):
+            return [idx.functions[r] for r in idx.resolve_call(
+                mi, ci, call, local, fid[1], unique_fallback=True)
+                if r in idx.functions]
+
         for n in ast.walk(fn):
             if not (isinstance(n, ast.Call) and len(n.args) >= 2):
                 continue
             if not _suffix(call_chain(n.func), ("_cached_program",)):
                 continue
-            sig_keys = _sig_keys(fn, n.args[0])
+            sig_keys = _sig_keys(fn, n.args[0], resolve)
             roots = _build_roots(idx, mi, ci, fn, fid, n.args[1])
             for key, (rm, rq, rl) in sorted(
                     _closure_reads(idx, roots).items()):

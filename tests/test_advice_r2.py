@@ -1,4 +1,5 @@
-"""Round-2 advisor findings, regression-locked (ADVICE.md r2).
+"""Round-2 advisor findings, regression-locked (the advisor's file,
+ADVICE.md, was deleted by PR 30; the findings live on as these tests).
 
 1. medium — exact-contract GroupBy ordered-limit must not silently trust
    the f32-approximate device candidate selection when keys tie at the

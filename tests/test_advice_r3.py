@@ -1,4 +1,5 @@
-"""Round-3 advisor findings, regression-locked (ADVICE.md r3).
+"""Round-3 advisor findings, regression-locked (the advisor's file,
+ADVICE.md, was deleted by PR 30; the findings live on as these tests).
 
 1. (retired with the probe tooling it covered, PR 22)
 2. low — EXPLAIN's late-materialization line is labelled an estimate

@@ -101,21 +101,39 @@ def test_compaction_engaged_and_stats():
     assert st.get("compact_m", 0) > 0
 
 
-def test_overflow_retries_uncompacted(monkeypatch):
-    """A wildly-optimistic selectivity estimate must not produce wrong
-    results: the '__over__' channel forces the uncompacted retry."""
-    from spark_druid_olap_tpu.parallel import cost as C
-    monkeypatch.setattr(C, "_filter_selectivity",
-                        lambda f, ds: 1e-5)      # ~0 rows predicted
-    c = _ctx(True)
-    got = c.sql("select region, count(*) as n from sales "
+OVERFLOW_SQL = ("select region, count(*) as n from sales "
                 "where qty >= 0 group by region order by region")
+
+
+@pytest.mark.parametrize("waves", ["one_wave", "several_waves"])
+def test_overflow_retries_uncompacted_then_remembers(waves, monkeypatch):
+    """A wildly-optimistic selectivity estimate must not produce wrong
+    results: the '__over__' channel forces the uncompacted retry, and a
+    per-wave budget that lies (estimate ~0 survivors) aborts the
+    compacted wave run and re-runs the whole scan uncompacted. The
+    statement is remembered by its values: the same text again goes
+    straight to the uncompacted program, which is built already."""
+    from spark_druid_olap_tpu.parallel import cost as C
+    if waves == "one_wave":
+        monkeypatch.setattr(C, "_filter_selectivity",
+                            lambda f, ds: 1e-5)      # ~0 rows predicted
+        c, ref, sql = _ctx(True), _ctx(False), OVERFLOW_SQL
+    else:
+        monkeypatch.setattr(C, "_filter_selectivity", lambda f, ds: 1e-6)
+        c, ref, sql = _wave_ctx(True), _wave_ctx(False), WAVE_SQL
+    c.config.set("sdot.cache.enabled", False)
+    got = c.sql(sql).to_pandas()
     st = c.history.entries()[-1].stats
-    ref = _ctx(False).sql("select region, count(*) as n from sales "
-                          "where qty >= 0 group by region order by region")
-    pd.testing.assert_frame_equal(got.to_pandas(), ref.to_pandas(),
-                                  check_dtype=False)
+    want = ref.sql(sql).to_pandas()
+    pd.testing.assert_frame_equal(got, want, check_dtype=False, atol=1e-6)
+    assert (st.get("waves", 1) > 1) == (waves == "several_waves"), st
     assert st.get("compact_overflow", 0) > 0
+    assert st["program"]["built"] is True
+    again = c.sql(sql).to_pandas()
+    st = c.history.entries()[-1].stats
+    pd.testing.assert_frame_equal(again, want, check_dtype=False, atol=1e-6)
+    assert "compact_overflow" not in st and "compact_m" not in st, st
+    assert st["program"]["built"] is False
 
 
 def test_staged_expensive_membership_matches():
@@ -243,20 +261,6 @@ def test_wave_mode_compaction_matches():
         f"compaction not engaged in wave mode: {st}"
     b = _wave_ctx(False).sql(WAVE_SQL).to_pandas()
     pd.testing.assert_frame_equal(a, b, check_dtype=False, atol=1e-6)
-
-
-def test_wave_mode_compaction_overflow_retries(monkeypatch):
-    """A per-wave budget that lies (estimate ~0 survivors) must abort
-    the compacted wave run and re-run the whole scan uncompacted."""
-    from spark_druid_olap_tpu.parallel import cost as C
-    monkeypatch.setattr(C, "_filter_selectivity", lambda f, ds: 1e-6)
-    c = _wave_ctx(True)
-    got = c.sql(WAVE_SQL).to_pandas()
-    st = c.history.entries()[-1].stats
-    assert st.get("waves", 1) > 1
-    assert st.get("compact_overflow", 0) > 0
-    ref = _wave_ctx(False).sql(WAVE_SQL).to_pandas()
-    pd.testing.assert_frame_equal(got, ref, check_dtype=False, atol=1e-6)
 
 
 # -- the two forms of late materialization ------------------------------------

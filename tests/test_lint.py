@@ -160,14 +160,16 @@ def test_keys_pass_fires_on_keys_fixture():
     by_rule = {}
     for f in found:
         by_rule.setdefault(f.rule, []).append(f)
-    # three _cached_program call shapes resolve: the lambda build, the
-    # loop-nested local ``def build`` (engine.py:27 / engine.py:32), and
-    # the pallas wave build reading a tiling key (engine.py:40)
+    # four _cached_program call shapes resolve: the lambda build, the
+    # loop-nested local ``def build`` (engine.py:30 / engine.py:35), the
+    # pallas wave build reading a tiling key (engine.py:43), and the
+    # signature whose common part a helper returns (engine.py:54)
     k1 = by_rule["compile-sig-missing-config"]
     assert {f.symbol for f in k1} == {
         "Engine.run:HLL_LOG2M",
-        "Engine.run_wave:PALLAS_TILE_BYTES"}, found
-    assert sorted(f.line for f in k1) == [27, 32, 40], \
+        "Engine.run_wave:PALLAS_TILE_BYTES",
+        "Engine.run_helper:HLL_LOG2M"}, found
+    assert sorted(f.line for f in k1) == [30, 35, 43, 54], \
         [f.render() for f in k1]
     assert by_rule["key-missing-field"][0].symbol == \
         "normalize_spec:granularity"
@@ -178,6 +180,35 @@ def test_keys_pass_fires_on_keys_fixture():
         "config:WLM_POLL_MS"
     assert by_rule["fingerprint-unfiltered"][0].symbol == \
         "Config.fingerprint"
+
+
+def test_keys_pass_reads_a_signature_through_its_helper():
+    """The program signatures take their common part from one helper
+    (``QueryEngine._sig_base``). K1 reads what the helper returns: a key
+    it folds is covered (the fixture's build reads TZ_ID, no finding), a
+    key the build reads and the helper lacks is still found — and on the
+    live tree a key dropped from the helper fails, by name, the
+    signature of every tier whose build reads it (the hashed tier
+    refuses sketches, so it never reads the KLL lane count)."""
+    k1 = {f.symbol for f in _fixture("keys", ("keys",))
+          if f.rule == "compile-sig-missing-config"}
+    assert "Engine.run_helper:HLL_LOG2M" in k1
+    assert "Engine.run_helper:TZ_ID" not in k1
+    import ast
+    from spark_druid_olap_tpu.tools.sdlint import keys as K
+    proj = Project(PKG_ROOT)
+    mod = proj.by_suffix("parallel/executor.py")
+    helper = next(n for n in ast.walk(mod.tree)
+                  if isinstance(n, ast.FunctionDef)
+                  and n.name == "_sig_base")
+    ret = next(n for n in ast.walk(helper) if isinstance(n, ast.Return))
+    ret.value.elts = [e for e in ret.value.elts
+                      if "QUANTILE_LANES" not in ast.dump(e)]
+    dropped = {f.symbol for f in K.run(proj)
+               if f.rule == "compile-sig-missing-config"}
+    assert dropped == {"QueryEngine._run_agg:QUANTILE_LANES",
+                       "SharedScanCoalescer._run_fused:QUANTILE_LANES"}, \
+        dropped
 
 
 def test_leaks_pass_fires_on_leaks_fixture():

@@ -477,6 +477,76 @@ def test_span_rule_hashed_statement(ctx):
             "dispatch.launch", "dispatch.wait", "dispatch.fetch"], kids
 
 
+# One wave pipeline (QueryEngine._waves) runs every tier, so every tier
+# leaves the SAME tree: per program launch one ``dispatch`` with exactly
+# one launch, wait and fetch; wave i+1 binds inside wave i's ``dispatch``
+# (the transfer overlaps the compute); one wave binds before its launch.
+
+LWF = ["dispatch.launch", "dispatch.wait", "dispatch.fetch"]
+LBWF = ["dispatch.launch", "bind", "dispatch.wait", "dispatch.fetch"]
+WAVE_TIERS = {
+    # tier: (settings, statements fired together, a second program a wave)
+    "dense": ({}, [Q], False),
+    "hashed_direct": ({"sdot.engine.groupby.dense.max.keys": 8},
+                      ["SELECT region, qty, SUM(price) AS rev FROM sales "
+                       "GROUP BY region, qty"], False),
+    "hashed_table": ({"sdot.engine.groupby.dense.max.keys": 8,
+                      "sdot.engine.groupby.hash.compact.min.slots": 1},
+                     ["SELECT region, qty, SUM(price) AS rev FROM sales "
+                      "GROUP BY region, qty"], True),
+    "fused_group": ({"sdot.sharedscan.enabled": True,
+                     "sdot.wlm.batch.window.ms": 2000},
+                    ["SELECT region, SUM(qty) AS q, COUNT(*) AS n "
+                     "FROM sales GROUP BY region",
+                     "SELECT SUM(price) AS p FROM sales WHERE qty < 24"],
+                    False),
+}
+
+
+@pytest.mark.parametrize("waves", [1, 3])
+@pytest.mark.parametrize("tier", sorted(WAVE_TIERS))
+def test_every_tier_leaves_the_same_dispatch_tree(tier, waves):
+    settings, sqls, second = WAVE_TIERS[tier]
+    c = sdot.Context({"sdot.cache.enabled": False, **settings})
+    if waves > 1:       # a budget under one segment: a wave a segment
+        c.config.set("sdot.engine.wave.max.bytes", 1)
+    c.ingest_dataframe("sales", _sales_df(3 * 1024), time_column="ts",
+                       target_rows=1024)
+    try:
+        for _ in range(2):                      # cold, then warm
+            c.history.clear()
+            barrier = threading.Barrier(len(sqls))
+
+            def fire(sql):
+                barrier.wait()
+                c.sql(sql)
+            ts = [threading.Thread(target=fire, args=(q,)) for q in sqls]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join()
+        recs = [r.stats for r in c.history.entries()]
+    finally:
+        c.close()
+    assert len(recs) == len(sqls)
+    for st in recs:
+        _check_record(st)
+    st = max(recs, key=lambda st: st["n_dispatch"])   # the group's leader
+    assert st["waves"] == waves, st
+    if tier == "fused_group":
+        assert st["sharedscan"]["queries"] == len(sqls), st["sharedscan"]
+    else:
+        assert bool(st.get("hashed")) == tier.startswith("hashed"), st
+    want = []
+    for i in range(waves):
+        want.append(LBWF if i + 1 < waves else LWF)
+        if second:
+            want.append(LWF)
+    kids = _children(st["spans"], "dispatch")
+    assert kids == want, kids
+    assert len(kids) == st["n_dispatch"]
+
+
 @contextlib.contextmanager
 def _interpret_env():
     """The wave kernel through ``pl.pallas_call(interpret=True)``, for

@@ -597,3 +597,110 @@ def test_fusion_smoke_canned_storm(store):
         assert fc is not None
         assert fc["column_streams_saved"] > 0, fc
         assert fc["shared_predicates"] > 0, fc
+
+
+# -- one dense decode (QueryEngine._decode_dense), two spans ------------------
+
+_PARTNER = S.GroupByQuerySpec("sales", (S.DimensionSpec("flag", "flag"),),
+                              AGGS)
+_HLL = S.AggregationSpec("cardinality", "products", field="product")
+DECODE_CASES = {
+    # dictionary decode + an HLL estimate beside exact sums
+    "dims_hll": (S.GroupByQuerySpec(
+        "sales", (S.DimensionSpec("region", "region"),),
+        AGGS + (_HLL,)), False),
+    # a global aggregate over zero matching rows: the one identity row
+    # (both values live in every segment, so nothing is pruned)
+    "global_empty": (S.TimeseriesQuerySpec(
+        "sales", AGGS + (S.AggregationSpec("doublemin", "lo",
+                                           field="price"),),
+        filter=S.LogicalFilter("and", (S.SelectorFilter("flag", "A"),
+                                       S.SelectorFilter("flag", "N")))),
+        False),
+    # a historical's raw register blocks instead of estimates
+    "partial_sketches": (S.GroupByQuerySpec(
+        "sales", (S.DimensionSpec("region", "region"),), (_HLL, AGGS[2])),
+        True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_lane_decode_equals_solo_decode(store, case):
+    """A lane of a fused group and a solo statement decode through the
+    same function: columns, order, dtypes and values agree — estimates,
+    the identity row and raw registers included."""
+    spec, partial = DECODE_CASES[case]
+    eng, ref = _engine(store), _ref_engine(store)
+    eng.partial_sketches = ref.partial_sketches = partial
+    want = ref.execute(spec)
+    before = eng.sharedscan.stats()["queries_coalesced"]
+    n = 2
+    got, errs = [None] * n, []
+    bar = threading.Barrier(n)
+
+    def worker(i, q):
+        bar.wait()
+        try:
+            got[i] = eng.execute(q)
+        except Exception as e:          # noqa: BLE001 - asserted below
+            errs.append(e)
+
+    ts = [threading.Thread(target=worker, args=(i, q))
+          for i, q in enumerate((spec, _PARTNER))]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join()
+    assert not errs, errs
+    assert eng.sharedscan.stats()["queries_coalesced"] - before == n
+    assert got[0].columns == want.columns
+    for name in want.columns:
+        a, b = np.asarray(got[0].data[name]), np.asarray(want.data[name])
+        assert a.dtype == b.dtype and a.shape == b.shape, (name, a, b)
+        if a.dtype == object:
+            assert list(a) == list(b), name
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+    if case == "global_empty":
+        assert len(want.data["n"]) == 1 and want.data["n"][0] == 0
+        assert np.isnan(want.data["lo"][0])
+    if partial:
+        assert np.asarray(want.data["products"]).ndim == 2   # [G, m]
+
+
+def test_lane_records_demux_solo_records_decode(sales_df):
+    """The span says who decoded: the leader of a fused group decodes
+    every lane under ``demux`` (its record has no ``decode``, a
+    follower's neither), a solo statement under ``decode``."""
+    sqls = ["SELECT region, SUM(qty) AS q FROM sales GROUP BY region",
+            "SELECT flag, COUNT(*) AS n FROM sales GROUP BY flag"]
+    c = sdot.Context({"sdot.cache.enabled": False,
+                      "sdot.sharedscan.enabled": True,
+                      "sdot.wlm.batch.window.ms": WINDOW_MS})
+    c.ingest_dataframe("sales", sales_df, time_column="ts")
+    try:
+        bar = threading.Barrier(len(sqls))
+
+        def fire(sql):
+            bar.wait()
+            c.sql(sql)
+        ts = [threading.Thread(target=fire, args=(q,)) for q in sqls]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join()
+        fused = [r.stats for r in c.history.entries()]
+        c.config.set("sdot.sharedscan.enabled", False)
+        c.sql(sqls[0])
+        solo = c.history.entries()[-1].stats
+    finally:
+        c.close()
+    assert len(fused) == 2 and all("sharedscan" in st for st in fused)
+    for st in fused:
+        names = [sp[0] for sp in st["spans"]]
+        assert "decode" not in names, names
+        lead = st["sharedscan"]["role"] == "leader"
+        assert names.count("demux") == (len(sqls) if lead else 0), names
+    names = [sp[0] for sp in solo["spans"]]
+    assert "sharedscan" not in solo
+    assert names.count("decode") == 1 and "demux" not in names, names
