@@ -48,8 +48,10 @@ of an accepted group is an error, not a row here; see docs/KERNELS.md):
   pattern/extraction dims, tz-shifted granularities, ...) -> jaxpr,
   caught by a chip-independent 8x128 trace probe against a Mosaic-safe
   primitive whitelist, NOT by a device compile error.
-- HLL registers (scatter-max over 2^log2m buckets — infeasible in a
-  VMEM-tiled scratch block at the default m=2048) and theta sketches
+- HLL registers (per-slot maxima over n_keys * 2^log2m slots —
+  infeasible in a VMEM-tiled scratch block at the default m=2048; a
+  packed-key sort or a scatter, ``ops.hll.register_form``) and theta
+  sketches
   over the in-kernel row cap: computed by the existing XLA register ops
   in the SAME jit after the kernel — still one kernel launch per wave,
   at the cost of one extra XLA stream of the sketch lanes' columns.
@@ -285,7 +287,7 @@ def _prep_dtype(dt) -> object:
 
 def build_wave_fn(ds, lanes, min_day: int, max_day: int, fplan, *,
                   union_names, tz: str, log2m: int, tile_bytes: int,
-                  kll_lanes: int = KLL.K_LANES):
+                  kll_lanes: int = KLL.K_LANES, hll_costs=None):
     """Lower a fused group to the wave mega-kernel.
 
     Returns ``(wave_fn, info)`` where ``wave_fn(arrays)`` maps the wave's
@@ -502,7 +504,7 @@ def build_wave_fn(ds, lanes, min_day: int, max_day: int, fplan, *,
                     m = base if am is None else (base & am)
                     if p.kind == "hll":
                         routed[nm] = HLL.hll_registers(
-                            key, m, vals, lp.n_keys, log2m)
+                            key, m, vals, lp.n_keys, log2m, hll_costs)
                     elif p.kind == "kll":
                         tcol = ctx.col(ds.time.name) \
                             if ds.time is not None else None

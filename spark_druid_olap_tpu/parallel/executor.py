@@ -1257,8 +1257,9 @@ class QueryEngine:
         top_idx = None
         # the statement's SHAPE, not its literal values; the selected
         # segments' day range only where the program is built from it
+        hll_costs = self._hll_costs(agg_plans)
         base_sig = (self._sig_base(ds), lits.shape, s_pad, days, sharded,
-                    n_dev, tuple(names))
+                    n_dev, tuple(names), hll_costs)
         if having_dev:
             # two dispatches: finals stay device-resident, only the mask
             # count then the passing groups travel
@@ -1266,7 +1267,8 @@ class QueryEngine:
             progA = self._cached_program(
                 sigA, lambda: self._build_agg_table_program(
                     ds, all_dim_plans, agg_plans, filter_spec, intervals,
-                    days, n_keys, sharded, routes, having_dev, lits))
+                    days, n_keys, sharded, routes, having_dev, lits,
+                    hll_costs))
             # one wave (_plan_device_having): the table stays on the
             # device, only its count travels
             (table, stats), = self._waves(q, t0, ds, names, [seg_idx],
@@ -1303,15 +1305,20 @@ class QueryEngine:
                 n_dev=n_dev, allow_sharded=True, n_keys=n_keys)
 
             def run(late):
-                prog_fn, unpack, compact = self._cached_program(
+                prog_fn, unpack, compact, notes = self._cached_program(
                     ("agg", base_sig, topk, late),
                     lambda: self._build_agg_program(
                         ds, all_dim_plans, agg_plans, filter_spec,
                         intervals, days, n_keys, sharded,
-                        routes, topk=topk, late=late, lits=lits))
+                        routes, topk=topk, late=late, hll_costs=hll_costs,
+                        lits=lits))
                 finals, n_over, out = self._run_waves(
                     q, ds, names, seg_idx, s_pad, sharded, prog_fn, unpack,
                     routes, n_out, sketch_plans, t0, lits)
+                if notes:
+                    self.last_stats.update({
+                        "hll_form": notes["hll_form"],
+                        "hll_slots": notes["hll_slots"]})
                 return (finals, out), n_over, compact
 
             late = self._late(compact_m)
@@ -2495,12 +2502,13 @@ class QueryEngine:
         if lits.count:
             arrays[L.LITERALS_KEY] = lits.pack()
         fn = self._make_core(ds, dim_plans, agg_plans, q.filter, q.intervals,
-                             days, n_keys, routes, lits=lits)
+                             days, n_keys, routes,
+                             hll_costs=self._hll_costs(agg_plans), lits=lits)
         return fn, arrays
 
     def _make_core(self, ds, dim_plans, agg_plans, filter_spec,
                    intervals, days, n_keys, routes,
-                   compact=None, *, lits):
+                   compact=None, hll_costs=None, notes=None, *, lits):
         """``days``: the selected segments' (min_day, max_day), or None
         where the signature does not carry them — then nothing traced
         may read them. ``lits``: the building statement's literal plan;
@@ -2508,7 +2516,11 @@ class QueryEngine:
         ``L.LITERALS_KEY``. ``compact``: the program's ``Compaction`` —
         late materialization between the cheap filter and everything
         after it; an overflow of its budget surfaces as '__over__' and
-        the host retries without."""
+        the host retries without. ``hll_costs``: the unit costs an HLL
+        aggregation's registers choose their form under (``_hll_costs``).
+        ``notes``: a dict the trace fills with what the statement record
+        says of the program's sketch epilogue (``hll_form``,
+        ``hll_slots``)."""
         min_day, max_day = days or (None, None)
         matmul_max = self.config.get(GROUPBY_MATMUL_MAX_KEYS)
         log2m = self.config.get(HLL_LOG2M)
@@ -2568,7 +2580,12 @@ class QueryEngine:
                 am = p.build_mask(ctx, cse=cse)
                 m = base if am is None else (base & am)
                 out[p.spec.name] = HLL.hll_registers(
-                    key, m, vals, n_keys, log2m)
+                    key, m, vals, n_keys, log2m, hll_costs)
+            if hll_plans and notes is not None:
+                notes.update(
+                    hll_form=HLL.register_form(key.size, n_keys, log2m,
+                                               hll_costs),
+                    hll_slots=(n_keys + 1) << log2m)
             for p in theta_plans:
                 vals = p.build_values(ctx)
                 am = p.build_mask(ctx, cse=cse)
@@ -2589,10 +2606,13 @@ class QueryEngine:
 
     def _build_agg_program(self, ds, dim_plans, agg_plans, filter_spec,
                            intervals, days, n_keys, sharded,
-                           routes, topk=None, late=None, *, lits):
-        """Returns (jit_fn, unpack, compact): ``compact`` is the program's
-        ``Compaction`` under a ``late`` budget (``_late``; the statement
-        record reads the form it ran in from there), else None.
+                           routes, topk=None, late=None, hll_costs=None,
+                           *, lits):
+        """Returns (jit_fn, unpack, compact, notes): ``compact`` is the
+        program's ``Compaction`` under a ``late`` budget (``_late``; the
+        statement record reads the form it ran in from there), else None;
+        ``notes`` what the first trace says of its sketch epilogue
+        (``_make_core``).
 
         The program packs outputs into TWO flat device buffers so the host
         pays at most two device->host transfers (each buffer is its own
@@ -2614,9 +2634,11 @@ class QueryEngine:
         topN threshold).
         """
         compact = Compaction(*late) if late else None
+        notes = {}
         core = self._make_core(ds, dim_plans, agg_plans, filter_spec,
                                intervals, days, n_keys, routes,
-                               compact=compact, lits=lits)
+                               compact=compact, hll_costs=hll_costs,
+                               notes=notes, lits=lits)
         hll_plans = [p for p in agg_plans if p.kind == "hll"]
         theta_plans = [p for p in agg_plans if p.kind == "theta"]
         kll_plans = [p for p in agg_plans if p.kind == "kll"]
@@ -2699,7 +2721,7 @@ class QueryEngine:
                                  check_vma=False)
             fn = named_jit("sdot_agg_dense", smfn)
 
-        return fn, unpack, compact
+        return fn, unpack, compact, notes
 
     def _sig_base(self, ds):
         """What every program signature starts from: the store the
@@ -2714,6 +2736,19 @@ class QueryEngine:
                 bool(self.config.get(ENCODE_ENABLED)),
                 jax.default_backend(), bool(jax.config.jax_enable_x64),
                 bool(self.config.get(SHAREDSCAN_FUSION_ENABLED)))
+
+    def _hll_costs(self, agg_plans):
+        """What a program with an HLL aggregation is built under and
+        cached by: the backend's unit costs ``ops.hll.hll_registers``
+        chooses its form with, beside the traced shapes
+        (``ops.hll.register_form``). None without one."""
+        if not any(p.kind == "hll" for p in agg_plans):
+            return None
+        from spark_druid_olap_tpu.utils import config as CF
+        return HLL.RegisterCosts(
+            C.unit_cost(self.config, CF.COST_SORT_ROW),
+            C.unit_cost(self.config, CF.COST_GATHER_PROBE),
+            C.unit_cost(self.config, CF.COST_SCATTER_UPDATE))
 
     def _cached_program(self, sig, build):
         """Program-cache fetch with PER-SIGNATURE compile ownership: warm
@@ -2821,13 +2856,14 @@ class QueryEngine:
     def _build_agg_table_program(self, ds, dim_plans, agg_plans,
                                  filter_spec, intervals, days,
                                  n_keys, sharded, routes, having_dev,
-                                 lits):
+                                 lits, hll_costs=None):
         """HAVING-compaction dispatch 1 of 2: scan + merge, leave the
         finals DEVICE-RESIDENT, compute the exact having mask and transfer
         only its count. ≈ Druid evaluating HavingSpec on the data node
         instead of shipping every group to the broker."""
         core = self._make_core(ds, dim_plans, agg_plans, filter_spec,
-                               intervals, days, n_keys, routes, lits=lits)
+                               intervals, days, n_keys, routes,
+                               hll_costs=hll_costs, lits=lits)
         hll_plans = [p for p in agg_plans if p.kind == "hll"]
         theta_plans = [p for p in agg_plans if p.kind == "theta"]
         kll_plans = [p for p in agg_plans if p.kind == "kll"]

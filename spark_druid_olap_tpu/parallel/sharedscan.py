@@ -414,6 +414,8 @@ class SharedScanCoalescer:
                 and PW.wave_eligible(
                     lanes, int(eng.config.get(PALLAS_WAVE_MAX_LANES)))
 
+            hll_costs = eng._hll_costs(
+                [p for lp in lanes for p in lp.agg_plans])
             sig = ("aggmulti", eng._sig_base(ds), s_pad, min_day, max_day,
                    tuple(union_names), sigs,
                    # the fusion plan shapes the traced program: the token is
@@ -431,7 +433,9 @@ class SharedScanCoalescer:
                    # mesh decision re-derived on EVERY fused execution (a
                    # sdot.mesh.* flip, device-count change, or cost-model
                    # swing re-keys the program — sdlint K1)
-                   dec.sig_fields())
+                   dec.sig_fields(),
+                   # what the lanes' HLL registers choose their form under
+                   hll_costs)
 
         def _build():
             """Wave first (one pallas launch per wave), jaxpr-fused on
@@ -443,7 +447,7 @@ class SharedScanCoalescer:
                     return self._build_wave_program(
                         ds, lanes, min_day, max_day, fplan,
                         union_names=union_names, s_pad=s_pad,
-                        mesh_dec=dec)
+                        mesh_dec=dec, hll_costs=hll_costs)
                 except PW.WaveFallback:
                     # a planned decline only: trace, lowering and
                     # compiler errors propagate (docs/KERNELS.md)
@@ -451,7 +455,8 @@ class SharedScanCoalescer:
                         self.pallas_fallbacks += 1
             fn, unp = self._build_fused_program(ds, lanes, min_day,
                                                 max_day, fplan,
-                                                mesh_dec=dec)
+                                                mesh_dec=dec,
+                                                hll_costs=hll_costs)
             return fn, unp, None
 
         prog_fn, unpacks, wave_info = eng._cached_program(sig, _build)
@@ -615,7 +620,7 @@ class SharedScanCoalescer:
 
     def _build_fused_program(self, ds, lanes: List[_LanePlan],
                              min_day: int, max_day: int, fplan=None,
-                             mesh_dec=None):
+                             mesh_dec=None, hll_costs=None):
         """(jit_fn, [per-lane unpack]). One ScanContext over the union
         bind; each lane is the engine's dense core (mask -> fused keys ->
         dense_groupby -> sketch registers) packed through its own
@@ -688,7 +693,7 @@ class SharedScanCoalescer:
                     m = base if am is None else (base & am)
                     if p.kind == "hll":
                         out[p.spec.name] = HLL.hll_registers(
-                            key, m, vals, lp.n_keys, log2m)
+                            key, m, vals, lp.n_keys, log2m, hll_costs)
                     elif p.kind == "kll":
                         tcol = ctx.col(ds.time.name) \
                             if ds.time is not None else None
@@ -711,7 +716,8 @@ class SharedScanCoalescer:
 
     def _wave_program_fn(self, ds, lanes: List[_LanePlan],
                          min_day: int, max_day: int, fplan=None, *,
-                         union_names, s_pad, mesh_dec=None):
+                         union_names, s_pad, mesh_dec=None,
+                         hll_costs=None):
         """(jit_fn, [per-lane unpack], wave_info, arg shapes) — the
         traced but not yet compiled wave program. The group's whole wave
         lowers through ONE hand-scheduled Pallas mega-kernel
@@ -726,6 +732,7 @@ class SharedScanCoalescer:
         wave_fn, info = PW.build_wave_fn(
             ds, lanes, min_day, max_day, fplan,
             union_names=union_names, tz=tz, log2m=log2m,
+            hll_costs=hll_costs,
             tile_bytes=int(eng.config.get(PALLAS_WAVE_TILE_BYTES)),
             kll_lanes=eng.config.get(QUANTILE_LANES))
         packers = [eng._agg_meta_packers(lp.agg_plans, lp.routes,
@@ -755,7 +762,8 @@ class SharedScanCoalescer:
 
     def _build_wave_program(self, ds, lanes: List[_LanePlan],
                             min_day: int, max_day: int, fplan=None, *,
-                            union_names, s_pad, mesh_dec=None):
+                            union_names, s_pad, mesh_dec=None,
+                            hll_costs=None):
         """(compiled program, [per-lane unpack], wave_info). Compiles at
         BUILD time and keeps the executable as the program (nothing
         compiles twice), so what the backend's compiler refuses — a
@@ -764,7 +772,7 @@ class SharedScanCoalescer:
         the lane set, never at the group's first dispatch."""
         fn, unpacks, info, shapes = self._wave_program_fn(
             ds, lanes, min_day, max_day, fplan, union_names=union_names,
-            s_pad=s_pad, mesh_dec=mesh_dec)
+            s_pad=s_pad, mesh_dec=mesh_dec, hll_costs=hll_costs)
         try:
             prog = fn.lower(shapes).compile()
         except Exception as e:  # noqa: BLE001 — re-raised with the lane set

@@ -150,7 +150,9 @@ COST_SORT_ROW = _entry(
     "/ 6.0M; a STABLE 2-operand sort of the same rows is 5.8 / 10.3ms); "
     "tools/calibrate.py refits it on the live backend — the CPU "
     "fallback's x64 sort is ~400x this, which is what flips the "
-    "compaction gate there.", float)
+    "compaction gate there. Also what ops.hll.register_form prices the "
+    "HLL registers' packed-key sort with (v5e: 5.4-7.5ms / 8.0M rows).",
+    float)
 COST_SORT_PAYLOAD_ROW = _entry(
     "sdot.querycostmodel.sort.payload.seconds.per.row", 6.7e-10,
     "Measured seconds per row per EXTRA sort payload operand "
@@ -160,9 +162,9 @@ COST_SCATTER_UPDATE = _entry(
     "sdot.querycostmodel.scatter.seconds.per.update", 6.7e-9,
     "Measured seconds per update of an XLA scatter/segment-sum into a "
     "group table that FITS in cache (v5e: ~40ms / 6M updates, index "
-    "order irrelevant; fit at a 128KB table by tools/calibrate.py). The "
-    "past-cache thrash regime is the separate scatter.big constant.",
-    float)
+    "order irrelevant; fit at a 128KB table by tools/calibrate.py; the "
+    "HLL registers' segment_max 54.6ms / 8.0M). The past-cache thrash "
+    "regime is the separate scatter.big constant.", float)
 COST_SCATTER_UPDATE_BIG = _entry(
     "sdot.querycostmodel.scatter.big.seconds.per.update", 6.7e-9,
     "Measured seconds per scatter update when the group table exceeds "
@@ -181,8 +183,9 @@ COST_GATHER_PROBE = _entry(
     "sdot.querycostmodel.gather.seconds.per.probe", 9e-9,
     "Measured seconds per probe of a flattened 1D device gather "
     "(v5e: 7.1ns at random positions, 8.2-9.2ns at late "
-    "materialization's sorted ones, up to 23ns for 2^20 of them). Fit by "
-    "tools/calibrate.py.", float)
+    "materialization's sorted ones, up to 23ns for 2^20 of them; 1-8ns "
+    "in the HLL registers' run-end search). Fit by tools/calibrate.py.",
+    float)
 COST_FUSED_ROW = _entry(
     "sdot.querycostmodel.fused.seconds.per.row", 3.3e-10,
     "Measured seconds per row of the fused Pallas small-K group-by "

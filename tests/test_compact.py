@@ -12,8 +12,10 @@ The survivors' arrays reach the prefix in one of two forms, chosen from
 static shapes and the backend's unit costs (ops.scan.carries_by_sort):
 as payloads of the compaction sort, or by a gather each. The CPU's
 constants always gather, so the cases below pin each form through
-`sdot.querycostmodel.gather.seconds.per.probe` — the one constant only
-this stage reads — and hold the two forms to bit-equal answers.
+`sdot.querycostmodel.gather.seconds.per.probe` — a constant only this
+stage and the HLL registers' form rule read, and the latter scatters on
+the CPU whatever a probe costs — and hold the two forms to bit-equal
+answers.
 """
 
 import numpy as np
