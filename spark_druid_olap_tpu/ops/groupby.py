@@ -84,7 +84,11 @@ class AggInput:
     ``values`` is the [S, R] input (None for count); ``mask`` an optional
     per-agg filter mask (filtered aggregations, reference
     FilteredAggregationSpec). ``is_int``/``maxabs`` are static metadata
-    driving the numeric route (column min/max from segment metadata)."""
+    driving the numeric route (column min/max from segment metadata).
+    ``same_in_group`` says every row of a group holds the same value (an
+    FD-demoted grouping column, the planner's ``anyvalue``): a core that
+    has a group's rows side by side may read one of them instead of
+    reducing them all (ops/sorted_groupby.py does)."""
 
     name: str
     kind: str
@@ -92,6 +96,7 @@ class AggInput:
     mask: Optional[object] = None
     is_int: bool = False
     maxabs: Optional[float] = None
+    same_in_group: bool = False
 
 
 @dataclasses.dataclass(frozen=True)

@@ -19,7 +19,14 @@ programs stack ~6 of those. This module replaces the scatters entirely:
   ~log2(run) ulps of the GROUP total. A plain prefix-sum difference would
   carry the PREFIX magnitude's cancellation error into small groups,
   which is why the naive version is wrong and this one is not.
-- **min/max** use a segmented scan with the same reset flag.
+- **min/max** use a segmented scan with the same reset flag — unless the
+  planner says every row of a group holds the same value
+  (``AggInput.same_in_group``: an FD-demoted grouping column, ``anyvalue``):
+  then the run's last row holds the final as it is and nothing is scanned.
+  A segmented scan is ``log2(n)`` unrolled levels of slices, pads and
+  concatenations; TPC-H Q18's outer statement carried four of them over
+  8.0 M rows, and the chip's compiler did not finish that program inside
+  a benchmark run's 20 minutes (``PERF.md`` §6, PR 33).
 - **Per-group finals** sit at each run's LAST row, and a row is the last
   of its run iff the next row starts one — known without any search. A
   second ``lax.sort`` keyed on the run-last rows' group id compacts them
@@ -285,9 +292,13 @@ def sorted_hash_groupby(khi, klo, valid, T: int, inputs: List[AggInput],
         r = routes[a.name]
         at = len(cols)
         if a.kind in ("min", "max"):
-            pick = jnp.minimum if a.kind == "min" else jnp.maximum
-            cols += _seg_scan(new, (v,),
-                              lambda x, y, pick=pick: (pick(x[0], y[0]),))
+            if a.same_in_group:
+                # the run's rows agree: its last row holds the final
+                cols.append(v)
+            else:
+                pick = jnp.minimum if a.kind == "min" else jnp.maximum
+                cols += _seg_scan(
+                    new, (v,), lambda x, y, pick=pick: (pick(x[0], y[0]),))
             how = "final"
         elif r.tag == "i32":
             # wrap-exact mod 2^32: per-group totals fit i32 by the route

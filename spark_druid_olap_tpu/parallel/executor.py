@@ -1795,9 +1795,19 @@ class QueryEngine:
                     metas, T, self.config.get(GROUPBY_MATMUL_MAX_KEYS),
                     n_rows=n_rows_dev)
             late_key = self._late(lm)
+            # HAVING on the device-resident table (the dense tier's
+            # transfer filter, _plan_device_having): one chip, one wave
+            # — a partial table's totals are not the group's — and an
+            # aggregate the table holds as ONE exact integer column
+            having_dev = self._plan_device_having(
+                having, routes, agg_plans, T, None, n_waves) \
+                if compact and n_dev == 1 else None
+            if having_dev and routes[having_dev[0]].tag not in ("i32",
+                                                                "i64"):
+                having_dev = None
             sig = ("hashagg", self._sig_base(ds), lits.shape, s_pad, days,
                    sharded, n_dev, T, tuple(names), topk, compact,
-                   late_key, sorted_run)
+                   late_key, sorted_run, having_dev)
 
             def build(late_key=late_key):
                 # the program and, under a budget, its Compaction: the
@@ -1808,7 +1818,7 @@ class QueryEngine:
                         ds, dim_plans, parts, agg_plans, filter_spec,
                         intervals, days, T, sharded, routes,
                         compact=late, sorted_run=sorted_run,
-                        lits=lits), late
+                        having_dev=having_dev, lits=lits), late
                 return self._build_hash_program(
                     ds, dim_plans, parts, agg_plans, filter_spec,
                     intervals, days, T, sharded, routes,
@@ -1835,7 +1845,8 @@ class QueryEngine:
                     continue
                 # the table's second program, a dispatch of its own: the
                 # exchanged top-k candidates or the occupied slots
-                table, stats = landed[0], landed[1].reshape(-1, 2)
+                table, stats = landed[0], landed[1].reshape(
+                    -1, 3 if having_dev else 2)
                 unresolved += int(stats[:, 0].sum())
                 if unresolved:
                     break
@@ -1855,12 +1866,15 @@ class QueryEngine:
                             agg_plans, routes, metric, ascending,
                             k_cand, k_sel, T))
                 else:
-                    occ_max = max(1, int(stats[:, 1].max()))
+                    # the slots that travel: the occupied ones, or those
+                    # of them that pass the HAVING
+                    occ_max = max(1, int(stats[:, -1].max()))
                     kg = min(T, 1 << max(6, (occ_max - 1).bit_length()))
                     gfn, unpackB = self._cached_program(
                         (sig, "gather", kg),
                         lambda kg=kg: self._build_hash_gather_program(
-                            agg_plans, routes, kg, T, sharded))
+                            agg_plans, routes, kg, T, sharded,
+                            having_dev))
                 kg_used = max(kg_used, kg)
                 raw = self._run_program(gfn, table, unpackB)
                 partials.extend(_hash_chip_partials(raw, routes, kg, n_dev))
@@ -1933,7 +1947,8 @@ class QueryEngine:
             else int(s_pad // n_dev) * int(ds.padded_rows),
             "topk_device": int(topk[1]) if topk
             else (int(exch[1]) if exch else 0),
-            "topk_exchange": bool(exch)})
+            "topk_exchange": bool(exch),
+            "having_device": int(kg_used) if having_dev else 0})
         return QueryResult(columns, data)
 
     def _plan_device_topk_hashed(self, limit, having, agg_plans, n_dev,
@@ -2039,10 +2054,14 @@ class QueryEngine:
                 else jnp.zeros_like(khi)
             inputs = []
             for p in agg_plans:
-                inputs.append(G.AggInput(p.spec.name, p.kind,
-                                         p.build_values(ctx),
-                                         p.build_mask(ctx, cse=cse),
-                                         is_int=p.is_int, maxabs=p.maxabs))
+                mask = p.build_mask(ctx, cse=cse)
+                # a filtered aggregation's masked rows hold the sentinel,
+                # not the group's value: its rows do not agree
+                inputs.append(G.AggInput(
+                    p.spec.name, p.kind, p.build_values(ctx), mask,
+                    is_int=p.is_int, maxabs=p.maxabs,
+                    same_in_group=p.spec.kind == "anyvalue"
+                    and mask is None))
             if sorted_run:
                 # sorted-run tier: the slot sort rides the agg values as
                 # payloads; prefix scans + run-boundary reads replace
@@ -2212,10 +2231,12 @@ class QueryEngine:
     def _build_hash_table_program(self, ds, dim_plans, parts, agg_plans,
                                   filter_spec, intervals, days,
                                   T, sharded, routes, compact=None,
-                                  sorted_run=False, *, lits):
+                                  sorted_run=False, having_dev=None, *,
+                                  lits):
         """Compaction dispatch 1 of 2: build the table, leave it DEVICE-
         RESIDENT, transfer only '__stats__' = [unresolved, occupied] per
-        chip. The host sizes the gather dispatch from the occupancy."""
+        chip — with ``having_dev`` also how many occupied slots pass the
+        HAVING. The host sizes the gather dispatch from the last."""
         core = self._hash_core(ds, dim_plans, parts, agg_plans, filter_spec,
                                intervals, days, T, routes,
                                compact=compact, sorted_run=sorted_run,
@@ -2224,9 +2245,12 @@ class QueryEngine:
         def run(arrays):
             out = core(arrays)
             unres = out.pop("__unres__")
-            occ = jnp.sum(out["__tkhi__"] != H.EMPTY).astype(jnp.int32)
+            counts = [out["__tkhi__"] != H.EMPTY]
+            if having_dev:
+                counts.append(_hash_having_mask(having_dev, out, routes))
             out["__stats__"] = jnp.concatenate(
-                [unres.astype(jnp.int32), occ.reshape(1)])
+                [unres.astype(jnp.int32)]
+                + [jnp.sum(m).astype(jnp.int32).reshape(1) for m in counts])
             return out
 
         if not sharded:
@@ -2381,18 +2405,23 @@ class QueryEngine:
         return named_jit("sdot_hashed_topk_exchange", smfn), unpack
 
     def _build_hash_gather_program(self, agg_plans, routes, k_gather, T,
-                                   sharded):
+                                   sharded, having_dev=None):
         """Compaction dispatch 2 of 2: gather the ``k_gather`` occupied
         slots from the resident table (per chip) and pack them into one
         transfer buffer — transfer scales with the ACTUAL group count, not
         the table size (a conservatively-sized table costs HBM, not
-        wire)."""
+        wire). With ``having_dev`` the slots that pass the HAVING come
+        first: of 1.5 M groups the few that pass travel (the host's
+        epilogue applies the HAVING again, so a slot too many is dropped
+        there)."""
         pack, unpack = self._hash_packers(agg_plans, routes, k_gather,
                                           False)
 
         def run(table):
-            occ = (table["__tkhi__"] != H.EMPTY).astype(jnp.float32)
-            _, idx = jax.lax.top_k(occ, k_gather)
+            keep = _hash_having_mask(having_dev, table, routes) \
+                if having_dev \
+                else table["__tkhi__"] != H.EMPTY
+            _, idx = jax.lax.top_k(keep.astype(jnp.float32), k_gather)
             return pack(_gather_rows(table, idx, T))
 
         if not sharded:
@@ -2843,14 +2872,7 @@ class QueryEngine:
             limbs = out[name + ".limbs"].reshape(n_keys, G.N_LIMBS)
             m = G.limbs_compare(limbs, lit, op)
         else:
-            v = out[name]
-            cmp = {"=": lambda a, b: a == b, "!=": lambda a, b: a != b,
-                   "<": lambda a, b: a < b, "<=": lambda a, b: a <= b,
-                   ">": lambda a, b: a > b, ">=": lambda a, b: a >= b}
-            m = cmp[op](v, jnp.asarray(lit, v.dtype))
-            nm = G.route_null_mask(r, out)
-            if nm is not None:          # NULL metric: UNKNOWN -> drop
-                m = m & ~nm
+            m = _having_passes(r, out, op, lit)
         return m & occ
 
     def _build_agg_table_program(self, ds, dim_plans, agg_plans,
@@ -3698,6 +3720,32 @@ def _decode_buf(chunk: np.ndarray, dt: str, x64: bool) -> np.ndarray:
             return chunk.astype(np.int32).view(np.float32)
         return chunk.view(np.float32)
     return chunk
+
+
+_HAVING_CMP = {"=": jnp.equal, "!=": jnp.not_equal, "<": jnp.less,
+               "<=": jnp.less_equal, ">": jnp.greater,
+               ">=": jnp.greater_equal}
+
+
+def _having_passes(route, out, op, lit):
+    """Device bool per key: the aggregate ``route`` holds as ONE exact
+    column passes ``op lit`` (``_plan_device_having``); a NULL min/max
+    (its sentinel survived) is UNKNOWN and drops."""
+    v = out[route.name]
+    m = _HAVING_CMP[op](v, jnp.asarray(lit, v.dtype))
+    nm = G.route_null_mask(route, out)
+    return m if nm is None else m & ~nm
+
+
+def _hash_having_mask(having_dev, table, routes):
+    """Device bool [T] over a hashed tier's resident table: slot occupied
+    AND its HAVING passes — ``having_dev`` = (aggregation, op, integer
+    literal) over a route the table holds as one exact integer column
+    (tags i32 / i64; the dense tier's ``_having_mask`` shares the
+    comparison)."""
+    name, op, lit = having_dev
+    return _having_passes(routes[name], table, op, lit) \
+        & (table["__tkhi__"] != H.EMPTY)
 
 
 def _gather_rows(out, idx, n_keys):

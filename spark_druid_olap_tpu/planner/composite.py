@@ -32,6 +32,7 @@ import pandas as pd
 from spark_druid_olap_tpu.ir import expr as E
 from spark_druid_olap_tpu.planner.plans import PlannedQuery, PlanUnsupported
 from spark_druid_olap_tpu.sql import ast as A
+from spark_druid_olap_tpu.utils import phases as PH
 
 
 @dataclasses.dataclass
@@ -290,7 +291,8 @@ def execute_composite(ctx, plan: SubPlan) -> pd.DataFrame:
     prev = getattr(tls, "temp_frames", None)
     tls.temp_frames = {**(prev or {}), **frames}
     try:
-        return host_exec.execute_select(ctx, plan.outer_stmt)
+        with PH.phase("result"):
+            return host_exec.execute_select(ctx, plan.outer_stmt)
     finally:
         tls.temp_frames = prev
 

@@ -239,8 +239,9 @@ def _cached_inner(ctx, q2, sql_tag):
             from spark_druid_olap_tpu.sql.session import _note_subquery_hit
             _note_subquery_hit()             # served_from provenance
             return hit
-    from spark_druid_olap_tpu.sql.session import _run_select
-    df = _run_select(ctx, q2, sql=sql_tag).to_pandas()
+    from spark_druid_olap_tpu.sql.session import _run_select, run_subquery
+    df = run_subquery(
+        ctx, lambda: _run_select(ctx, q2, sql=sql_tag).to_pandas())
     if use_cache:
         result_cache_put(cache, key, df)
     return df

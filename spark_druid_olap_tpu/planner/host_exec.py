@@ -195,13 +195,22 @@ def try_engine(ctx, stmt: A.SelectStmt) -> Optional[pd.DataFrame]:
         from spark_druid_olap_tpu.planner.decorrelate import \
             inline_subqueries
         from spark_druid_olap_tpu.planner.viewmerge import merge_derived
-        from spark_druid_olap_tpu.sql.session import execute_planned
+        from spark_druid_olap_tpu.sql.session import (
+            execute_planned, run_subquery, subquery_parent)
         stmt2 = inline_subqueries(ctx, merge_derived(ctx, stmt))
         pq = B.build(ctx, stmt2)
-        df = execute_planned(ctx, pq)
-        ctx.history.record(stmt2, {**ctx.engine.last_stats,
-                                   "mode": "engine"},
-                           sql="(engine-assisted subtree)")
+
+        def run():
+            df = execute_planned(ctx, pq)
+            stats = {**ctx.engine.last_stats, "mode": "engine"}
+            parent = subquery_parent(ctx)
+            if parent is not None:
+                stats["parent"] = parent
+            ctx.history.record(stmt2, stats,
+                               sql="(engine-assisted subtree)")
+            return df
+
+        df = run_subquery(ctx, run)
     except (PlanUnsupported, EngineFallback, HostExecError,
             host_eval.HostEvalError, KeyError):
         df = None

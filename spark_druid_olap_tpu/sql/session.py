@@ -38,6 +38,41 @@ def _note_subquery_hit() -> None:
     _subq_tls.hits = getattr(_subq_tls, "hits", 0) + 1
 
 
+def run_subquery(ctx, run) -> pd.DataFrame:
+    """``run()`` executes an inlined subquery or an engine-assisted
+    subtree (planner/decorrelate ``_cached_inner``, host_exec
+    ``try_engine``) for the statement this thread is working on. It runs
+    under a ``subquery`` span that hangs under the statement's root and
+    whose time leaves the phase it ran inside — the outer statement's
+    ``plan.rewrite`` holds planning only — and is counted for
+    the outer record: ``subqueries`` executions, ``subquery_rows`` rows of
+    their result frames, ``subquery_fetch_bytes`` copied back from the
+    device under the span. A subquery of the subquery counts in both
+    statements' executions and rows, and its bytes once in each."""
+    t = _subq_tls
+    fetched0 = getattr(t, "fetch_bytes", 0)
+    dc0 = ctx.engine.dispatch_counts[3]
+    t.depth = getattr(t, "depth", 0) + 1
+    try:
+        with PH.lifted("subquery"):
+            df = run()
+    finally:
+        t.depth -= 1
+    t.runs = getattr(t, "runs", 0) + 1
+    t.rows = getattr(t, "rows", 0) + len(df)
+    t.fetch_bytes = fetched0 + ctx.engine.dispatch_counts[3] - dc0
+    return df
+
+
+def subquery_parent(ctx) -> Optional[str]:
+    """The query id of the statement whose subquery is executing on this
+    thread (the ``parent`` key of the inner's record); None outside one
+    and for a statement without an id."""
+    if getattr(_subq_tls, "depth", 0):
+        return getattr(host_exec.ctx_tls(ctx), "query_id", None)
+    return None
+
+
 def resolve_lookups(ctx, stmt: A.SelectStmt) -> A.SelectStmt:
     """Inline registered lookup tables: ``LOOKUP(col, 'name')`` becomes
     ``__lookup_pairs(col, <pairs literal>)`` so both the pushdown builder
@@ -444,6 +479,7 @@ def _run_select_planned(ctx, stmt, sql: str, ph_tok, memo,
                         memo_hit, t0: float) -> QueryResult:
     dc0 = list(ctx.engine.dispatch_counts)
     sq0 = getattr(_subq_tls, "hits", 0)
+    sub0 = [getattr(_subq_tls, k, 0) for k in ("runs", "rows", "fetch_bytes")]
     offset = stmt.offset
     if offset:
         # strip the offset before planning: the engine/host paths see an
@@ -635,6 +671,16 @@ def _run_select_planned(ctx, stmt, sql: str, ph_tok, memo,
     elif mode == "engine" and stats["n_dispatch"] == 0 \
             and getattr(_subq_tls, "hits", 0) > sq0:
         stats["served_from"] = "subquery_cache"
+    # subqueries and engine-assisted subtrees this statement EXECUTED
+    # (run_subquery); one answered from a cache is none
+    n_sub = getattr(_subq_tls, "runs", 0) - sub0[0]
+    if n_sub:
+        stats["subqueries"] = n_sub
+        stats["subquery_rows"] = _subq_tls.rows - sub0[1]
+        stats["subquery_fetch_bytes"] = _subq_tls.fetch_bytes - sub0[2]
+    parent = subquery_parent(ctx)
+    if parent is not None:
+        stats["parent"] = parent
     if plan_cached:
         stats["plan_cached"] = True
     if memo_hit is not None:

@@ -216,7 +216,7 @@ def _phases_registry(project: Project) \
     return mod, names
 
 
-_PHASE_METHODS = {"phase", "add", "stash", "open_root"}
+_PHASE_METHODS = {"phase", "lifted", "add", "stash", "open_root"}
 _PHASE_RECEIVERS = {"PH", "phases"}
 
 

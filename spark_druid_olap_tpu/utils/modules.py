@@ -51,6 +51,17 @@ class Module:
         ctx.statement_handlers.extend(self.statement_handlers())
 
 
+class HighCardinalityGroupBy(Module):
+    """Contributes nothing. A deployment names it in ``sdot.modules`` to say
+    that it sends group-bys whose programs compile in bounded time only
+    since the sorted-run core reads a column every row of a group agrees on
+    (``ops.groupby.AggInput.same_in_group``) at the run's last row instead
+    of scanning it: TPC-H Q18's outer at SF1 carries four such columns over
+    8.0 M rows and did not compile in 19 minutes before. A program without
+    this class refuses such a deployment when the ``Context`` is created
+    (``load_module`` raises) instead of sitting in that compile."""
+
+
 def load_module(spec: str) -> Module:
     """Instantiate ``package.module:ClassName`` (≈ ModuleLoader's reflective
     ``Class.forName``, SparklineDataModule.scala:120-150)."""

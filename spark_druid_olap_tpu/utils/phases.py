@@ -16,6 +16,8 @@ Usage::
     with PH.phase("plan.build"):
         ...
     PH.add("tier.fault", seconds)    # pre-measured interval ending now
+    with PH.lifted("subquery"):      # the root's child, wherever it runs
+        ...
     phases = PH.end(tok)             # {"plan.build": ms, ...}
     tok.stmt.spans, tok.stmt.t0_ns   # the tree, for the record
     PH.close_root(root)
@@ -36,6 +38,17 @@ Semantics:
   span outside ``begin()``..``end()`` (``http.*``) appear in the tree
   only, so the phases of a statement never overlap and
   ``total_ms - sum(phases)`` is never negative.
+- ``lifted(name)`` is ``phase(name)`` for work that runs INSIDE a span
+  it does not belong to: an inlined subquery executes while the outer
+  statement's ``plan.rewrite`` is open.  Nothing is closed: the span's
+  ``parent`` is the nearest open span of the same name, else the root,
+  whatever was open when it began (so ``parent`` says what a span is
+  part of; a lifted span begins and ends inside a sibling's interval).
+  The first ``subquery`` of a statement is then a direct child of the
+  root and a phase of its own, a subquery's own subquery nests inside
+  it, and its time is taken out of the phase of the root's child it ran
+  inside: ``phases["plan.rewrite"]`` is that span's duration minus the
+  subqueries', planning only, and no time is counted twice.
 - The state is thread-local.  ``begin()`` returns ``None`` when an
   accumulator is already open (nested query execution, e.g. a window
   statement re-entering the select path) — inner spans then land in the
@@ -85,6 +98,7 @@ PHASES = {
     "plan.join": "general-join recognition",
     "plan.composite": "composite (host-assist) plan build",
     "plan.engine": "engine-side aggregation plan (dims, routes, segments)",
+    "subquery": "one execution of an inlined subquery / engine-assisted subtree",
     "wlm.admit": "workload-manager admission",
     "cache.lookup": "result-cache probe",
     "coalesce.hold": "shared scan: joining a group -> the group's close",
@@ -235,9 +249,41 @@ class _Phase:
             self.st = None
 
 
+class _Lifted(_Phase):
+    """A phase that is no part of the spans open around it (see
+    ``lifted``)."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_Lifted":
+        _Phase.__enter__(self)
+        st = self.st
+        if st is not None:
+            spans = st.spans
+            self.row[3] = next((i for i in reversed(st.stack[:-1])
+                                if spans[i][0] == self.name), 0)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        st = self.st
+        _Phase.__exit__(self)       # books it when it is the root's child
+        if st is not None and self.row[3] == 0 and st.acc is not None \
+                and len(st.stack) > 1:
+            # ... and takes it out of the root's child it ran inside
+            outer = st.spans[st.stack[1]][0]
+            st.acc[outer] = st.acc.get(outer, 0.0) - self.row[2] / 1e6
+
+
 def phase(name: str) -> _Phase:
     """Context manager timing one span; no-op without an open statement."""
     return _Phase(name)
+
+
+def lifted(name: str) -> _Phase:
+    """``phase(name)`` for work that runs inside spans it is no part of:
+    it hangs under the nearest open span of its own name, else under the
+    root, and its time leaves the phase it ran inside."""
+    return _Lifted(name)
 
 
 def add(name: str, seconds: float, end_ns: Optional[int] = None) -> None:

@@ -416,3 +416,149 @@ def test_lowered_program_has_no_loop(shape):
     text = jax.jit(run).lower(khi, klo, valid, filt, ints, small).as_text()
     assert text.count("stablehlo.sort") == 2
     assert "stablehlo.while" not in text
+
+
+# -- FD-demoted columns: read at the run's last row, not scanned (PR 33) -----
+
+def _fd_case(shape, seed=5):
+    """Key parts, validity and two value columns that are a FUNCTION of
+    the key (what the planner's ``anyvalue`` promises), plus a sum."""
+    n, T, khi, klo, valid, filt, ints, small = _finals_case(shape, seed)
+    dep_i = (khi * 7 + klo * 3 - 11).astype(jnp.int32)
+    dep_f = (khi.astype(jnp.float32) * 0.5 - klo.astype(jnp.float32))
+    return n, T, khi, klo, valid, dep_i, dep_f, small
+
+
+def _fd_inputs(dep_i, dep_f, small, same):
+    inputs = [G.AggInput("any_i", "max", dep_i, None, is_int=True,
+                         same_in_group=same),
+              G.AggInput("any_f", "max", dep_f, None, is_int=False,
+                         same_in_group=same),
+              G.AggInput("s", "sum", small, None, is_int=True)]
+    routes = {"any_i": G.Route("any_i", "max", "i32"),
+              "any_f": G.Route("any_f", "max", "f32"),
+              "s": G.Route("s", "sum", "i32")}
+    return inputs, routes
+
+
+@pytest.mark.parametrize("shape", ["T_gt_n", "trailing_invalid",
+                                   "T_much_lt_n"])
+def test_same_in_group_reads_the_runs_last_row(shape):
+    """A column every row of a group agrees on gives the same table
+    whether the core scans it (max) or reads it at the run's last row."""
+    n, T, khi, klo, valid, dep_i, dep_f, small = _fd_case(shape)
+    out = {}
+    for same in (False, True):
+        inputs, routes = _fd_inputs(dep_i, dep_f, small, same)
+        out[same] = SG.sorted_hash_groupby(khi, klo, valid, T, inputs,
+                                           routes)
+    assert int(out[True]["__unres__"][0]) == 0
+    assert set(out[True]) == set(out[False])
+    for k in out[False]:
+        _assert_same_bits(out[True][k], out[False][k], (shape, k))
+
+
+def test_same_in_group_drops_the_segmented_scans():
+    """What the flag is for: q18's four demoted columns cost four
+    23-level scans of 8.0 M rows, and the chip's compiler did not finish
+    the program inside a benchmark run's limit (PERF.md, PR 33). Counted
+    here by the scan's slices in the lowered text."""
+    n, T, khi, klo, valid, dep_i, dep_f, small = _fd_case("T_gt_n")
+
+    def lowered(same):
+        def run(khi, klo, valid, dep_i, dep_f, small):
+            inputs, routes = _fd_inputs(dep_i, dep_f, small, same)
+            return SG.sorted_hash_groupby(khi, klo, valid, T, inputs,
+                                          routes)
+        return jax.jit(run).lower(khi, klo, valid, dep_i, dep_f,
+                                  small).as_text()
+
+    scanned, read = lowered(False), lowered(True)
+    assert scanned.count("stablehlo.sort") == read.count("stablehlo.sort") \
+        == 2
+    assert scanned.count("stablehlo.maximum") > 10
+    assert read.count("stablehlo.maximum") == 0
+    assert read.count("stablehlo.slice") < scanned.count("stablehlo.slice") / 4
+
+
+# -- HAVING on the hashed tier's device-resident table (PR 33) ----------------
+
+HAVING_CONF = {**HASHED_CONF,
+               "sdot.engine.groupby.hash.compact.min.slots": 1024,
+               "sdot.engine.having.device.min.keys": 1024}
+
+
+def _having_run(conf, sql, df):
+    ctx = sdot.Context(config=conf)
+    ctx.ingest_dataframe("t", df)
+    r = ctx.sql(sql).to_pandas()
+    st = ctx.history.entries()[-1].stats
+    assert st.get("hashed"), st
+    return r, st
+
+
+@pytest.mark.parametrize("op,lit", [(">", 230), ("<=", 60), ("=", 151),
+                                    (">", 10**6)])
+@pytest.mark.parametrize("sortedrun", ["on", "off"])
+def test_hashed_having_on_the_device_table(op, lit, sortedrun):
+    """TPC-H q18's inner shape: a high-cardinality group-by whose HAVING
+    keeps a few groups. The table form counts the passing slots on the
+    device and the gather dispatch brings only those (`having_device`,
+    `fetch_bytes`); the answer is the host-HAVING form's, row for row."""
+    df = _frame(n=60_000, seed=3, n_keys=9000)
+    sql = (f"select k, sum(q) as s, count(*) as c from t group by k "
+           f"having sum(q) {op} {lit} order by k")
+    conf = {**HAVING_CONF, "sdot.engine.groupby.hash.sortedrun": sortedrun}
+    got, st = _having_run(conf, sql, df)
+    # the same tier with the device HAVING priced out: every group travels
+    want, st0 = _having_run(
+        {**conf, "sdot.engine.having.device.min.keys": 1 << 30}, sql, df)
+    pd.testing.assert_frame_equal(got, want)
+    s = df.groupby("k").q.sum()
+    n_pass = int({">": s > lit, "<=": s <= lit, "=": s == lit}[op].sum())
+    assert len(got) == n_pass and (n_pass > 0) == (lit != 10**6)
+    n_groups = df.k.nunique()
+    assert st0["having_device"] == 0 and st0["groups"] == n_groups
+    assert st["having_device"] == max(64, 1 << (max(n_pass, 1) - 1)
+                                      .bit_length())
+    assert st["groups"] <= st["having_device"] < n_groups
+    assert st["n_dispatch"] == st0["n_dispatch"] == 2
+    assert st["sorted_run"] is (sortedrun == "on")
+
+
+def test_hashed_having_stays_on_the_host_where_the_table_cannot_say():
+    """A float total is an (acc, c) pair on the chip and a comparison
+    with a non-integer literal is not exact there: the host filters."""
+    df = _frame(n=30_000, seed=4, n_keys=5000)
+    for sql in ("select k, sum(price) as p from t group by k "
+                "having sum(price) > 900 order by k",
+                "select k, sum(q) as s from t group by k "
+                "having sum(q) > 200.5 order by k"):
+        got, st = _having_run(HAVING_CONF, sql, df)
+        assert st["having_device"] == 0 and st["groups"] == df.k.nunique()
+        col = "price" if "price" in sql else "q"
+        s = df.groupby("k")[col].sum()
+        assert len(got) == int((s > (900 if col == "price" else 200.5)).sum())
+
+
+def test_device_having_comparison_is_one_for_both_tiers():
+    """``_having_passes`` is what the dense tier's ``_having_mask`` and
+    the hashed table's ``_hash_having_mask`` both compare with: a min/max
+    whose sentinel survived is NULL, UNKNOWN under any operator, and
+    drops; a sum has no NULL."""
+    from spark_druid_olap_tpu.parallel import executor as X
+    out = {"m": jnp.asarray([5, G.I32_MIN, 30], jnp.int32),
+           "s": jnp.asarray([5, 0, 30], jnp.int32),
+           "__tkhi__": jnp.asarray([1, 2, X.H.EMPTY], jnp.int32)}
+    mx, sm = G.Route("m", "max", "i32"), G.Route("s", "sum", "i32")
+    assert X._having_passes(mx, out, "<=", 20).tolist() == [True, False,
+                                                            False]
+    assert X._having_passes(mx, out, "!=", 5).tolist() == [False, False,
+                                                           True]
+    assert X._having_passes(sm, out, "<=", 20).tolist() == [True, True,
+                                                            False]
+    # the hashed table's mask: that, on occupied slots
+    assert X._hash_having_mask(("s", ">=", 0), out,
+                               {"s": sm}).tolist() == [True, True, False]
+    assert X._hash_having_mask(("m", "<", 40), out,
+                               {"m": mx}).tolist() == [True, False, False]
