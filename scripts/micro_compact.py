@@ -2,9 +2,12 @@
 """Late materialization's stage alone, on the backend JAX gives: how c
 columns' survivors reach the static [M] prefix out of N scanned rows.
 
-    python scripts/micro_compact.py [OUT.json [SHRINK]]
+    python scripts/micro_compact.py [OUT.json [SHRINK [SHAPE,...]]]
 
-(``SHRINK`` divides every shape, for a rehearsal off the chip.)
+(``SHRINK`` divides every shape, for a rehearsal off the chip; the shape
+names pick some of ``SHAPES``. The ``*_observed`` shapes time the
+``unique`` spelling alone: every form of all four shapes compiles for
+over ten minutes.)
 
 Three forms at each shape (host clock around ``block_until_ready``, the
 median of 5 rounds of 4 calls; ms):
@@ -37,7 +40,12 @@ SHAPES = [
     # look at the data?)
     ("q3", 4 * 1_000_448, 1 << 20, (0.01, 0.2)),
     ("small_budget", 6 * 1_000_448, 1 << 15, (0.002,)),
+    # the budgets q3 and q10 run under once their shapes have reported
+    # their survivors (PR 34): does a probe still cost what it did?
+    ("q3_observed", 4 * 1_000_448, 1 << 16, (0.0076,)),
+    ("q10_observed", 8 * 1_000_448, 1 << 18, (0.0144,)),
 ]
+SPELLINGS = (("stable", False), ("unique", True))
 N_COLS = 5            # q3: three int32 key columns, two float32 values
 
 
@@ -54,7 +62,7 @@ def _ms(fn, args):
     return float(np.median(ts)) * 1e3
 
 
-def measure(n, m, lives, rng):
+def measure(n, m, lives, rng, spellings=SPELLINGS):
     import jax
     import jax.numpy as jnp
 
@@ -105,7 +113,7 @@ def measure(n, m, lives, rng):
     out = {"n": n, "m": m, "live": live}
     k = min(m, int(np.asarray(mask).sum()))
     want = None
-    for tag, unique in (("stable", False), ("unique", True)):
+    for tag, unique in spellings:
         positions, *carriers = forms(unique)
         out[f"positions_{tag}_ms"] = _ms(jax.jit(positions), (mask,))
         for form in carriers:
@@ -135,9 +143,13 @@ def main():
     dev = jax.devices()[0]
     rng = np.random.default_rng(29)
     shrink = int(sys.argv[2]) if len(sys.argv) > 2 else 1
+    picked = sys.argv[3].split(",") if len(sys.argv) > 3 else None
     doc = {"platform": dev.platform, "device_kind": dev.device_kind,
-           "shapes": {name: measure(n // shrink, m // shrink, lives, rng)
-                      for name, n, m, lives in SHAPES}}
+           "shapes": {name: measure(
+               n // shrink, m // shrink, lives, rng,
+               SPELLINGS[1:] if name.endswith("_observed") else SPELLINGS)
+               for name, n, m, lives in SHAPES
+               if picked is None or name in picked}}
     line = json.dumps(doc, indent=1)
     if len(sys.argv) > 1:
         with open(sys.argv[1], "w") as f:

@@ -116,8 +116,8 @@ class CompactScanContext(ScanContext):
     value derivation, and aggregation all run at O(M) instead of O(N).
     This is the columnar-engine move Druid's historicals make with
     bitmap-index row lists; the TPU form keeps shapes static via a
-    planner-chosen budget with on-device overflow detection (host
-    retries uncompacted).
+    planner-chosen budget with on-device overflow detection (the
+    survivor count travels; the host re-runs under a budget that holds).
 
     Two forms serve a read, one per context, chosen by ``carries_by_sort``
     from the static shapes:
@@ -236,9 +236,11 @@ def compact_scan(ctx: ScanContext, mask, compact_m: int, body,
     """The ONE late-materialization stanza. ``mask`` is the survivor mask
     over ``ctx``'s full-width arrays, ``body(cctx, base)`` what the
     program goes on to compute from the compacted context. Returns
-    ``(cctx, base, n_over)``: the compacted context, the [M] mask of its
-    live rows, and how many survivors did not fit (the caller reports it;
-    the host retries uncompacted).
+    ``(cctx, base, n_live)``: the compacted context, the [M] mask of its
+    live rows, and how many rows survived ``mask`` (the caller reports
+    it: over ``compact_m`` the prefix dropped rows and the host runs the
+    statement again under a budget that holds them; under it, the count
+    is what the shape's next budget is sized from).
 
     One int32 key, ``row + N * dead``, sorts the survivors first and each
     side in ascending row order. It is unique, so the sort needs no
@@ -256,7 +258,6 @@ def compact_scan(ctx: ScanContext, mask, compact_m: int, body,
     okey = jnp.arange(n, dtype=jnp.int32) \
         + jnp.where(flat, jnp.int32(0), jnp.int32(n))
     n_live = jnp.sum(flat.astype(jnp.int32))
-    n_over = jnp.maximum(n_live - jnp.int32(m), 0).astype(jnp.int32)
     # survivors sort first, so which prefix rows are live needs no read
     base = jnp.arange(m, dtype=jnp.int32) < n_live
     parent = (ctx.ds, ctx.arrays, ctx.min_day, ctx.max_day, ctx.tz,
@@ -265,7 +266,7 @@ def compact_scan(ctx: ScanContext, mask, compact_m: int, body,
         sidx = jax.lax.slice_in_dim(
             jax.lax.sort(okey, is_stable=False), 0, m)
         keep = jnp.where(sidx >= n, sidx - n, sidx)
-        return CompactScanContext(*parent, keep=keep), base, n_over
+        return CompactScanContext(*parent, keep=keep), base, n_live
     rec = _ReadRecorder(*parent, m=m)
     jax.eval_shape(lambda: body(rec, jnp.zeros((m,), jnp.bool_)))
     ride = [_full_width(ctx, key).reshape(-1) for key in rec.reads]
@@ -276,7 +277,7 @@ def compact_scan(ctx: ScanContext, mask, compact_m: int, body,
     for key, a, s in zip(rec.reads, ride, rode):
         s = jax.lax.slice_in_dim(s, 0, m)
         taken[key] = s != 0 if a.dtype == jnp.bool_ else s
-    return CompactScanContext(*parent, taken=taken), base, n_over
+    return CompactScanContext(*parent, taken=taken), base, n_live
 
 
 def array_names(ds: Datasource, columns, need_time_ms: bool):

@@ -510,6 +510,27 @@ def explain_cost(ctx, q: S.QuerySpec) -> str:
     return out
 
 
+def _seen_compact_shape(eng, q, ds, seg_idx):
+    """The shape (``QueryEngine._compact_shape``) a one-chip, one-wave
+    run of ``q`` remembers its survivor count under, where it has one;
+    else None."""
+    from spark_druid_olap_tpu.parallel import executor as X
+    agg = X._agg_shape(q)
+    if agg is None or not eng._compact_seen:
+        return None
+    dim_plans, _, min_day, max_day, _, names, _ = eng._plan_agg(
+        ds, seg_idx, agg[0], q.aggregations, q.granularity, q.filter,
+        q.intervals)
+    lits, days = eng._plan_literals(q, ds, dim_plans, min_day, max_day)
+    for tier in ("agg", "hashagg"):
+        shape = eng._compact_shape(tier, ds, lits,
+                                   X._pad_segments(len(seg_idx), 1), days,
+                                   False, 1, names)
+        if shape in eng._compact_seen:
+            return shape
+    return None
+
+
 def _explain_scan_plan(ctx, q: S.QuerySpec) -> str:
     """Physical scan decisions: late-materialization budget and staged
     (post-compaction) filter conjuncts — the explain surface for the
@@ -519,13 +540,17 @@ def _explain_scan_plan(ctx, q: S.QuerySpec) -> str:
     ds = eng.store.get(q.datasource)
     seg_idx = ds.prune_segments(getattr(q, "intervals", None), f)
     cheap, exp = eng._split_filter_staged(f)
-    m = eng._plan_compact_m(ds, seg_idx, cheap, sharded=False)
+    shape = _seen_compact_shape(eng, q, ds, seg_idx)
+    m = eng._plan_compact_m(ds, seg_idx, cheap, sharded=False, shape=shape)
     if m is None:
         return ""
     # ESTIMATE: the execution-time decision additionally sees the agg
     # routes ('ffl' Pallas ceiling), sharding, and overflow memory —
-    # none of which exist at explain time (ADVICE r3)
-    line = f"\nscan: late-materialize to [{m:,}] survivors (estimate)"
+    # none of which exist at explain time (ADVICE r3). OBSERVED: a
+    # compacting program of this shape has reported its survivors, and
+    # the budget is the one that count holds the shape to
+    line = (f"\nscan: late-materialize to [{m:,}] survivors "
+            f"({'estimate' if shape is None else 'observed'})")
     if exp is not None:
         n_exp = len(exp.fields) if isinstance(exp, S.LogicalFilter) \
             and exp.op == "and" else 1

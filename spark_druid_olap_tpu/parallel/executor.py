@@ -711,7 +711,9 @@ class QueryEngine:
         self.mesh = mesh
         self._programs: Dict[tuple, object] = {}   # compile cache
         self._compiling: Dict[tuple, object] = {}  # sig -> in-flight Event
-        self._compact_overflowed: set = set()      # shapes whose budget blew
+        # shape -> (most survivors a compacting program of it reported,
+        # the budget that count holds the shape to): _note_survivors
+        self._compact_seen: Dict[tuple, tuple] = {}
         self._literal_plans: Dict[tuple, tuple] = {}  # spec -> its literals
         self._device_arrays: Dict[tuple, object] = {}
         self._device_bytes = 0
@@ -1125,7 +1127,7 @@ class QueryEngine:
         with self._compile_lock:
             self.mesh = make_mesh(devices=devs) if len(devs) > 1 else None
             self._programs.clear()
-            self._compact_overflowed.clear()
+            self._compact_seen.clear()
             self._device_arrays.clear()
             self._device_bytes = 0
         self.last_stats["resharded_to"] = len(devs)
@@ -1300,9 +1302,14 @@ class QueryEngine:
             # gather-heavy conjuncts apply after compaction and don't
             # shrink what the prefix must hold
             cheap_f0, _ = self._split_filter_staged(filter_spec)
-            compact_m = self._plan_compact_m(
-                ds, seg_idx[:spw], cheap_f0, sharded, routes=routes,
-                n_dev=n_dev, allow_sharded=True, n_keys=n_keys)
+            shape = self._compact_shape("agg", ds, lits, s_pad, days,
+                                        sharded, n_dev, names)
+
+            def plan():
+                return self._plan_compact_m(
+                    ds, seg_idx[:spw], cheap_f0, sharded, routes=routes,
+                    n_dev=n_dev, allow_sharded=True, n_keys=n_keys,
+                    shape=shape)
 
             def run(late):
                 prog_fn, unpack, compact, notes = self._cached_program(
@@ -1312,19 +1319,22 @@ class QueryEngine:
                         intervals, days, n_keys, sharded,
                         routes, topk=topk, late=late, hll_costs=hll_costs,
                         lits=lits))
-                finals, n_over, out = self._run_waves(
+                finals, n_live, out = self._run_waves(
                     q, ds, names, seg_idx, s_pad, sharded, prog_fn, unpack,
-                    routes, n_out, sketch_plans, t0, lits)
+                    routes, n_out, sketch_plans, t0, lits,
+                    budget=compact and compact.m)
                 if notes:
                     self.last_stats.update({
                         "hll_form": notes["hll_form"],
                         "hll_slots": notes["hll_slots"]})
-                return (finals, out), n_over, compact
+                return (finals, out), n_live, compact
 
-            late = self._late(compact_m)
-            # (the key holds the statement's repr: built under a budget only)
-            finals, out = self._retry_uncompacted(
-                late and ("agg", base_sig, topk, _cache_repr(q)), late, run)
+            finals, out = self._run_budgeted(
+                shape, plan, run,
+                count=None if sharded or n_waves > 1 else
+                lambda: self._count_survivors(
+                    q, t0, ds, names, seg_idx, s_pad, lits, cheap_f0,
+                    intervals, days))
             if topk:
                 top_idx = np.asarray(out["__topk_idx__"]).astype(np.int64)
         if t0 is not None:
@@ -1497,14 +1507,20 @@ class QueryEngine:
 
     def _plan_compact_m(self, ds, seg_idx, filter_spec, sharded,
                         routes=None, n_dev=1, allow_sharded=False,
-                        n_keys=None, n_ops=None):
+                        n_keys=None, n_ops=None, shape=None):
         """Static survivor budget for late materialization (None = don't
-        compact). Uses the cost model's filter-selectivity estimate with
-        a 2x safety margin; a wrong estimate is caught by the program's
-        '__over__' output and retried uncompacted. Sharded (dense path
-        only): the budget is PER SHARD — the compact block runs on each
-        shard's local arrays under shard_map, and overflow counts psum
-        before travelling.
+        compact). Where a compacting program of this ``shape`` (or the
+        filter-only count, ``_count_survivors``) has reported how many
+        rows survive, the budget is the one that count holds the shape
+        to (``_note_survivors``: twice the most seen, kept while it
+        holds). At the first sight of a shape it is the cost model's
+        filter-selectivity estimate, which multiplies conjuncts as if
+        independent, with the same 2x margin. Either way a budget the
+        survivors exceed is caught by the program's '__live__' output
+        and the statement run again (``_run_budgeted``). Sharded (dense
+        path only): the budget is PER SHARD — the compact block runs on
+        each shard's local arrays under shard_map, and the largest
+        shard's count travels.
 
         Gate (VERDICT r3 weak 6 — calibrated constants, not literals): the
         compaction sort costs ``rows * sort_c``; it saves the downstream
@@ -1528,9 +1544,12 @@ class QueryEngine:
         rows //= max(int(n_dev) if sharded else 1, 1)   # per-shard budget
         if min_rows > 0 and rows < min_rows:
             return None                  # small scans: the sort wins nothing
-        sel = C._filter_selectivity(filter_spec, ds)
-        est = rows * sel * 2.0           # safety margin before retry
-        m = 1 << max(6, int(np.ceil(np.log2(max(est, 1.0)))))
+        seen = self._compact_seen.get(shape)
+        if seen is None:
+            sel = C._filter_selectivity(filter_spec, ds)
+            m = _budget_for(rows * sel)
+        else:
+            m = seen[1]
         m = max(m, 1 << 15) if rows >= (1 << 21) else m
         if m > rows // 2:
             return None                  # unselective: nothing to remove
@@ -1567,6 +1586,18 @@ class QueryEngine:
                 return None
         return int(m)
 
+    def _compact_shape(self, tier, ds, lits, s_pad, days, sharded, n_dev,
+                       names):
+        """What a survivor count is remembered under: the scan program's
+        signature without its budget and what hangs on it (the table's
+        width, the device epilogues chosen by that width). Like the
+        signature it holds the statement's shape and never a literal's
+        value, so every draw of a template shares one entry; unlike a
+        plan cache's key it holds no text, so a deployment that turns
+        every memory of a text off keeps it."""
+        return (tier, self._sig_base(ds), lits.shape, s_pad, days, sharded,
+                n_dev, tuple(names))
+
     def _late(self, compact_m):
         """What a compacting program is built under and cached by: the
         budget and the two unit costs that choose, with the traced
@@ -1579,31 +1610,95 @@ class QueryEngine:
                 C.unit_cost(self.config, CF.COST_SORT_PAYLOAD_ROW),
                 C.unit_cost(self.config, CF.COST_GATHER_PROBE))
 
-    def _retry_uncompacted(self, key, late, run):
-        """Late materialization's overflow protocol. ``run(late)`` runs
-        the scan under the budget ``late`` (None: uncompacted, and
-        ``key`` is not looked at) and returns (result, rows over the
-        budget, the program's Compaction). The estimate was too optimistic where rows are
-        over: the overflow is recorded (``compact_overflow``), the
-        statement remembered under ``key`` — its VALUES, for which the
-        estimate is structurally off, not its shape — and the scan run
-        again uncompacted; a remembered statement goes straight there
-        and does not pay the double execution on every warm run."""
-        if late and key in self._compact_overflowed:
-            late = None
-        result, n_over, compact = run(late)
-        if late and n_over:
-            self.last_stats["compact_overflow"] = int(n_over)
-            self._compact_overflowed.add(key)
-            result, _, compact = run(None)
-        self._note_compaction(compact)
+    def _run_budgeted(self, shape, plan, run, count=None):
+        """Late materialization's budget protocol, the dense and the
+        hashed tier's alike. ``plan()`` is the tier's ``_plan_compact_m``
+        for ``shape`` — the program's signature without its budget and
+        table width, never a literal's value: every draw of a template
+        shares one entry — and ``run(late)`` runs the scan under the
+        budget ``late`` (``_late``; None: uncompacted) and returns
+        (result, survivors the program counted, its Compaction).
+
+        The first sight of a shape whose estimate says "compact" takes
+        the count from ``count()``, a filter-only program, so the
+        estimate's program is never built (None — sharded or several
+        waves, which no deployment runs compacted — observes with the
+        estimate's program instead). Every compacting run reports its
+        count to the shape; one the budget did not hold (the prefix
+        dropped rows: ``compact_overflow``) is run again under the
+        budget the count asks for, or uncompacted where
+        ``_plan_compact_m``'s exits say so, and the SHAPE goes straight
+        there from then on. The record says how full the budget ran
+        (``compact_live``) and where it came from (``compact_from``)."""
+        with PH.phase("plan.engine"):
+            m = plan()
+            observed = shape in self._compact_seen
+        if m and not observed and count is not None:
+            self._note_survivors(shape, m, count())
+            m, observed = plan(), True
+        while True:
+            result, n_live, compact = run(self._late(m))
+            if not m:
+                return result
+            self._note_survivors(shape, m, n_live)
+            if n_live <= m:
+                break
+            self.last_stats["compact_overflow"] = n_live - m
+            m, observed = plan(), True
+        self.last_stats.update({
+            "compact_m": compact.m, "compact_carry": compact.carry,
+            "compact_cols": compact.cols, "compact_live": n_live,
+            "compact_from": "observed" if observed else "estimate"})
         return result
 
-    def _note_compaction(self, compact):
-        if compact:
-            self.last_stats.update({"compact_m": compact.m,
-                                    "compact_carry": compact.carry,
-                                    "compact_cols": compact.cols})
+    def _note_survivors(self, shape, m, n_live):
+        """A program of ``shape`` counted ``n_live`` survivors under the
+        budget ``m``. The shape keeps the most it has seen and the budget
+        that count holds it to — sticky: the budget in force stays while
+        the count fits it and fills over an eighth of it, so draws that
+        straddle a power of two do not flip the shape between two
+        programs; it is sized anew, at twice the count, only where rows
+        were dropped or a quarter of it would do."""
+        seen = self._compact_seen.get(shape)
+        if seen is not None and n_live <= seen[0]:
+            return                      # nothing the shape has not seen
+        with self._compile_lock:
+            seen = self._compact_seen.get(shape)
+            if seen is not None:
+                n_live, m = max(n_live, seen[0]), seen[1]
+            want = _budget_for(n_live)
+            if n_live > m or want * 4 <= m:
+                m = want
+            _memo_put_bounded(self._compact_seen, shape, (n_live, m),
+                              _COMPACT_SEEN_MAX)
+
+    def _count_survivors(self, q, t0, ds, names, seg_idx, s_pad, lits,
+                         cheap_f, intervals, days):
+        """How many rows the cheap filter keeps, by a program that does
+        nothing else (no sort: it compiles in seconds where a compacting
+        program takes minutes): the first sight of a compacting shape,
+        once a shape a process. One chip, one wave; binds the statement's
+        own arrays, which its scan program then finds resident."""
+        min_day, max_day = days or (None, None)
+
+        def build():
+            def run(arrays):
+                ctx = ScanContext(ds, arrays, min_day, max_day,
+                                  tz=self.config.get(TZ_ID),
+                                  operands=_operands(lits, arrays))
+                base = _survivor_mask(ctx, cheap_f, intervals)
+                return jnp.sum(base.astype(jnp.int32)).reshape(1)
+            return named_jit("sdot_count_survivors", run)
+
+        prog = self._cached_program(
+            ("count", self._sig_base(ds), lits.shape, s_pad, days,
+             tuple(names)), build)
+        n_live, = self._waves(q, t0, ds, names, [seg_idx], s_pad, False,
+                              lits, prog,
+                              lambda buf: int(np.asarray(buf)[0]))
+        # the record names the scan program, which follows
+        del self.last_stats["program"]
+        return n_live
 
     def _plan_device_topk(self, limit, having, agg_plans, n_keys):
         """Decide whether the ordered-limit epilogue can run on device:
@@ -1756,20 +1851,26 @@ class QueryEngine:
             exch_plan = self._plan_hash_topk_exchange(q, limit, having,
                                                       agg_plans)
 
-        kg_used = 0
-        tk_scores = None
         # late materialization (shared with the dense path): the key
-        # build + scatter aggregation shrink to O(survivors); a budget
-        # overflow folds into '__unres__' and the first retry disables it
+        # build + aggregation shrink to O(survivors) under the shape's
+        # survivor budget (_run_budgeted), and at most that many rows
+        # reach the table, so the budget bounds the table as truly as
+        # min(key space, selected rows) does
         cheap_f0, _ = self._split_filter_staged(filter_spec)
-        lm = self._plan_compact_m(ds, seg_idx, cheap_f0, sharded,
-                                  n_keys=T,
-                                  n_ops=len(agg_plans) + 2) \
-            if n_waves == 1 else None
-        if lm and ("hashlm", ds.name, _cache_repr(q)) \
-                in self._compact_overflowed:
-            lm = None
-        while True:
+        shape = self._compact_shape("hashagg", ds, lits, s_pad, days,
+                                    sharded, n_dev, names)
+        T_full, fixed_T = T, bool(self.config.get(GROUPBY_HASH_SLOTS))
+
+        def plan():
+            return self._plan_compact_m(
+                ds, seg_idx, cheap_f0, sharded, n_keys=T_full,
+                n_ops=len(agg_plans) + 2, shape=shape) \
+                if n_waves == 1 else None
+
+        def scan(T, late_key):
+            """One scan into a table of ``T`` slots under the budget
+            ``late_key`` -> (rows the table did not resolve, survivors
+            counted, what the epilogue reads)."""
             # k_sel*4 <= T also bounds k_sel < T, so no clamp is needed
             topk = topk_plan if topk_plan and topk_plan[1] * 4 <= T \
                 else None
@@ -1794,7 +1895,6 @@ class QueryEngine:
                 routes = G.plan_routes(
                     metas, T, self.config.get(GROUPBY_MATMUL_MAX_KEYS),
                     n_rows=n_rows_dev)
-            late_key = self._late(lm)
             # HAVING on the device-resident table (the dense tier's
             # transfer filter, _plan_device_having): one chip, one wave
             # — a partial table's totals are not the group's — and an
@@ -1809,7 +1909,7 @@ class QueryEngine:
                    sharded, n_dev, T, tuple(names), topk, compact,
                    late_key, sorted_run, having_dev)
 
-            def build(late_key=late_key):
+            def build():
                 # the program and, under a budget, its Compaction: the
                 # record reads the form it ran in from there
                 late = Compaction(*late_key) if late_key else None
@@ -1827,7 +1927,9 @@ class QueryEngine:
 
             prog, late = self._cached_program(sig, build)
 
-            partials, unresolved = [], 0
+            partials, unresolved, kg_used, tk_scores = [], 0, 0, None
+            # (without a budget no survivors are counted)
+            n_live, budget = 0, late.m if late else 0
             # the table form's program has no unpack: its table stays on
             # the device and a wave lands as (table, stats)
             prog_fn, unpack = (prog, None) if compact or exch else prog
@@ -1836,7 +1938,9 @@ class QueryEngine:
                 if unpack is not None:
                     raw = landed
                     unresolved += int(raw.pop("__unres__").sum())
-                    if unresolved:
+                    if late:
+                        n_live = int(raw.pop("__live__")[0])
+                    if unresolved or n_live > budget:
                         break
                     if topk:
                         tk_scores = raw.pop("__topk_score__")
@@ -1844,11 +1948,15 @@ class QueryEngine:
                         _hash_chip_partials(raw, routes, k_out, n_dev))
                     continue
                 # the table's second program, a dispatch of its own: the
-                # exchanged top-k candidates or the occupied slots
+                # exchanged top-k candidates or the occupied slots.
+                # '__stats__' a chip: unresolved, (survivors under a
+                # budget,) occupied slots (, those that pass the HAVING)
                 table, stats = landed[0], landed[1].reshape(
-                    -1, 3 if having_dev else 2)
+                    -1, 2 + bool(late) + bool(having_dev))
                 unresolved += int(stats[:, 0].sum())
-                if unresolved:
+                if late:
+                    n_live = int(stats[0, 1])
+                if unresolved or n_live > budget:
                     break
                 if exch:
                     metric, k_sel, ascending = exch
@@ -1878,22 +1986,33 @@ class QueryEngine:
                 kg_used = max(kg_used, kg)
                 raw = self._run_program(gfn, table, unpackB)
                 partials.extend(_hash_chip_partials(raw, routes, kg, n_dev))
-            if not unresolved:
-                self._note_compaction(late)
-                break
-            if lm:
-                # the late-materialization budget may be what overflowed
-                # (it folds into '__unres__'): disable it at the SAME T
-                # first; only a second failure means true table overflow
-                self.last_stats["compact_overflow"] = int(unresolved)
-                self._compact_overflowed.add(
-                    ("hashlm", ds.name, _cache_repr(q)))
-                lm = None
-                continue
-            T *= 4
-            if T > max_slots:
-                raise EngineFallback(
-                    f"hashed group-by exceeded {max_slots} table slots")
+            return unresolved, n_live, (
+                T, routes, topk, exch, having_dev, sorted_run, partials,
+                kg_used, tk_scores, late)
+
+        def run(late_key):
+            # the table follows the budget; its overflow retries at 4x
+            # slots behind it (a budget's overflow is the protocol's)
+            T = T_full if fixed_T or not late_key else min(
+                T_full, H.initial_slots(late_key[0], hi=max_slots))
+            while True:
+                unresolved, n_live, tier = scan(T, late_key)
+                if late_key and n_live > late_key[0]:
+                    return None, n_live, None    # the budget's overflow
+                if not unresolved:
+                    return tier, n_live, tier[-1]
+                T *= 4
+                if T > max_slots:
+                    raise EngineFallback(
+                        f"hashed group-by exceeded {max_slots} table slots")
+
+        (T, routes, topk, exch, having_dev, sorted_run, partials, kg_used,
+         tk_scores, late) = self._run_budgeted(
+            shape, plan, run,
+            count=None if sharded or n_waves > 1 else
+            lambda: self._count_survivors(
+                q, t0, ds, names, seg_idx, s_pad, lits, cheap_f0,
+                intervals, days))
         if t0 is not None:
             self._stage_check(q, t0)
 
@@ -1943,7 +2062,7 @@ class QueryEngine:
             # the mechanism that ran: sorted-run core or scatter, and the
             # rows a chip's table was built from (per wave)
             "sorted_run": bool(sorted_run),
-            "hash_rows": int(lm) if lm
+            "hash_rows": late.m if late
             else int(s_pad // n_dev) * int(ds.padded_rows),
             "topk_device": int(topk[1]) if topk
             else (int(exch[1]) if exch else 0),
@@ -1982,7 +2101,7 @@ class QueryEngine:
         static [compact.m] prefix (``ops.scan.compact_scan``: as payloads
         of the compaction sort or by a gather an array, whichever the
         shapes price lower) and the tail runs at O(survivors). Returns
-        (the tail's outputs, the survivors over budget) and notes on
+        (the tail's outputs, the survivors counted) and notes on
         ``compact`` the form that ran. The position sort is 0.7ms/M rows
         on a v5e and each column it carries 0.5-0.7 — far below one
         6M-row scatter (~40ms)."""
@@ -1999,12 +2118,12 @@ class QueryEngine:
                     live = live & em
             return tail(cctx, live, cse)
 
-        cctx, live, n_over = compact_scan(
+        cctx, live, n_live = compact_scan(
             ctx, base, compact.m, staged, compact.payload_row_s,
             compact.probe_s)
         out = staged(cctx, live)
         compact.carry, compact.cols = cctx.carried()
-        return out, n_over
+        return out, n_live
 
     def _hash_core(self, ds, dim_plans, parts, agg_plans, filter_spec,
                    intervals, days, T, routes,
@@ -2014,9 +2133,9 @@ class QueryEngine:
         buffers. Returns the raw out dict incl. '__tkhi__'/'__tklo__' key
         tables and '__unres__' (shape [1]). With ``compact`` (a
         ``Compaction``), late materialization (same machinery as the dense
-        path) runs the key build + aggregation at O(survivors); a budget
-        overflow folds into '__unres__' (the host first retries
-        uncompacted, then grows T)."""
+        path) runs the key build + aggregation at O(survivors) and the
+        survivor count travels as '__live__' (shape [1]) beside
+        '__unres__', which stays the table's own."""
         matmul_max = self.config.get(GROUPBY_MATMUL_MAX_KEYS)
         cards = [p.card for p in dim_plans]
         cheap_f, exp_f = (self._split_filter_staged(filter_spec)
@@ -2031,20 +2150,12 @@ class QueryEngine:
                               tz=self.config.get(TZ_ID), operands=operands)
             # same trace-time predicate CSE as the dense core
             cse = FU.CSECache(ctx) if fuse_cse else None
-            base = ctx.row_valid()
-            fm = cse.lower(cheap_f) if cse is not None \
-                else F.lower_filter(cheap_f, ctx)
-            if fm is not None:
-                base = base & fm
-            im = F.interval_mask(intervals, ctx)
-            if im is not None:
-                base = base & im
+            base = _survivor_mask(ctx, cheap_f, intervals, cse)
             if not compact:
                 return tail(ctx, base, cse)
-            out, n_over = self._compacted(ctx, base, compact, tail, exp_f,
+            out, n_live = self._compacted(ctx, base, compact, tail, exp_f,
                                           fuse_cse)
-            out["__unres__"] = (out["__unres__"].reshape(-1)[0]
-                                + n_over).reshape(1)
+            out["__live__"] = n_live.reshape(1)
             return out
 
         def tail(ctx, base, cse):
@@ -2080,13 +2191,14 @@ class QueryEngine:
         return core
 
     def _hash_packers(self, agg_plans, routes, k_out, with_unres: bool,
-                      with_score: bool = False):
+                      with_score: bool = False, with_live: bool = False):
         """(pack, unpack) over the hash outputs: ONE flat buffer — every
         device->host transfer is its own sync, so the table must not
         travel as 8-10 separate arrays (same packing contract as the
         dense path)."""
         x64 = G._x64()
         meta = ([("__unres__", 1, "i32")] if with_unres else []) \
+            + ([("__live__", 1, "i32")] if with_live else []) \
             + [("__tkhi__", k_out, "i32"), ("__tklo__", k_out, "i32")]
         if with_score:
             meta.append(("__topk_score__", k_out, "f64" if x64 else "f32"))
@@ -2212,14 +2324,16 @@ class QueryEngine:
                                lits=lits)
         k_out = topk[1] if topk else T
         pack, unpack = self._hash_packers(agg_plans, routes, k_out, True,
-                                          with_score=bool(topk))
+                                          with_score=bool(topk),
+                                          with_live=bool(compact))
 
         def run(arrays):
             out = core(arrays)
             if topk:
-                unres = out.pop("__unres__")
+                counts = {k: out.pop(k) for k in ("__unres__", "__live__")
+                          if k in out}
                 out = _hash_topk_gather(out, routes, topk, T)
-                out["__unres__"] = unres
+                out.update(counts)
             return pack(out)
 
         if not sharded:
@@ -2235,8 +2349,9 @@ class QueryEngine:
                                   lits):
         """Compaction dispatch 1 of 2: build the table, leave it DEVICE-
         RESIDENT, transfer only '__stats__' = [unresolved, occupied] per
-        chip — with ``having_dev`` also how many occupied slots pass the
-        HAVING. The host sizes the gather dispatch from the last."""
+        chip — under a ``compact`` budget the survivors counted between
+        the two, with ``having_dev`` also how many occupied slots pass
+        the HAVING. The host sizes the gather dispatch from the last."""
         core = self._hash_core(ds, dim_plans, parts, agg_plans, filter_spec,
                                intervals, days, T, routes,
                                compact=compact, sorted_run=sorted_run,
@@ -2244,12 +2359,13 @@ class QueryEngine:
 
         def run(arrays):
             out = core(arrays)
-            unres = out.pop("__unres__")
+            head = [out.pop("__unres__")] \
+                + ([out.pop("__live__")] if compact else [])
             counts = [out["__tkhi__"] != H.EMPTY]
             if having_dev:
                 counts.append(_hash_having_mask(having_dev, out, routes))
             out["__stats__"] = jnp.concatenate(
-                [unres.astype(jnp.int32)]
+                [c.astype(jnp.int32) for c in head]
                 + [jnp.sum(m).astype(jnp.int32).reshape(1) for m in counts])
             return out
 
@@ -2430,28 +2546,30 @@ class QueryEngine:
                                 P(SEGMENT_AXIS)), unpack
 
     def _run_waves(self, q, ds, names, seg_idx, s_pad, sharded, prog_fn,
-                   unpack, routes, n_out, sketch_plans, t0, lits):
+                   unpack, routes, n_out, sketch_plans, t0, lits,
+                   budget=None):
         """The dense tier's consumer of the wave pipeline: each wave's
-        [n_out] finals merge on the host. Returns (finals, rows over the
-        compaction budget, the last wave's unpacked outputs); a wave
-        over its budget stops the scan, which the caller re-runs
-        uncompacted. ≈ the reference's cost-model "waves" of
+        [n_out] finals merge on the host. Returns (finals, the most
+        survivors a wave (and, sharded, a shard) counted under the
+        compaction ``budget`` — 0 without one —, the last wave's unpacked
+        outputs); a wave over its budget stops the scan, which the
+        caller re-runs. ≈ the reference's cost-model "waves" of
         segments-per-query bounding per-historical work
         (DruidQueryCostModel.scala:309-314,444)."""
         wave_segs = [seg_idx[i: i + s_pad]
                      for i in range(0, len(seg_idx), s_pad)]
-        finals = None
+        finals, n_live = None, 0
         for out in self._waves(q, t0, ds, names, wave_segs, s_pad, sharded,
                                lits, prog_fn, unpack):
-            over = out.pop("__over__", None)
-            n_over = 0 if over is None \
-                else int(np.asarray(over).reshape(-1)[0])
-            if n_over:
-                return None, n_over, out
+            if budget:
+                n_live = max(n_live, int(
+                    np.asarray(out.pop("__live__")).reshape(-1)[0]))
+                if n_live > budget:
+                    return None, n_live, out
             f = _finals_from_out(out, routes, n_out, sketch_plans)
             finals = f if finals is None \
                 else _merge_wave_finals(finals, f, routes, sketch_plans)
-        return finals, 0, out
+        return finals, n_live, out
 
     def _plan_agg(self, ds, seg_idx, dimensions, aggregations, granularity,
                   filter_spec, intervals):
@@ -2544,9 +2662,10 @@ class QueryEngine:
         the filters read their literals from the operand bound under
         ``L.LITERALS_KEY``. ``compact``: the program's ``Compaction`` —
         late materialization between the cheap filter and everything
-        after it; an overflow of its budget surfaces as '__over__' and
-        the host retries without. ``hll_costs``: the unit costs an HLL
-        aggregation's registers choose their form under (``_hll_costs``).
+        after it; its survivor count surfaces as '__live__' and the host
+        runs a statement whose budget it exceeds again. ``hll_costs``:
+        the unit costs an HLL aggregation's registers choose their form
+        under (``_hll_costs``).
         ``notes``: a dict the trace fills with what the statement record
         says of the program's sketch epilogue (``hll_form``,
         ``hll_slots``)."""
@@ -2573,19 +2692,12 @@ class QueryEngine:
             # shared by every filtered aggregation) — memoized lowering
             # emits each distinct sub-mask once, bit-identically
             cse = FU.CSECache(ctx) if fuse_cse else None
-            base = ctx.row_valid()
-            fm = cse.lower(cheap_f) if cse is not None \
-                else F.lower_filter(cheap_f, ctx)
-            if fm is not None:
-                base = base & fm
-            im = F.interval_mask(intervals, ctx)
-            if im is not None:
-                base = base & im
+            base = _survivor_mask(ctx, cheap_f, intervals, cse)
             if not compact:
                 return tail(ctx, base, cse)
-            out, n_over = self._compacted(ctx, base, compact, tail, exp_f,
+            out, n_live = self._compacted(ctx, base, compact, tail, exp_f,
                                           fuse_cse)
-            out["__over__"] = n_over.reshape(1)
+            out["__live__"] = n_live.reshape(1)
             return out
 
         def tail(ctx, base, cse):
@@ -2674,7 +2786,7 @@ class QueryEngine:
         pack, unpack = self._agg_meta_packers(
             agg_plans, routes, topk[1] if topk else n_keys,
             with_idx=bool(topk), with_score=bool(topk),
-            with_over=bool(late))
+            with_live=bool(late))
 
         def topk_gather(out, axis_name=None):
             """Select k_sel candidate keys by score, gather every output."""
@@ -2694,10 +2806,10 @@ class QueryEngine:
             def plain(arrays):
                 out = core(arrays)
                 if topk:
-                    over = out.pop("__over__", None)
+                    live = out.pop("__live__", None)
                     out = topk_gather(out)
-                    if over is not None:
-                        out["__over__"] = over
+                    if live is not None:
+                        out["__live__"] = live
                 return pack(out)
 
             fn = named_jit("sdot_agg_dense", plain)
@@ -2712,7 +2824,7 @@ class QueryEngine:
 
             def sharded_core(arrays):
                 out = core(arrays)
-                over = out.pop("__over__", None)
+                live = out.pop("__live__", None)
                 # ONE mergeable-partial layout for every sharded program
                 # (solo cores here, the fused mesh tier in
                 # parallel/meshexec.py): psum / pmin / pmax per route
@@ -2721,11 +2833,12 @@ class QueryEngine:
                                                SEGMENT_AXIS)
                 if topk:
                     merged = topk_gather(merged, SEGMENT_AXIS)
-                if over is not None:
-                    # any shard overflowing its local budget invalidates
-                    # the run (those rows were dropped): psum so every
-                    # chip's replicated buffer carries the global count
-                    merged["__over__"] = jax.lax.psum(over, SEGMENT_AXIS)
+                if live is not None:
+                    # the budget is a shard's: any shard over it
+                    # invalidates the run (those rows were dropped), so
+                    # every chip's replicated buffer carries the largest
+                    # shard's count
+                    merged["__live__"] = jax.lax.pmax(live, SEGMENT_AXIS)
                 return pack(merged)
 
             if MH.is_multihost():
@@ -2988,7 +3101,7 @@ class QueryEngine:
         return named_jit("sdot_gather", smfn), unpack
 
     def _agg_meta_packers(self, agg_plans, routes, n_out, with_idx,
-                          with_score=False, with_over=False):
+                          with_score=False, with_live=False):
         """(pack, unpack) for the dense path's TWO-buffer transfer:
         collective-merged outputs in one replicated buffer, per-chip
         ff/lanes partial pairs in one segment-sharded buffer. ``n_out``
@@ -3022,8 +3135,8 @@ class QueryEngine:
         if with_score:
             meta.append(("__topk_score__", n_out, "f64" if x64 else "f32",
                          True))
-        if with_over:
-            meta.append(("__over__", 1, "i32", True))
+        if with_live:
+            meta.append(("__live__", 1, "i32", True))
         merged_meta = [t for t in meta if t[3]]
         perchip_meta = [t for t in meta if not t[3]]
         buf_dtype = jnp.int64 if x64 else jnp.int32
@@ -3506,7 +3619,7 @@ class QueryEngine:
         # in _cached_program/_device_tables (sdlint locks/unguarded-write)
         with self._compile_lock:
             self._programs.clear()
-            self._compact_overflowed.clear()
+            self._compact_seen.clear()
             self._literal_plans.clear()
             self._device_arrays.clear()
             self._device_bytes = 0
@@ -3543,6 +3656,7 @@ def _cache_repr(q) -> str:
 
 
 _LITERAL_PLANS_MAX = 512     # specs whose literal plan is kept (a few KB each)
+_COMPACT_SEEN_MAX = 512      # shapes whose survivor count is kept
 
 
 def _spec_identity(q) -> Optional[tuple]:
@@ -3559,6 +3673,12 @@ def _spec_identity(q) -> Optional[tuple]:
         return None
 
 
+def _budget_for(n_live) -> int:
+    """The power-of-two survivor budget for a count of ``n_live``: the
+    2x margin before a run overflows, 64 rows at least."""
+    return 1 << max(6, int(np.ceil(np.log2(max(n_live * 2.0, 1.0)))))
+
+
 def _memo_put_bounded(memo: dict, key, value, bound: int) -> None:
     """Insert, dropping the oldest entries past ``bound``. Lock-free:
     statements run in parallel and two may evict at once."""
@@ -3568,6 +3688,21 @@ def _memo_put_bounded(memo: dict, key, value, bound: int) -> None:
             memo.pop(next(iter(memo)), None)
         except (StopIteration, RuntimeError):   # emptied / resized under us
             break
+
+
+def _survivor_mask(ctx, cheap_f, intervals, cse=None):
+    """The rows a scan keeps before anything is compacted or aggregated:
+    valid, inside the intervals, passing the cheap filter (all of it
+    where the program does not compact)."""
+    base = ctx.row_valid()
+    fm = cse.lower(cheap_f) if cse is not None \
+        else F.lower_filter(cheap_f, ctx)
+    if fm is not None:
+        base = base & fm
+    im = F.interval_mask(intervals, ctx)
+    if im is not None:
+        base = base & im
+    return base
 
 
 def _operands(lits, arrays):

@@ -202,7 +202,8 @@ SCAN_COMPACT = _entry(
     "Late materialization: when the filter-selectivity estimate says few "
     "rows survive, sort survivors to a static prefix and run group-key "
     "building, value derivation, and aggregation at O(survivors) instead "
-    "of O(rows). Overflow of the estimated budget retries uncompacted.")
+    "of O(rows). The budget follows the survivors a statement shape was "
+    "seen to keep; a run that exceeds it is run again at twice the count.")
 SCAN_COMPACT_MIN_ROWS = _entry(
     "sdot.engine.scan.compact.min.rows", 1 << 21,
     "Scans below this many rows never compact (the sort pass wins "

@@ -5,8 +5,15 @@ row positions to a static prefix, and run group-key building / value
 derivation / aggregation at O(survivors) (executor._plan_compact_m,
 CompactScanContext). These tests force the path at test scale via
 `sdot.engine.scan.compact.min.rows` and diff against the uncompacted
-engine: identical results, including the overflow-retry route when the
-selectivity estimate is wildly wrong.
+engine: identical results, including the re-run when a budget is too
+small for its survivors.
+
+The budget is the shape's, not the statement's (executor._run_budgeted):
+a compacting program reports how many rows survived, the shape remembers
+the most it has seen, and the next statement of that shape — whatever
+its literal values — is planned from that count; the independence
+estimate serves only the first sight, and where one chip runs one wave
+even there a filter-only count stands in for the estimate's program.
 
 The survivors' arrays reach the prefix in one of two forms, chosen from
 static shapes and the backend's unit costs (ops.scan.carries_by_sort):
@@ -23,6 +30,7 @@ import pandas as pd
 import pytest
 
 import spark_druid_olap_tpu as sdot
+from spark_druid_olap_tpu.parallel.executor import _budget_for
 
 
 def _df(n=6000, seed=7):
@@ -103,39 +111,234 @@ def test_compaction_engaged_and_stats():
     assert st.get("compact_m", 0) > 0
 
 
-OVERFLOW_SQL = ("select region, count(*) as n from sales "
-                "where qty >= 0 group by region order by region")
+# one shape, its values drawn: qty < 1 keeps ~1 % of the rows, < 8 ~8 %
+DRAW_SQL = ("select region, count(*) as n, sum(price) as p from sales "
+            "where qty < {} group by region order by region")
+
+
+def _send(c, sql):
+    got = c.sql(sql).to_pandas()
+    return got, dict(c.history.entries()[-1].stats)
+
+
+def _draw_ctx():
+    c = _ctx(True)
+    c.config.set("sdot.cache.enabled", False)
+    return c
+
+
+def _compacting_programs(c):
+    """Scan programs built under a budget so far (the budget's place in
+    a signature: last in the dense tier's, third from last in the
+    hashed tier's)."""
+    late = {"agg": -1, "hashagg": -3}
+    return [sig for sig in c.engine._programs
+            if sig[0] in late and sig[late[sig[0]]]]
 
 
 @pytest.mark.parametrize("waves", ["one_wave", "several_waves"])
-def test_overflow_retries_uncompacted_then_remembers(waves, monkeypatch):
-    """A wildly-optimistic selectivity estimate must not produce wrong
-    results: the '__over__' channel forces the uncompacted retry, and a
-    per-wave budget that lies (estimate ~0 survivors) aborts the
-    compacted wave run and re-runs the whole scan uncompacted. The
-    statement is remembered by its values: the same text again goes
-    straight to the uncompacted program, which is built already."""
-    from spark_druid_olap_tpu.parallel import cost as C
+def test_second_run_plans_from_the_first_count(waves):
+    """A compacting run reports its survivors (``compact_live``) and the
+    next run of the shape is planned from them (``compact_from``). One
+    chip, one wave: the FIRST sight already is — a filter-only count
+    (a dispatch of its own, once a shape) stands in for the estimate's
+    program, which is never built. Several waves observe with the
+    estimate's program."""
     if waves == "one_wave":
-        monkeypatch.setattr(C, "_filter_selectivity",
-                            lambda f, ds: 1e-5)      # ~0 rows predicted
-        c, ref, sql = _ctx(True), _ctx(False), OVERFLOW_SQL
+        c, sql = _draw_ctx(), DRAW_SQL.format(3)
     else:
-        monkeypatch.setattr(C, "_filter_selectivity", lambda f, ds: 1e-6)
-        c, ref, sql = _wave_ctx(True), _wave_ctx(False), WAVE_SQL
+        c, sql = _wave_ctx(True), WAVE_SQL
+        c.config.set("sdot.cache.enabled", False)
+    _, first = _send(c, sql)
+    assert first["compact_m"] > 0 and 0 < first["compact_live"]
+    assert first["compact_live"] <= first["compact_m"]
+    if waves == "one_wave":
+        assert first["compact_from"] == "observed"
+        assert first["n_dispatch"] == 2
+    else:
+        assert first["compact_from"] == "estimate"
+        assert first["waves"] > 1
+    (n_live, m), = c.engine._compact_seen.values()
+    assert n_live == first["compact_live"]
+    _, second = _send(c, sql)
+    assert second["compact_from"] == "observed"
+    assert second["compact_live"] == first["compact_live"]
+    assert second["compact_m"] == m
+    assert m in (first["compact_m"], _budget_for(n_live))
+    assert second["n_dispatch"] == second["waves"]
+    assert len(_compacting_programs(c)) == 1 + (m != first["compact_m"])
+
+
+def test_draws_of_one_shape_share_the_entry_and_one_program():
+    """The entry is keyed by the program's signature without its budget:
+    no text, no literal value."""
+    c, ref = _draw_ctx(), _ctx(False)
+    records = []
+    for v in (3, 2, 4):
+        got, st = _send(c, DRAW_SQL.format(v))
+        pd.testing.assert_frame_equal(
+            got, ref.sql(DRAW_SQL.format(v)).to_pandas(),
+            check_dtype=False, atol=1e-6)
+        records.append(st)
+    assert len(c.engine._compact_seen) == 1
+    assert len(_compacting_programs(c)) == 1
+    assert [st["program"]["built"] for st in records] == [True, False, False]
+    assert len({st["program"]["sig"] for st in records}) == 1
+    assert len({st["compact_m"] for st in records}) == 1
+    assert [st["compact_from"] for st in records] == ["observed"] * 3
+    (n_live, _), = c.engine._compact_seen.values()
+    assert n_live == max(st["compact_live"] for st in records)
+
+
+@pytest.mark.parametrize("draws", [(4, 7, 4), (7, 2, 7), (7, 1, 5, 3, 7)])
+def test_budget_that_held_is_kept(draws):
+    """Sticky: a count that fits the budget in force re-plans nothing, so
+    draws that straddle a power of two (qty < 4 asks for 512 rows,
+    qty < 7 for 1024, qty < 2 for 256) do not flip the shape between
+    programs — nor does a draw far under the most the shape has seen."""
+    c = _draw_ctx()
+    seen = [_send(c, DRAW_SQL.format(v))[1] for v in draws]
+    m = seen[0]["compact_m"]
+    assert {_budget_for(st["compact_live"]) for st in seen} != {m}
+    for st in seen:
+        assert st["compact_m"] == m and "compact_overflow" not in st
+        assert st["compact_live"] <= m
+    assert len(_compacting_programs(c)) == 1
+    assert [st["program"]["built"] for st in seen[1:]] \
+        == [False] * (len(draws) - 1)
+
+
+def test_quarter_full_budget_is_sized_anew_once(monkeypatch):
+    """A count a quarter of the budget would hold re-plans (the first
+    sight's case on the chip: q3's estimate asks for 2^20, its count for
+    2^16) — downwards once, on the largest count seen, never after."""
+    from spark_druid_olap_tpu.parallel import cost as C
+    monkeypatch.setattr(C, "_filter_selectivity", lambda f, ds: 0.1)
+    c = _wave_ctx(True)
     c.config.set("sdot.cache.enabled", False)
-    got = c.sql(sql).to_pandas()
-    st = c.history.entries()[-1].stats
+    _, first = _send(c, WAVE_SQL)             # several waves: the estimate's
+    assert first["compact_from"] == "estimate"
+    assert _budget_for(first["compact_live"]) * 4 <= first["compact_m"]
+    for _ in range(2):
+        _, st = _send(c, WAVE_SQL)
+        assert st["compact_m"] == _budget_for(first["compact_live"])
+        assert st["compact_from"] == "observed"
+    assert len(_compacting_programs(c)) == 2
+
+
+def test_concurrent_statements_lose_no_count():
+    """Statements run in parallel and report to one shape: the entry
+    ends at the largest count any of them saw, under a budget that holds
+    it (a lost update would leave a smaller count, or a budget sized for
+    one)."""
+    import os
+    import sys
+    import threading
+    eng = sdot.Context().engine
+    counts = [int(c) for c in
+              np.random.default_rng(17).integers(1, 50_000, 4000)]
+    workers = 2 * (os.cpu_count() or 4)
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(
+            target=lambda part: [eng._note_survivors("shape", 1 << 20, n)
+                                 for n in part],
+            args=(counts[i::workers],)) for i in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(was)
+    n_live, m = eng._compact_seen["shape"]
+    assert n_live == max(counts)
+    assert n_live <= m <= 4 * _budget_for(n_live)
+
+
+def _overflow_one_wave(monkeypatch):
+    c = _draw_ctx()
+    _, small = _send(c, DRAW_SQL.format(1))   # the shape learns ~1 %
+    return c, _ctx(False), DRAW_SQL.format(8), small["compact_m"]
+
+
+def _overflow_several_waves(monkeypatch):
+    from spark_druid_olap_tpu.parallel import cost as C
+    monkeypatch.setattr(C, "_filter_selectivity", lambda f, ds: 1e-6)
+    c = _wave_ctx(True)
+    c.config.set("sdot.cache.enabled", False)
+    return c, _wave_ctx(False), WAVE_SQL, 64
+
+
+def _overflow_to_uncompacted(monkeypatch):
+    c = _draw_ctx()
+    _, small = _send(c, DRAW_SQL.format(1))
+    return c, _ctx(False), DRAW_SQL.format(1000), small["compact_m"]
+
+
+@pytest.mark.parametrize("case", ["one_wave", "several_waves",
+                                  "to_uncompacted"])
+def test_overflow_reruns_at_the_counted_size_then_remembers(case,
+                                                            monkeypatch):
+    """A budget its survivors exceed must not produce wrong results: the
+    '__live__' channel says how many there were, the statement is run
+    again under the budget that count asks for — twice the count, or
+    uncompacted where nothing would be removed — and answers as the
+    uncompacted engine does, bit for bit. A per-wave budget that lies
+    (estimate ~0 survivors) aborts the compacted wave run and re-runs
+    the whole scan. The SHAPE remembers: the next send goes straight to
+    that size, whose program is built already."""
+    c, ref, sql, m0 = {"one_wave": _overflow_one_wave,
+                       "several_waves": _overflow_several_waves,
+                       "to_uncompacted": _overflow_to_uncompacted}[case](
+        monkeypatch)
+    got, st = _send(c, sql)
     want = ref.sql(sql).to_pandas()
-    pd.testing.assert_frame_equal(got, want, check_dtype=False, atol=1e-6)
-    assert (st.get("waves", 1) > 1) == (waves == "several_waves"), st
-    assert st.get("compact_overflow", 0) > 0
+    pd.testing.assert_frame_equal(got, want, check_exact=True)
+    assert (st.get("waves", 1) > 1) == (case == "several_waves"), st
+    assert st["compact_overflow"] > 0
     assert st["program"]["built"] is True
-    again = c.sql(sql).to_pandas()
-    st = c.history.entries()[-1].stats
-    pd.testing.assert_frame_equal(again, want, check_dtype=False, atol=1e-6)
-    assert "compact_overflow" not in st and "compact_m" not in st, st
-    assert st["program"]["built"] is False
+    if case == "to_uncompacted":
+        assert "compact_m" not in st and "compact_live" not in st
+    else:
+        assert st["compact_m"] >= _budget_for(st["compact_live"]) > m0
+        assert st["compact_from"] == "observed"
+    if case == "one_wave":
+        assert st["compact_overflow"] == st["compact_live"] - m0
+        assert st["compact_m"] == _budget_for(st["compact_live"])
+    again, st2 = _send(c, sql)
+    pd.testing.assert_frame_equal(again, want, check_exact=True)
+    assert "compact_overflow" not in st2
+    assert st2["program"]["built"] is False
+    assert st2.get("compact_m") == st.get("compact_m")
+    assert st2["n_dispatch"] == st2.get("waves", 1)
+
+
+def test_never_compacting_statement_keeps_its_program(monkeypatch):
+    """A dense statement the gate never lets compact reports no count,
+    so it stays on the estimate and on the program it has: its signature
+    is the one without a budget, with and without an entry for its
+    shape."""
+    c = sdot.Context()                       # the gate's own min.rows
+    c.config.set("sdot.cache.enabled", False)
+    c.ingest_dataframe("sales", _df(), time_column="ts", target_rows=1024)
+    sql = DRAW_SQL.format(3)
+    shapes, shape_of = [], c.engine._compact_shape
+    monkeypatch.setattr(
+        c.engine, "_compact_shape",
+        lambda *a: shapes.append(shape_of(*a)) or shapes[-1])
+    _, first = _send(c, sql)
+    assert "compact_m" not in first and "compact_from" not in first
+    assert not c.engine._compact_seen
+    sig, = c.engine._programs
+    assert sig[0] == "agg" and sig[-1] is None
+    # an entry under its shape, as if a compacting run had reported
+    c.engine._note_survivors(shapes[-1], 128, 100)
+    _, second = _send(c, sql)
+    assert second["program"] == {**first["program"], "built": False}
+    assert "compact_m" not in second
+    assert list(c.engine._programs) == [sig]
 
 
 def test_staged_expensive_membership_matches():
@@ -169,6 +372,31 @@ def test_hashed_tier_compaction_matches():
     st = c1.history.entries()[-1].stats
     assert st.get("hashed")
     assert st.get("compact_m", 0) > 0 or st.get("compact_overflow", 0) > 0
+
+
+@pytest.mark.parametrize("sortedrun", ["on", "off"])
+def test_hashed_table_follows_the_budget(sortedrun):
+    """At most ``compact_m`` rows reach the table, so it is no wider than
+    ``initial_slots(compact_m)`` — where the uncompacted statement sizes
+    it from min(key space, selected rows) — and the answers are equal."""
+    from spark_druid_olap_tpu.ops import hash_groupby as H
+    df = _df(60_000, seed=3)
+    df["sku"] = np.random.default_rng(3).integers(0, 40_000, len(df)) \
+        .astype(str)
+    sql = ("select sku, sum(qty) as s, count(*) as n from sales "
+           "where qty = 7 group by sku order by s desc, sku limit 20")
+    frames, records = [], []
+    for compact in (True, False):
+        c = _ctx(compact, df)
+        _hashed(sortedrun)(c, None)
+        frames.append(c.sql(sql).to_pandas())
+        records.append(dict(c.history.entries()[-1].stats))
+    pd.testing.assert_frame_equal(*frames, check_exact=True)
+    st, plain = records
+    assert st["hashed"] and plain["hashed"] and "compact_m" not in plain
+    assert st["hash_rows"] == st["compact_m"] >= st["compact_live"] > 0
+    assert st["hash_slots"] <= H.initial_slots(st["compact_m"])
+    assert st["hash_slots"] < plain["hash_slots"]
 
 
 def test_sketches_under_compaction_match():
@@ -346,8 +574,9 @@ HASHED_SQL = ("select sku, sum(qty) as s, sum(price) as p, count(*) as n "
               "from sales where region = 'east' and qty = 7 "
               "group by sku order by sku limit 30")
 
-# name -> (runner, arrays the compacted body reads; None: the budget
-# overflows and the statement is answered uncompacted)
+# name -> (runner, arrays the compacted body reads; None: the estimate
+# lies, the count says every row survives, and the statement is answered
+# uncompacted without a compacting program ever being built)
 FORM_CASES = {
     "dense_selector": (_sql_case(QUERIES[0]), 2),
     "dense_in_expr": (_sql_case(QUERIES[1]), 2),
@@ -367,7 +596,7 @@ FORM_CASES = {
     "all_rows_filtered": (_sql_case(
         "select count(*) as n, sum(qty) as s, max(price) as mx from sales "
         "where sku = 'sku001' and qty > 98 and qty < 1"), 2),
-    "overflow_retry": (_sql_case(
+    "estimate_lies": (_sql_case(
         "select region, count(*) as n, sum(price) as p from sales "
         "where qty >= 0 group by region order by region",
         _lying_estimate), None),
@@ -399,7 +628,7 @@ def test_form_matches_uncompacted(case, form, form_runs, monkeypatch):
     pd.testing.assert_frame_equal(got, want, check_dtype=False, atol=1e-6)
     cols = FORM_CASES[case][1]
     if cols is None:
-        assert st.get("compact_overflow", 0) > 0
+        assert "compact_overflow" not in st and st["n_dispatch"] == 2
         assert "compact_m" not in st and "compact_carry" not in st
     else:
         assert st.get("compact_m", 0) > 0, st
@@ -468,16 +697,17 @@ def _stage(arrays, mask, m, sort):
     carries by sort, at a second gathers."""
     from spark_druid_olap_tpu.ops import scan as SC
     ctx = SC.ScanContext(None, arrays, 0, 0)
-    cctx, base, n_over = SC.compact_scan(
+    cctx, base, n_live = SC.compact_scan(
         ctx, mask, m, _stage_body, 1e-15 if sort else 1.0, 1e-9)
-    return _stage_body(cctx, base), n_over, cctx.carried()
+    return _stage_body(cctx, base), n_live, cctx.carried()
 
 
 @pytest.mark.parametrize("live", ["under", "exact", "over"])
 def test_stage_prefix_and_base(live):
     """Both forms hold the survivors in row order in the [M] prefix; the
     validity array rides as int8 and returns a bool mask; `base` — now an
-    iota compare — equals the old read ``flat[keep]``; overflow counts."""
+    iota compare — equals the old read ``flat[keep]``; the survivors are
+    counted whether or not they fit."""
     import jax.numpy as jnp
     m = 256
     arrays = _stage_arrays()
@@ -494,9 +724,9 @@ def test_stage_prefix_and_base(live):
     k = min(want_live, m)
     outs = {}
     for sort in (True, False):
-        out, n_over, carried = _stage(dev, jnp.asarray(mask), m, sort)
+        out, n_live, carried = _stage(dev, jnp.asarray(mask), m, sort)
         assert carried == ("sort" if sort else "gather", 4)
-        assert int(n_over) == max(want_live - m, 0)
+        assert int(n_live) == want_live
         base = np.asarray(out["base"])
         assert base.dtype == np.bool_
         np.testing.assert_array_equal(base, old_base)

@@ -414,3 +414,38 @@ def test_estimate_prices_interconnect_bytes(store):
     # bytes shipped (n_dev - 1) times, ring convention
     assert est.ici_bytes == est.output_groups * 2 * 8 * (est.n_devices - 1)
     assert est.ici_bytes > 0
+
+
+@pytest.mark.parametrize("tier", ["dense", "hashed"])
+def test_explain_says_where_the_budget_came_from(tier):
+    """The scan line's budget is the independence estimate's until a
+    compacting program of the shape has reported its survivors; from
+    then on it is the one that count holds the shape to — for another
+    draw of the shape as well."""
+    import re
+    import spark_druid_olap_tpu as sdot
+    rng = np.random.default_rng(5)
+    n = 40_000
+    df = pd.DataFrame({"k": rng.integers(0, 50, n).astype(str),
+                       "sel": rng.integers(0, 1000, n),
+                       "v": rng.normal(size=n)})
+    conf = {"sdot.engine.scan.compact.min.rows": 0,
+            "sdot.cache.enabled": False}
+    if tier == "hashed":
+        conf["sdot.engine.groupby.dense.max.keys"] = 8
+    ctx = sdot.Context(config=conf)
+    ctx.ingest_dataframe("exp_obs", df)
+    sql = "select k, sum(v) as s from exp_obs where sel < {} group by k"
+
+    def line(v):
+        m = re.search(r"late-materialize to \[([\d,]+)\] survivors \((\w+)\)",
+                      ctx.explain(sql.format(v)))
+        return int(m.group(1).replace(",", "")), m.group(2)
+
+    est, src = line(10)
+    assert src == "estimate"
+    ctx.sql(sql.format(10))
+    st = ctx.history.entries()[-1].stats
+    assert st.get("hashed", False) == (tier == "hashed")
+    for v in (10, 12):
+        assert line(v) == (st["compact_m"], "observed")

@@ -272,14 +272,14 @@ def test_kept_literals_do_not_outlive_their_datasource():
     assert got.v.tolist() == [7] and got.n.tolist() == [2]
 
 
-def test_learned_compaction_overflow_is_per_values(monkeypatch):
-    """A draw whose survivor budget overflowed goes uncompacted from
-    then on; another draw of the same shape still gets its own try
-    (``_compact_overflowed`` holds the statement's values)."""
-    from spark_druid_olap_tpu.parallel import cost as C
+def test_learned_survivor_count_is_per_shape():
+    """What late materialization learns — how many rows a shape's filter
+    keeps (``_compact_seen``) — is held by the statement's SHAPE, never
+    its values: a draw whose survivors overflow the budget another draw
+    left re-runs under the budget its own count asks for, and every
+    later draw of the shape, whatever its value, goes straight to that
+    one program."""
     ctx, data = _tpch_context({"sdot.engine.scan.compact.min.rows": 0})
-    sel = {"v": 1e-5}
-    monkeypatch.setattr(C, "_filter_selectivity", lambda f, ds: sel["v"])
     sql = ("select l_returnflag, count(*) as n from lineitem "
            "where l_quantity < {} group by l_returnflag "
            "order by l_returnflag")
@@ -289,15 +289,21 @@ def test_learned_compaction_overflow_is_per_values(monkeypatch):
         return li[li.l_quantity < q].groupby("l_returnflag").size() \
             .reset_index(name="n")
 
-    got, st = _run(ctx, sql.format(40))
-    assert st.get("compact_overflow", 0) > 0
-    _check(got, want(40))
-    _, st = _run(ctx, sql.format(40))          # remembered: no second try
-    assert "compact_overflow" not in st and "compact_m" not in st
-    sel["v"] = 0.05                             # an honest estimate now
-    got, st = _run(ctx, sql.format(3))          # same shape, other value
-    assert st.get("compact_m", 0) > 0, st
-    _check(got, want(3))
+    got, st = _run(ctx, sql.format(2))          # ~2 % of the rows
+    assert st["compact_from"] == "observed" and st["compact_m"] > 0
+    small = st["compact_m"]
+    _check(got, want(2))
+    got, st = _run(ctx, sql.format(6))          # same shape, ~10 %
+    assert st["compact_overflow"] == st["compact_live"] - small
+    assert st["compact_m"] > small
+    _check(got, want(6))
+    held = st["compact_m"]
+    for q in (6, 2, 4):                         # remembered: no second try
+        got, st = _run(ctx, sql.format(q))
+        assert "compact_overflow" not in st and st["compact_m"] == held
+        assert st["program"]["built"] is False
+        _check(got, want(q))
+    assert len(ctx.engine._compact_seen) == 1
 
 
 # -- shapes ----------------------------------------------------------------------
