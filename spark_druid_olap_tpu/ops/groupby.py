@@ -80,7 +80,9 @@ def _x64() -> bool:
 
 @dataclasses.dataclass
 class AggInput:
-    """One lowered aggregation: kind in {'count','sum','min','max'};
+    """One lowered aggregation: kind in {'count','sum','min','max'} — and,
+    in the hashed tier's cores only, 'hll' (``values`` are what is
+    hashed, ``log2m`` the sketch's precision; ops/sorted_groupby.py);
     ``values`` is the [S, R] input (None for count); ``mask`` an optional
     per-agg filter mask (filtered aggregations, reference
     FilteredAggregationSpec). ``is_int``/``maxabs`` are static metadata
@@ -97,6 +99,7 @@ class AggInput:
     is_int: bool = False
     maxabs: Optional[float] = None
     same_in_group: bool = False
+    log2m: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,7 +107,8 @@ class Route:
     """Static numeric route for one aggregation (see module docstring)."""
 
     name: str
-    kind: str                 # count|sum|min|max
+    kind: str                 # count|sum|min|max|hll (hashed tier: the
+    #                           finished estimate, one int32 a slot)
     tag: str                  # f64|i64|ff|lanes|limbs|i32|f32
     n_lanes: int = 1
     merged: bool = True       # device-collective merge vs per-chip host merge

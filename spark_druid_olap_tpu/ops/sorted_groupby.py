@@ -65,6 +65,23 @@ around ``block_until_ready``):
   were empty. At n = 6.0M, T = 2^14 the same search took 9.8 ms, the index
   way here 10.5 ms, carrying all six columns 33.3 ms (micro).
 
+- **HLL sketches** (kind ``hll``; PR 35) never become registers. The
+  core already orders rows by group: the sketch's coupon — ``register <<
+  b | rho`` over a few more bits of the hash
+  (``ops.hll.packed_registers``) — is a THIRD sort key, so inside a group's
+  run the rows of one register lie together with the largest rho last
+  and equal values side by side, and the group's ``sum of 2^-rho``, its
+  count of live registers and its count of distinct coupons are three
+  more prefix sums read at the run ends like any integer sum
+  (``ops.hll.run_sums``); the estimate is computed on the ``[T]`` table
+  (``ops.hll.estimate_sums``) and one int32 a slot travels. One sketch
+  rides the main sort; each further one pays a three-key sort of its own
+  — the groups' runs lie at the same rows in every such sort (the same
+  keys in the same order, whatever lies inside a run), so its three columns
+  are read at the main sort's run ends too. An estimate is final: nothing
+  merges two of them, so the executor runs such a program on one chip in
+  one wave.
+
 Outputs keep the hashed tier's existing contracts (``groupby.Route``
 outputs / ``combine_route`` / host key-wise merge): ``i32`` for counts
 and provably-in-range int sums, the new ``s64`` hi/lo pair for wide int
@@ -93,6 +110,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_druid_olap_tpu.ops import hash_groupby as H
+from spark_druid_olap_tpu.ops import hll as HLL
 from spark_druid_olap_tpu.ops.groupby import (
     AggInput,
     F32_MAX,
@@ -104,19 +122,21 @@ from spark_druid_olap_tpu.ops.groupby import (
     _x64,
 )
 
-SUPPORTED_KINDS = ("count", "sum", "min", "max")
+SUPPORTED_KINDS = ("count", "sum", "min", "max", "hll")
 
 
 def plan_sorted_routes(inputs: List[AggInput],
                        n_rows: Optional[int] = None) -> Optional[Dict[str, Route]]:
     """Routes for the sorted-run core, or None when some aggregation kind
-    is outside its reach (sketches -> caller keeps the scatter path).
+    is outside its reach (theta, KLL -> caller keeps the scatter path).
     Static — callable at plan time."""
     out: Dict[str, Route] = {}
     for a in inputs:
         if a.kind not in SUPPORTED_KINDS:
             return None
-        if a.kind in ("min", "max"):
+        if a.kind == "hll":
+            out[a.name] = Route(a.name, a.kind, "i32")
+        elif a.kind in ("min", "max"):
             if _x64():
                 out[a.name] = Route(a.name, a.kind,
                                     "i64" if a.is_int else "f64")
@@ -140,10 +160,23 @@ def plan_sorted_routes(inputs: List[AggInput],
     return out
 
 
-def _seg_scan(flag, vals, combine_vals):
+# Past this many rows a segmented scan runs as a loop of doubling shifts
+# (``_seg_scan_doubling``), not as ``associative_scan``'s unrolled tree:
+# the chip's compiler takes 1-2 min for a program with one such tree over
+# 2^20 rows (q3, q10: their late-materialized prefixes) and did not finish
+# one over 8.0 M rows in a quarter of an hour (PR 35; four of them: PR 33).
+_SCAN_TREE_MAX_ROWS = 1 << 21
+
+
+def _seg_scan(flag, vals, combine_vals, read=None):
     """Segmented scan: inclusive scan of ``vals`` that RESETS wherever
     ``flag`` is True (run starts). Classic associative segmented-scan
-    lifting: op((f1,v1),(f2,v2)) = (f1|f2, f2 ? v2 : combine(v1,v2))."""
+    lifting: op((f1,v1),(f2,v2)) = (f1|f2, f2 ? v2 : combine(v1,v2)).
+    ``read``: the rows whose result anyone reads (None: all of them);
+    the doubling form need not finish a run of the others."""
+    if flag.shape[0] > _SCAN_TREE_MAX_ROWS:
+        return _seg_scan_doubling(flag, vals, combine_vals, read)
+
     def op(a, b):
         fa, va = a[0], a[1:]
         fb, vb = b[0], b[1:]
@@ -155,6 +188,38 @@ def _seg_scan(flag, vals, combine_vals):
 
     res = jax.lax.associative_scan(op, (flag,) + tuple(vals))
     return res[1:]
+
+
+def _seg_scan_doubling(flag, vals, combine_vals, read=None):
+    """``_seg_scan``'s result by Hillis-Steele doubling: after the round
+    with shift ``d`` row ``i`` holds its run's rows ``(i - 2d, i]``
+    combined, so ``ceil(log2(longest run))`` rounds of one shifted,
+    masked ``combine`` each finish every run — a ``while_loop`` whose
+    body the compiler sees once, and whose trip count the data set: 5
+    rounds where the longest run has 30 rows, 23 for one run of 8.0 M.
+    The longest run is taken over the rows that are ``read``: the
+    sorted-run core's invalid and padding rows sort last as ONE run that
+    nobody reads (2.0 M of SF1's 8.0 M rows, more under a filter — 21
+    rounds by itself), and is left unfinished.
+    O(n log(longest run)) work where the tree does O(n); the same
+    associative ``combine`` over the same rows in the same order, grouped
+    differently (floats: the same compensated sum to its last few ulps)."""
+    n = flag.shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    start = jax.lax.cummax(jnp.where(flag, pos, 0))
+    length = pos - start if read is None else jnp.where(read, pos - start, 0)
+    longest = jnp.max(length) + 1
+
+    def body(carry):
+        d, vals = carry
+        # x[i - d] at i; what wraps around lies before its run's start
+        merged = combine_vals(tuple(jnp.roll(v, d) for v in vals), vals)
+        inside = pos - d >= start
+        return d * 2, tuple(jnp.where(inside, m, v)
+                            for m, v in zip(merged, vals))
+
+    return jax.lax.while_loop(lambda c: c[0] < longest, body,
+                              (jnp.int32(1), tuple(vals)))[1]
 
 
 def _two_sum(a, b):
@@ -252,9 +317,13 @@ def sorted_hash_groupby(khi, klo, valid, T: int, inputs: List[AggInput],
     # payloads: pre-masked per-agg value vectors (masking BEFORE the sort
     # keeps the per-agg filter masks off the sort operand list)
     payloads = []
+    packed = []
     for a in inputs:
         r = routes[a.name]
         am = base if a.mask is None else (base & a.mask.reshape(-1))
+        if a.kind == "hll":
+            packed.append(HLL.packed_registers(a.values, am, a.log2m))
+            continue
         if a.kind == "count":
             payloads.append(am.astype(jnp.int32))
             continue
@@ -271,9 +340,22 @@ def sorted_hash_groupby(khi, klo, valid, T: int, inputs: List[AggInput],
                 jnp.float64 if r.tag == "f64" else jnp.float32), 0.0)
         payloads.append(v)
 
-    ops = jax.lax.sort((khi_f, klo_f) + tuple(payloads), num_keys=2)
+    # the first sketch's packed registers are the main sort's third key;
+    # every further sketch sorts (group, its registers) by itself. No
+    # aggregation here reads the order of equal keys, and the chip's
+    # compiler takes under half as long for a sort that need not keep it
+    # (three keys, four operands, 8.0 M rows: 59 s against 127; PR 35):
+    # a program with a sketch asks for none (the others' sorts stay as
+    # the accepted cells compiled them)
+    riding = packed[:1]
+    ops = jax.lax.sort((khi_f, klo_f) + tuple(riding) + tuple(payloads),
+                       num_keys=2 + len(riding), is_stable=not riding)
     skh, skl = ops[0], ops[1]
     n = skh.shape[0]
+    sorted_packed = iter(list(ops[2: 2 + len(riding)]) + [
+        jax.lax.sort((khi_f, klo_f, s), num_keys=3, is_stable=False)[2]
+        for s in packed[1:]])
+    values = iter(ops[2 + len(riding):])
 
     new = (skh != jnp.roll(skh, 1)) | (skl != jnp.roll(skl, 1))
     new = new.at[0].set(True)
@@ -282,15 +364,21 @@ def sorted_hash_groupby(khi, klo, valid, T: int, inputs: List[AggInput],
     unresolved = jnp.sum((occupied_row & (gid >= T)).astype(jnp.int32))
     # a row is the LAST of its run iff the next row starts one: no search.
     # Invalid rows sort last as one trailing pseudo-group and are not kept.
-    keep = jnp.roll(new, -1).at[n - 1].set(True) & occupied_row
+    run_end = jnp.roll(new, -1).at[n - 1].set(True)
+    keep = run_end & occupied_row
 
     # per-row scanned columns; a group's final sits at its run-last row.
     # ``plan`` remembers which table columns each aggregation reads.
     cols = [skh, skl]
     plan = []
-    for a, v in zip(inputs, ops[2:]):
+    for a in inputs:
         r = routes[a.name]
         at = len(cols)
+        if a.kind == "hll":
+            cols += HLL.run_sums(next(sorted_packed), run_end, a.log2m)
+            plan.append((a, r, "hll", at))
+            continue
+        v = next(values)
         if a.kind in ("min", "max"):
             if a.same_in_group:
                 # the run's rows agree: its last row holds the final
@@ -298,7 +386,8 @@ def sorted_hash_groupby(khi, klo, valid, T: int, inputs: List[AggInput],
             else:
                 pick = jnp.minimum if a.kind == "min" else jnp.maximum
                 cols += _seg_scan(
-                    new, (v,), lambda x, y, pick=pick: (pick(x[0], y[0]),))
+                    new, (v,), lambda x, y, pick=pick: (pick(x[0], y[0]),),
+                    occupied_row)
             how = "final"
         elif r.tag == "i32":
             # wrap-exact mod 2^32: per-group totals fit i32 by the route
@@ -313,7 +402,8 @@ def sorted_hash_groupby(khi, klo, valid, T: int, inputs: List[AggInput],
             cols += _cumsum64(v.astype(jnp.int32))
             how = "diff64"
         elif r.tag == "f64":
-            cols += _seg_scan(new, (v,), lambda x, y: (x[0] + y[0],))
+            cols += _seg_scan(new, (v,), lambda x, y: (x[0] + y[0],),
+                              occupied_row)
             how = "final"
         else:
             # float sums: segmented COMPENSATED scan — (sum, err) pairs
@@ -322,7 +412,8 @@ def sorted_hash_groupby(khi, klo, valid, T: int, inputs: List[AggInput],
             def comb(xa, xb):
                 s, e = _two_sum(xa[0], xb[0])
                 return (s, e + xa[1] + xb[1])
-            cols += _seg_scan(new, (v, jnp.zeros_like(v)), comb)
+            cols += _seg_scan(new, (v, jnp.zeros_like(v)), comb,
+                              occupied_row)
             how = "ff"
         plan.append((a, r, how, at))
 
@@ -339,6 +430,10 @@ def sorted_hash_groupby(khi, klo, valid, T: int, inputs: List[AggInput],
         elif how == "diff":
             # cumulative value at this run's end minus the previous run's
             out[r.name] = jnp.where(g_occ, tab[at] - _shift1(tab[at]), 0)
+        elif how == "hll":
+            out[r.name] = jnp.where(g_occ, HLL.estimate_sums(
+                *(tab[at + i] - _shift1(tab[at + i]) for i in range(3)),
+                a.log2m), 0)
         elif how == "diff64":
             thi, tlo = _sub64(tab[at], tab[at + 1],
                               _shift1(tab[at]), _shift1(tab[at + 1]))

@@ -24,7 +24,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import time as _time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -703,6 +703,27 @@ def _dim_parses_numeric(ds: Datasource, field: str) -> bool:
 # the engine
 # =============================================================================
 
+class HashLayout(NamedTuple):
+    """``QueryEngine._hash_layout``: a statement's layout in the hashed
+    tier — the key's parts, the table's slots and their ceiling, whether
+    and over how many chips the scan is sharded, a segment's bytes,
+    segments a wave and waves."""
+
+    parts: List[List[int]]
+    T: int
+    max_slots: int
+    sharded: bool
+    n_dev: int
+    seg_bytes: int
+    spw: int
+    n_waves: int
+
+    @property
+    def one_table(self) -> bool:
+        """Every row of a group reaches ONE table: one wave on one chip."""
+        return self.n_dev == 1 and self.n_waves == 1
+
+
 class QueryEngine:
     def __init__(self, store: SegmentStore, config: Optional[Config] = None,
                  mesh: Optional[Mesh] = None):
@@ -1219,10 +1240,24 @@ class QueryEngine:
             from spark_druid_olap_tpu.utils import config as CF
             min_k = int(self.config.get(CF.GROUPBY_SORTED_MIN_KEYS))
             if min_k > 0 and n_keys >= min_k \
-                    and not any(p.kind in ("hll", "theta", "kll")
-                                for p in agg_plans) \
-                    and self._sorted_run_wanted():
-                route_hashed = True
+                    and not any(p.kind in ("theta", "kll")
+                                for p in agg_plans):
+                if any(p.kind == "hll" for p in agg_plans):
+                    # an HLL sketch goes where its registers' form runs:
+                    # the sparse one in the hashed tier (the sorted-run
+                    # core has the rows sorted by group already, the
+                    # scatter core sorts them by slot), where it is the
+                    # cheapest — or the block past what a send may fetch
+                    # — AND that tier would scan in one wave on one chip:
+                    # an estimate is final, and several waves' or chips'
+                    # registers only a dense form can merge
+                    route_hashed = self._hll_form(
+                        agg_plans, ds, len(seg_idx), n_keys,
+                        self._hash_layout(
+                            q, ds, seg_idx, all_dim_plans, agg_plans,
+                            names).one_table) == "sparse"
+                else:
+                    route_hashed = self._sorted_run_wanted()
         if route_hashed:
             return self._run_agg_hashed(
                 q, ds, seg_idx, all_dim_plans, agg_plans, names, min_day,
@@ -1288,6 +1323,11 @@ class QueryEngine:
                 lambda: self._build_agg_gather_program(
                     agg_plans, routes, n_out, n_keys, sharded, full=full))
             out = self._run_program(gfn, table, unpackB)
+            if sketch_plans:
+                self.last_stats.update({
+                    "sketch_fetch_bytes":
+                        4 * _sketch_words(out, sketch_plans),
+                    "sketch_groups": int(n_out)})
             finals = _finals_from_out(out, routes, n_out, sketch_plans)
             if not full:
                 top_idx = np.asarray(out["__topk_idx__"]) \
@@ -1344,7 +1384,6 @@ class QueryEngine:
             result, n_groups = self._decode_dense(
                 ds, all_dim_plans, agg_plans, routes, finals, gran_kind,
                 post_aggregations, having, limit, top_idx)
-
         if topk and not isinstance(q, S.TopNQuerySpec):
             # exact-contract GroupBy: the candidate selection is
             # f32-approximate — prove the boundary row clears the cutoff
@@ -1406,13 +1445,9 @@ class QueryEngine:
                 # (cluster/merge.py) — that is what makes the distributed
                 # estimate EQUAL the single-engine one, not merely close
                 data[name] = np.asarray(finals[name])[sel]
-            elif p.kind == "kll":
-                data[name] = KLL.estimate(finals[name],
-                                          p.spec.fraction or 0.5)[sel]
             else:
-                est = (HLL.estimate(finals[name]) if p.kind == "hll"
-                       else TH.estimate(finals[name]))[sel]
-                data[name] = np.round(est).astype(np.int64)
+                with PH.phase("sketch"):
+                    data[name] = _sketch_column(p, finals[name], sel)
         if global_empty:
             data.update(_identity_row(
                 {p.spec.name: p.kind for p in agg_plans
@@ -1775,18 +1810,11 @@ class QueryEngine:
         return data
 
     # -- hashed high-cardinality aggregation path -----------------------------
-    def _run_agg_hashed(self, q, ds, seg_idx, dim_plans, agg_plans, names,
-                        min_day, max_day, post_aggregations, having, limit,
-                        filter_spec, intervals, t0, no_topk: bool = False,
-                        *, lits, days):
-        """Group-by above the dense key-space ceiling: fixed-size device hash
-        table per chip/wave (ops/hash_groupby.py), partials merged by *key*
-        on host. Table overflow retries at 4x slots, then falls back.
-        ≈ Druid groupBy v2 never refusing on cardinality
-        (DruidQuerySpec.scala:558-571)."""
-        if any(p.kind in ("hll", "theta", "kll") for p in agg_plans):
-            raise EngineFallback(
-                "sketch aggregation over hashed group-by")
+    def _hash_layout(self, q, ds, seg_idx, dim_plans, agg_plans, names):
+        """How the hashed tier would lay a statement out, before anything
+        is built: key parts, table slots and their ceiling, chips, waves
+        (``HashLayout``). ``_run_agg`` reads it to decide an HLL
+        statement's tier ONCE; ``_run_agg_hashed`` runs by it."""
         cards = [p.card for p in dim_plans]
         try:
             parts = H.split_parts(cards)
@@ -1827,6 +1855,41 @@ class QueryEngine:
             min(rows_sel, T), len(agg_plans),
             io_budget=C.tier_io_budget(ds, self.config),
             io_seg_bytes=C.tier_io_seg_bytes(ds, names))
+        return HashLayout(parts, T, max_slots, sharded, n_dev, seg_bytes,
+                          spw, n_waves)
+
+    def _run_agg_hashed(self, q, ds, seg_idx, dim_plans, agg_plans, names,
+                        min_day, max_day, post_aggregations, having, limit,
+                        filter_spec, intervals, t0, no_topk: bool = False,
+                        *, lits, days):
+        """Group-by above the dense key-space ceiling: fixed-size device hash
+        table per chip/wave (ops/hash_groupby.py), partials merged by *key*
+        on host. Table overflow retries at 4x slots, then falls back.
+        ≈ Druid groupBy v2 never refusing on cardinality
+        (DruidQuerySpec.scala:558-571).
+
+        An HLL sketch runs here in the sparse form only (a table of T
+        slots has no ``[T, 2^log2m]`` block to give): one finished
+        estimate a slot, which no merge by key can combine, so the scan
+        must be one wave on one chip and no historical's. The medium-K
+        reroute sends no other here (``_run_agg``); a statement past the
+        dense tier's key space has no other tier and falls back."""
+        if any(p.kind in ("theta", "kll") for p in agg_plans):
+            raise EngineFallback(
+                "theta / kll sketch aggregation over hashed group-by")
+        has_hll = any(p.kind == "hll" for p in agg_plans)
+        if has_hll and self.partial_sketches:
+            # a historical ships raw registers for its broker to merge
+            # (_decode_dense): only the dense forms have them
+            raise EngineFallback(
+                "partial HLL registers over hashed group-by")
+        cards = [p.card for p in dim_plans]
+        lay = self._hash_layout(q, ds, seg_idx, dim_plans, agg_plans, names)
+        if has_hll and not lay.one_table:
+            raise EngineFallback(
+                "HLL sketch over a hashed group-by of several waves "
+                "or chips")
+        parts, T, max_slots, sharded, n_dev, seg_bytes, spw, n_waves = lay
         s_pad = spw if n_waves > 1 else _pad_segments(len(seg_idx), n_dev)
         n_seg_sel = len(seg_idx)
         multihost = sharded and MH.is_multihost()
@@ -1835,11 +1898,13 @@ class QueryEngine:
                 ds, seg_idx, n_waves, seg_bytes)
         wave_segs = [seg_idx[i: i + s_pad]
                      for i in range(0, len(seg_idx), s_pad)]
+        log2m = self.config.get(HLL_LOG2M)
 
         # no '__rows__' occupancy count here: occupied slots are read off
         # the key table (khi != EMPTY) directly
         metas = [G.AggInput(p.spec.name, p.kind, is_int=p.is_int,
-                            maxabs=p.maxabs) for p in agg_plans]
+                            maxabs=p.maxabs, log2m=log2m)
+                 for p in agg_plans]
         topk_plan = self._plan_device_topk_hashed(limit, having, agg_plans,
                                                   n_dev, n_waves) \
             if not no_topk else None
@@ -1893,8 +1958,13 @@ class QueryEngine:
                     sorted_run = True
             if not sorted_run:
                 routes = G.plan_routes(
-                    metas, T, self.config.get(GROUPBY_MATMUL_MAX_KEYS),
+                    [a for a in metas if a.kind != "hll"], T,
+                    self.config.get(GROUPBY_MATMUL_MAX_KEYS),
                     n_rows=n_rows_dev)
+                # (plan_routes knows the dense tier's kinds; in
+                # agg_plans' order, which the packers follow)
+                routes = {a.name: routes.get(a.name)
+                          or G.Route(a.name, "hll", "i32") for a in metas}
             # HAVING on the device-resident table (the dense tier's
             # transfer filter, _plan_device_having): one chip, one wave
             # — a partial table's totals are not the group's — and an
@@ -1928,6 +1998,7 @@ class QueryEngine:
             prog, late = self._cached_program(sig, build)
 
             partials, unresolved, kg_used, tk_scores = [], 0, 0, None
+            sk_words = 0    # sketch estimates among what was copied back
             # (without a budget no survivors are counted)
             n_live, budget = 0, late.m if late else 0
             # the table form's program has no unpack: its table stays on
@@ -1944,6 +2015,7 @@ class QueryEngine:
                         break
                     if topk:
                         tk_scores = raw.pop("__topk_score__")
+                    sk_words += _sketch_words(raw, agg_plans)
                     partials.extend(
                         _hash_chip_partials(raw, routes, k_out, n_dev))
                     continue
@@ -1985,10 +2057,11 @@ class QueryEngine:
                             having_dev))
                 kg_used = max(kg_used, kg)
                 raw = self._run_program(gfn, table, unpackB)
+                sk_words += _sketch_words(raw, agg_plans)
                 partials.extend(_hash_chip_partials(raw, routes, kg, n_dev))
             return unresolved, n_live, (
                 T, routes, topk, exch, having_dev, sorted_run, partials,
-                kg_used, tk_scores, late)
+                kg_used, tk_scores, sk_words, late)
 
         def run(late_key):
             # the table follows the budget; its overflow retries at 4x
@@ -2007,7 +2080,7 @@ class QueryEngine:
                         f"hashed group-by exceeded {max_slots} table slots")
 
         (T, routes, topk, exch, having_dev, sorted_run, partials, kg_used,
-         tk_scores, late) = self._run_budgeted(
+         tk_scores, sk_words, late) = self._run_budgeted(
             shape, plan, run,
             count=None if sharded or n_waves > 1 else
             lambda: self._count_survivors(
@@ -2033,11 +2106,27 @@ class QueryEngine:
                 columns.append(p.output_name)
             for p in agg_plans:
                 name = p.spec.name
-                data[name] = _decode_agg_value(ds, p, routes[name],
-                                               merged[name])
+                if p.kind == "hll":
+                    # one partial (one chip, one wave): merged as it came
+                    with PH.phase("sketch"):
+                        data[name] = _sketch_column(p, merged[name],
+                                                    slice(None))
+                else:
+                    data[name] = _decode_agg_value(ds, p, routes[name],
+                                                   merged[name])
                 columns.append(name)
             data = self._agg_epilogue(data, columns, post_aggregations,
                                       having, limit)
+        if has_hll:
+            # live (group, register) pairs the table can hold: a row each
+            rows = late.m if late \
+                else int(s_pad // n_dev) * int(ds.padded_rows)
+            self.last_stats.update({
+                "hll_form": "sparse",
+                "hll_slots": min(rows, T << log2m),
+                "sketch_fetch_bytes": 4 * sk_words,
+                "sketch_groups": sk_words // sum(
+                    p.kind == "hll" for p in agg_plans)})
 
         if topk and tk_scores is not None \
                 and not isinstance(q, S.TopNQuerySpec):
@@ -2089,7 +2178,7 @@ class QueryEngine:
             return None
         oc = limit.columns[0]
         mplan = next((p for p in agg_plans if p.spec.name == oc.name), None)
-        if mplan is None or mplan.dim_codes:
+        if mplan is None or mplan.dim_codes or mplan.kind == "hll":
             return None
         if n_dev != 1 or n_waves != 1:
             return None
@@ -2137,6 +2226,7 @@ class QueryEngine:
         survivor count travels as '__live__' (shape [1]) beside
         '__unres__', which stays the table's own."""
         matmul_max = self.config.get(GROUPBY_MATMUL_MAX_KEYS)
+        log2m = self.config.get(HLL_LOG2M)
         cards = [p.card for p in dim_plans]
         cheap_f, exp_f = (self._split_filter_staged(filter_spec)
                           if compact else (filter_spec, None))
@@ -2172,7 +2262,7 @@ class QueryEngine:
                     p.spec.name, p.kind, p.build_values(ctx), mask,
                     is_int=p.is_int, maxabs=p.maxabs,
                     same_in_group=p.spec.kind == "anyvalue"
-                    and mask is None))
+                    and mask is None, log2m=log2m))
             if sorted_run:
                 # sorted-run tier: the slot sort rides the agg values as
                 # payloads; prefix scans + run-boundary reads replace
@@ -2181,8 +2271,17 @@ class QueryEngine:
                                               routes)
             slot, tk_hi, tk_lo, unresolved = H.build_slots(
                 khi, klo, base, T)
-            out = G.dense_groupby(slot, base, T, inputs, routes,
-                                  matmul_max)
+            out = G.dense_groupby(
+                slot, base, T, [a for a in inputs if a.kind != "hll"],
+                routes, matmul_max)
+            for a in inputs:
+                if a.kind == "hll":
+                    # the slot is a dense group key: the dense tier's
+                    # sparse form, T groups wide
+                    live = base & (slot < T)
+                    out[a.name] = HLL.hll_estimates(
+                        slot, live if a.mask is None else live & a.mask,
+                        a.values, T, log2m)
             out["__tkhi__"] = tk_hi
             out["__tklo__"] = tk_lo
             out["__unres__"] = unresolved.reshape(1)
@@ -2558,9 +2657,15 @@ class QueryEngine:
         (DruidQueryCostModel.scala:309-314,444)."""
         wave_segs = [seg_idx[i: i + s_pad]
                      for i in range(0, len(seg_idx), s_pad)]
-        finals, n_live = None, 0
+        finals, n_live, sk_words = None, 0, 0
         for out in self._waves(q, t0, ds, names, wave_segs, s_pad, sharded,
                                lits, prog_fn, unpack):
+            if sketch_plans:
+                # (a wave's groups travel again with every wave)
+                sk_words += _sketch_words(out, sketch_plans)
+                self.last_stats.update({
+                    "sketch_fetch_bytes": 4 * sk_words,
+                    "sketch_groups": int(n_out)})
             if budget:
                 n_live = max(n_live, int(
                     np.asarray(out.pop("__live__")).reshape(-1)[0]))
@@ -2664,8 +2769,9 @@ class QueryEngine:
         late materialization between the cheap filter and everything
         after it; its survivor count surfaces as '__live__' and the host
         runs a statement whose budget it exceeds again. ``hll_costs``:
-        the unit costs an HLL aggregation's registers choose their form
-        under (``_hll_costs``).
+        the unit costs an HLL aggregation's registers choose their dense
+        form under (``_hll_costs``; the sparse form is the hashed
+        tier's, ``_hash_core``).
         ``notes``: a dict the trace fills with what the statement record
         says of the program's sketch epilogue (``hll_form``,
         ``hll_slots``)."""
@@ -2891,6 +2997,24 @@ class QueryEngine:
             C.unit_cost(self.config, CF.COST_SORT_ROW),
             C.unit_cost(self.config, CF.COST_GATHER_PROBE),
             C.unit_cost(self.config, CF.COST_SCATTER_UPDATE))
+
+    def _hll_form(self, agg_plans, ds, n_seg, n_keys, one_table):
+        """The form a statement of ``n_keys`` groups over ``n_seg``
+        segments should take its HLL registers in
+        (``ops.hll.register_form``), None without an HLL aggregation:
+        what the medium-K reroute asks, the hashed tier being where the
+        sparse form runs. ``one_table``: every row of a group would
+        reach ONE table there (``HashLayout.one_table``) — what the
+        sparse form needs, its estimates being final; a historical's
+        partial registers never are. Priced on the scanned rows,
+        whatever late materialization leaves of them."""
+        costs = self._hll_costs(agg_plans)
+        if costs is None:
+            return None
+        return HLL.register_form(
+            int(n_seg) * int(ds.padded_rows), n_keys,
+            self.config.get(HLL_LOG2M), costs,
+            sparse_ok=one_table and not self.partial_sketches)
 
     def _cached_program(self, sig, build):
         """Program-cache fetch with PER-SIGNATURE compile ownership: warm
@@ -4058,7 +4182,9 @@ def _hash_chip_partials(raw, routes, T, n_dev):
 def _merge_hash_partials(parts, routes):
     """Merge per-chip/per-wave hash-table partials by key on host (≈ the
     broker-side merge of historical partials). Sums/counts add exactly
-    (i64/f64 finals), min/max keep sentinels."""
+    (i64/f64 finals), min/max keep sentinels. An 'hll' route's
+    estimates are final and arrive in ONE partial (_run_agg_hashed
+    refuses more): a key's sum is its one value."""
     if not parts:
         empty = {name: np.zeros(0, np.float64) for name in routes}
         return np.zeros(0, np.int64), empty
@@ -4086,6 +4212,29 @@ def _merge_hash_partials(parts, routes):
             np.add.at(acc, inv, segs.astype(dt))
         merged[name] = acc
     return uniq, merged
+
+
+def _sketch_words(out, agg_plans):
+    """The 4-byte words of sketch state — registers, or the sparse
+    form's estimates — among one program's unpacked outputs ``out``:
+    counted where they were copied back, as ``fetch_bytes`` is."""
+    return sum(int(np.size(out[p.spec.name])) for p in agg_plans
+               if p.kind in ("hll", "theta", "kll"))
+
+
+def _sketch_column(p, state, sel):
+    """What is left of a sketch on the host, the ``sketch`` span: the
+    selected groups' estimates from the state a program shipped — a
+    dense ``[groups, width]`` register block, estimated here in float64,
+    or, from a program that took its HLL registers in the sparse form
+    (``ops.hll.hll_estimates``), the finished estimates, one a group."""
+    if p.kind == "kll":
+        return KLL.estimate(state, p.spec.fraction or 0.5)[sel]
+    if state.ndim == 1:
+        return state[sel].astype(np.int64)
+    est = (HLL.estimate(state) if p.kind == "hll"
+           else TH.estimate(state))[sel]
+    return np.round(est).astype(np.int64)
 
 
 def _finals_from_out(out, routes, n_keys, sketch_plans):

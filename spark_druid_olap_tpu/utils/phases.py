@@ -116,6 +116,7 @@ PHASES = {
     "merge": "hashed tier: cross-wave / cross-chip partial merge on host",
     "decode": "solo finals -> QueryResult (dictionary decode, epilogue)",
     "demux": "shared-scan per-lane demux/decode",
+    "sketch": "a sketch column's host part: estimate of what was fetched",
     "result": "engine results -> the statement's frame (host finish)",
     "epilogue": "window post-pass and result epilogue",
 }

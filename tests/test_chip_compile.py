@@ -307,3 +307,28 @@ def test_literal_operand_program_compiles(chip_mode, one_chip, store):
     text = compiled.as_text()
     assert f"s32[1,{words.shape[1]}]" in text, "no literal operand"
     _assert_kernel(compiled, "sdot_dense_groupby")
+
+
+# -- the sorted-run core's segmented scan past the tree's row limit (PR 35) ---
+
+def test_segmented_scan_over_sf1_rows_compiles_as_a_loop(chip_mode, one_chip):
+    """A float sum over the 8.0 M rows of an SF1 scan (the sketch cell's
+    ``sum(revenue)`` beside its HLL columns): ``associative_scan``'s
+    unrolled tree did not compile for the chip in a quarter of an hour at
+    that size, so past ``_SCAN_TREE_MAX_ROWS`` the scan is a ``while`` of
+    doubling shifts, which the chip's compiler takes in seconds."""
+    from spark_druid_olap_tpu.ops import sorted_groupby as SG
+    n = 8 * 1_000_448
+    assert n > SG._SCAN_TREE_MAX_ROWS
+
+    def comb(xa, xb):
+        s, e = SG._two_sum(xa[0], xb[0])
+        return (s, e + xa[1] + xb[1])
+
+    def scan(flag, v):
+        return SG._seg_scan(flag, (v, jnp.zeros_like(v)), comb)
+
+    compiled = jax.jit(scan).lower(
+        jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip),
+        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)).compile()
+    assert " while(" in compiled.as_text()

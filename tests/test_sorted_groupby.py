@@ -240,7 +240,14 @@ def test_medium_k_reroutes_to_sorted_run_and_matches():
                                   atol=1e-9)
 
 
-def test_medium_k_reroute_skips_sketches():
+@pytest.mark.parametrize("fn,hashed", [
+    ("approx_count_distinct_theta", False), ("approx_count_distinct", True)])
+def test_medium_k_reroute_skips_sketches(fn, hashed):
+    """theta (and KLL) keep the dense tier: the sorted-run core has no
+    route for them. An HLL sketch goes along since PR 35 where the dense
+    tier would itself take the sparse form (3,001 groups' register block
+    is past ``ops.hll.DENSE_BLOCK_MAX_BYTES``): the core has the rows
+    sorted by group already, the register is one more sort key."""
     df = _frame(n=20_000, seed=22, n_keys=3000)
     ctx = sdot.Context(config={
         "sdot.engine.groupby.sorted.min.keys": 1024,
@@ -248,11 +255,85 @@ def test_medium_k_reroute_skips_sketches():
         "sdot.querycostmodel.scatter.seconds.per.update": 1e-8,
     })
     ctx.ingest_dataframe("t", df)
-    r = ctx.sql("select k, approx_count_distinct(flag) as d from t "
+    r = ctx.sql(f"select k, {fn}(q) as d from t "
                 "group by k order by k").to_pandas()
     st = ctx.history.entries()[-1].stats
-    assert st["mode"] == "engine" and not st.get("hashed"), st
-    assert len(r) == df.k.nunique()
+    assert st["mode"] == "engine" and bool(st.get("hashed")) == hashed, st
+    assert st.get("hll_form") == ("sparse" if hashed else None)
+    want = df.groupby("k").q.nunique().sort_index()
+    assert r.k.tolist() == want.index.tolist()
+    # (50 values over 2^11 registers; theta's k-min sketch is coarser)
+    assert np.abs(r.d.to_numpy() - want.to_numpy()).max() \
+        <= (1 if hashed else 5)
+
+
+def test_segmented_scan_by_doubling_is_the_tree_scan():
+    """Past ``_SCAN_TREE_MAX_ROWS`` a segmented scan runs as a loop of
+    doubling shifts: the same combine over the same runs — singletons, a
+    run of every length up to one of 3,000 rows, the compensated float sum
+    and a max."""
+    rng = np.random.default_rng(35)
+    lengths = np.concatenate([np.ones(200, np.int64), np.arange(1, 60),
+                              [3000, 1, 2, 777]])
+    rng.shuffle(lengths)
+    n = int(lengths.sum())
+    flag = np.zeros(n, bool)
+    flag[np.concatenate([[0], np.cumsum(lengths)[:-1]])] = True
+    v = jnp.asarray((rng.random(n) * 1e5).astype(np.float32))
+    flag = jnp.asarray(flag)
+
+    def comb(xa, xb):
+        s, e = SG._two_sum(xa[0], xb[0])
+        return (s, e + xa[1] + xb[1])
+
+    def total(pair):
+        return np.asarray(pair[0], np.float64) + np.asarray(pair[1],
+                                                            np.float64)
+
+    assert n <= SG._SCAN_TREE_MAX_ROWS
+    tree = SG._seg_scan(flag, (v, jnp.zeros_like(v)), comb)
+    loop = jax.jit(lambda f, x: SG._seg_scan_doubling(
+        f, (x, jnp.zeros_like(x)), comb))(flag, v)
+    want = np.concatenate([np.cumsum(c) for c in np.split(
+        np.asarray(v, np.float64), np.cumsum(lengths)[:-1])])
+    np.testing.assert_allclose(total(loop), want, rtol=1e-12)
+    np.testing.assert_allclose(total(loop), total(tree), rtol=1e-12)
+    pick = lambda x, y: (jnp.maximum(x[0], y[0]),)      # noqa: E731
+    assert np.array_equal(
+        np.asarray(SG._seg_scan_doubling(flag, (v,), pick)[0]),
+        np.asarray(SG._seg_scan(flag, (v,), pick)[0]))
+
+
+def test_doubling_scan_stops_at_the_longest_run_that_is_read():
+    """The core's invalid rows sort last as one long run nobody reads:
+    the doubling form's trip count follows the runs that ARE read, whose
+    results are the tree's; the unread run is left unfinished."""
+    lengths = np.array([3, 1, 30, 7, 5000])
+    n = int(lengths.sum())
+    flag = np.zeros(n, bool)
+    flag[np.concatenate([[0], np.cumsum(lengths)[:-1]])] = True
+    read = np.arange(n) < n - 5000
+    v = jnp.ones(n, jnp.int32)
+    add = lambda x, y: (x[0] + y[0],)      # noqa: E731
+    want = np.asarray(SG._seg_scan(jnp.asarray(flag), (v,), add)[0])
+    got = np.asarray(SG._seg_scan_doubling(
+        jnp.asarray(flag), (v,), add, jnp.asarray(read))[0])
+    assert np.array_equal(got[read], want[read])
+    # 5 rounds for the 30-row run: the 5,000-row run got as far as 32
+    assert got[~read].max() == 32 and want[~read].max() == 5000
+    assert np.array_equal(np.asarray(SG._seg_scan_doubling(
+        jnp.asarray(flag), (v,), add)[0]), want)
+
+
+def test_sorted_run_core_past_the_tree_scans_row_limit(monkeypatch):
+    """The whole core with every segmented scan in the doubling form."""
+    df = _frame(n=30_000, seed=36)
+    want = _run(HASHED_CONF, df)
+    monkeypatch.setattr(SG, "_SCAN_TREE_MAX_ROWS", 0)
+    # (another padded_rows: a program of its own, traced under the patch)
+    got = _run({**HASHED_CONF, "sdot.segment.target.rows": 1 << 13}, df)
+    pd.testing.assert_frame_equal(got, want, check_dtype=False, rtol=1e-9,
+                                  atol=1e-9)
 
 
 # -- the finals stage: run-last rows to the table by one compaction sort -----
