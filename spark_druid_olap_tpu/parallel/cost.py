@@ -535,15 +535,20 @@ def _explain_scan_plan(ctx, q: S.QuerySpec) -> str:
     """Physical scan decisions: late-materialization budget and staged
     (post-compaction) filter conjuncts — the explain surface for the
     compact-then-aggregate path."""
+    from spark_druid_olap_tpu.parallel import executor as X
     eng = ctx.engine
     f = getattr(q, "filter", None)
     ds = eng.store.get(q.datasource)
     seg_idx = ds.prune_segments(getattr(q, "intervals", None), f)
-    cheap, exp = eng._split_filter_staged(f)
+    cheap, exp = X._compaction_filters(f)
     shape = _seen_compact_shape(eng, q, ds, seg_idx)
     m = eng._plan_compact_m(ds, seg_idx, cheap, sharded=False, shape=shape)
     if m is None:
         return ""
+    if shape is None and X._estimate_blind(cheap, ds):
+        # no estimate prices this mask: its first run counts it
+        return ("\nscan: late-materialize, the budget counted at the "
+                "first run")
     # ESTIMATE: the execution-time decision additionally sees the agg
     # routes ('ffl' Pallas ceiling), sharding, and overflow memory —
     # none of which exist at explain time (ADVICE r3). OBSERVED: a

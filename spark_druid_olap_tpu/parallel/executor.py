@@ -1338,10 +1338,10 @@ class QueryEngine:
             # late materialization: the compact block runs INSIDE each
             # wave's program under a per-wave survivor budget (the first
             # wave's rows stand in for all — waves are equal-sized
-            # splits), priced from the CHEAP conjuncts only: staged
+            # splits), priced from the compaction mask only: staged
             # gather-heavy conjuncts apply after compaction and don't
             # shrink what the prefix must hold
-            cheap_f0, _ = self._split_filter_staged(filter_spec)
+            cheap_f0, _ = _compaction_filters(filter_spec)
             shape = self._compact_shape("agg", ds, lits, s_pad, days,
                                         sharded, n_dev, names)
 
@@ -1374,7 +1374,7 @@ class QueryEngine:
                 count=None if sharded or n_waves > 1 else
                 lambda: self._count_survivors(
                     q, t0, ds, names, seg_idx, s_pad, lits, cheap_f0,
-                    intervals, days))
+                    intervals, days), staged=_all_staged(cheap_f0))
             if topk:
                 top_idx = np.asarray(out["__topk_idx__"]).astype(np.int64)
         if t0 is not None:
@@ -1580,9 +1580,9 @@ class QueryEngine:
         if min_rows > 0 and rows < min_rows:
             return None                  # small scans: the sort wins nothing
         seen = self._compact_seen.get(shape)
-        if seen is None:
-            sel = C._filter_selectivity(filter_spec, ds)
-            m = _budget_for(rows * sel)
+        if seen is None:        # a blind estimate leaves it to the count
+            m = _budget_for(0 if _estimate_blind(filter_spec, ds) else
+                            rows * C._filter_selectivity(filter_spec, ds))
         else:
             m = seen[1]
         m = max(m, 1 << 15) if rows >= (1 << 21) else m
@@ -1645,7 +1645,7 @@ class QueryEngine:
                 C.unit_cost(self.config, CF.COST_SORT_PAYLOAD_ROW),
                 C.unit_cost(self.config, CF.COST_GATHER_PROBE))
 
-    def _run_budgeted(self, shape, plan, run, count=None):
+    def _run_budgeted(self, shape, plan, run, count=None, staged=False):
         """Late materialization's budget protocol, the dense and the
         hashed tier's alike. ``plan()`` is the tier's ``_plan_compact_m``
         for ``shape`` — the program's signature without its budget and
@@ -1663,8 +1663,8 @@ class QueryEngine:
         dropped rows: ``compact_overflow``) is run again under the
         budget the count asks for, or uncompacted where
         ``_plan_compact_m``'s exits say so, and the SHAPE goes straight
-        there from then on. The record says how full the budget ran
-        (``compact_live``) and where it came from (``compact_from``)."""
+        there from then on. The record says how full the budget ran, from
+        where, on which mask (``compact_live``, ``_from``, ``_mask``)."""
         with PH.phase("plan.engine"):
             m = plan()
             observed = shape in self._compact_seen
@@ -1683,7 +1683,8 @@ class QueryEngine:
         self.last_stats.update({
             "compact_m": compact.m, "compact_carry": compact.carry,
             "compact_cols": compact.cols, "compact_live": n_live,
-            "compact_from": "observed" if observed else "estimate"})
+            "compact_from": "observed" if observed else "estimate",
+            "compact_mask": "staged" if staged else "cheap"})
         return result
 
     def _note_survivors(self, shape, m, n_live):
@@ -1709,11 +1710,10 @@ class QueryEngine:
 
     def _count_survivors(self, q, t0, ds, names, seg_idx, s_pad, lits,
                          cheap_f, intervals, days):
-        """How many rows the cheap filter keeps, by a program that does
-        nothing else (no sort: it compiles in seconds where a compacting
-        program takes minutes): the first sight of a compacting shape,
-        once a shape a process. One chip, one wave; binds the statement's
-        own arrays, which its scan program then finds resident."""
+        """How many rows the compaction mask keeps, by a program doing
+        nothing else (no sort: seconds to compile, where a compacting one
+        takes minutes): once a shape a process, at its first sight. One
+        chip, one wave; binds the arrays its scan program then finds."""
         min_day, max_day = days or (None, None)
 
         def build():
@@ -1921,7 +1921,7 @@ class QueryEngine:
         # survivor budget (_run_budgeted), and at most that many rows
         # reach the table, so the budget bounds the table as truly as
         # min(key space, selected rows) does
-        cheap_f0, _ = self._split_filter_staged(filter_spec)
+        cheap_f0, _ = _compaction_filters(filter_spec)
         shape = self._compact_shape("hashagg", ds, lits, s_pad, days,
                                     sharded, n_dev, names)
         T_full, fixed_T = T, bool(self.config.get(GROUPBY_HASH_SLOTS))
@@ -2085,7 +2085,7 @@ class QueryEngine:
             count=None if sharded or n_waves > 1 else
             lambda: self._count_survivors(
                 q, t0, ds, names, seg_idx, s_pad, lits, cheap_f0,
-                intervals, days))
+                intervals, days), staged=_all_staged(cheap_f0))
         if t0 is not None:
             self._stage_check(q, t0)
 
@@ -2228,7 +2228,7 @@ class QueryEngine:
         matmul_max = self.config.get(GROUPBY_MATMUL_MAX_KEYS)
         log2m = self.config.get(HLL_LOG2M)
         cards = [p.card for p in dim_plans]
-        cheap_f, exp_f = (self._split_filter_staged(filter_spec)
+        cheap_f, exp_f = (_compaction_filters(filter_spec)
                           if compact else (filter_spec, None))
         fuse_cse = bool(self.config.get(SHAREDSCAN_FUSION_ENABLED))
         min_day, max_day = days or (None, None)
@@ -2785,7 +2785,7 @@ class QueryEngine:
         dense_plans = [p for p in agg_plans
                        if p.kind not in ("hll", "theta", "kll")]
 
-        cheap_f, exp_f = (self._split_filter_staged(filter_spec)
+        cheap_f, exp_f = (_compaction_filters(filter_spec)
                           if compact else (filter_spec, None))
         fuse_cse = bool(self.config.get(SHAREDSCAN_FUSION_ENABLED))
 
@@ -4416,3 +4416,36 @@ def filter_to_expr(f: S.FilterSpec) -> E.Expr:
         return E.And(tuple(parts)) if len(parts) != 1 else (
             parts[0] if parts else E.Literal(True))
     raise EngineFallback(f"filter {type(f).__name__}")
+
+
+def _all_staged(f) -> bool:
+    """Whether ``f`` is a filter of staged conjuncts alone
+    (``QueryEngine._split_filter_staged`` leaves no cheap part)."""
+    return f is not None and QueryEngine._split_filter_staged(f)[0] is None
+
+
+def _compaction_filters(f):
+    """(the mask late materialization compacts on, the conjuncts applied
+    to its survivors after it). The cheap conjuncts are the mask and the
+    staged ones follow (``QueryEngine._split_filter_staged``); a filter
+    whose conjuncts are ALL staged (an executed IN subquery's keys, past
+    1,024 of them a sorted set) is its own mask — it is evaluated over
+    every row with or without compaction, and nothing is left to follow."""
+    if _all_staged(f):
+        return f, None
+    return QueryEngine._split_filter_staged(f)
+
+
+def _estimate_blind(f, ds) -> bool:
+    """Whether the selectivity estimate has nothing to price the
+    compaction mask ``f`` by, so that its first sight asks the count
+    (``_plan_compact_m``): a mask of staged conjuncts alone, or one with
+    a value list over a column that has no dictionary — where
+    ``cost._filter_selectivity`` guesses 100 distinct values, and calls
+    TPC-H q18's 56-71 order keys (of 1.5 M orders at SF1) unselective."""
+    if _all_staged(f):
+        return True
+    conj = f.fields if isinstance(f, S.LogicalFilter) and f.op == "and" \
+        else (f,)
+    return any(isinstance(x, S.InFilter)
+               and ds.cardinality(x.dimension) is None for x in conj)

@@ -822,3 +822,138 @@ def test_gate_prices_the_form_the_program_runs(monkeypatch):
     eng.config.set(CF.COST_SORT_ROW.key, 2.2e-10)
     assert eng._plan_compact_m(ds(6), range(6), object(), False,
                                routes=ffl, n_keys=25) == 1 << 20
+
+
+# -- the mask a statement compacts on -------------------------------------------
+#
+# A filter with a cheap conjunct compacts on its cheap conjuncts and stages
+# the gather-heavy rest after the compaction; a filter of staged conjuncts
+# alone (an executed IN subquery's keys, past 1,024 of them a sorted set
+# the program binary-searches or probes) is its own mask; and a mask no
+# estimate can price — such a set, or a value list over a column with no
+# dictionary — is sized by the filter-only count at its first sight.
+
+def _keyed_df(n=60_000, seed=21):
+    """``_df`` with ``k``: each row's own integer key."""
+    df = _df(n, seed=seed)
+    df["k"] = np.random.default_rng(seed).permutation(n)
+    return df
+
+
+def _keyed_ctx(compact, df, picks, hashed=False):
+    c = _ctx(compact, df)
+    c.config.set("sdot.cache.enabled", False)
+    c.ingest_dataframe("picks", pd.DataFrame({"pk": picks}))
+    if hashed:
+        c.config.set("sdot.engine.groupby.dense.max.keys", 8)
+    return c
+
+
+KEYED_SQL = ("select region, sku, count(*) as n, sum(qty) as s from sales "
+             "where k in (select pk from picks) group by region, sku "
+             "order by region, sku")
+
+
+def _picks(n_keys, n_rows=60_000, seed=23):
+    return np.sort(np.random.default_rng(seed).choice(
+        n_rows, n_keys, replace=False))
+
+
+@pytest.mark.parametrize("tier", ["dense", "hashed"])
+def test_wide_set_is_its_own_compaction_mask(tier):
+    """1,500 keys of 60,000: a sorted set the split stages, and the
+    statement's only conjunct. It compacts on that set — counted at its
+    first sight, since no estimate prices it — and answers as the
+    uncompacted program does."""
+    df, picks = _keyed_df(), _picks(1500)
+    c = _keyed_ctx(True, df, picks, hashed=tier == "hashed")
+    got, st = _send(c, KEYED_SQL)
+    want = _keyed_ctx(False, df, picks, hashed=tier == "hashed") \
+        .sql(KEYED_SQL).to_pandas()
+    pd.testing.assert_frame_equal(got, want, check_exact=True)
+    assert st.get("hashed", False) == (tier == "hashed")
+    assert st["compact_mask"] == "staged"
+    assert st["compact_live"] == len(picks) <= st["compact_m"]
+    assert st["compact_from"] == "observed"
+    _, again = _send(c, KEYED_SQL)
+    assert again["compact_m"] == st["compact_m"]
+    assert again["program"]["built"] is False
+
+
+def test_staged_mask_under_a_short_budget_reruns(monkeypatch):
+    """A count that lies low leaves a budget its survivors exceed: the
+    program's own count re-runs the statement at the size it asks for,
+    with the same answer."""
+    from spark_druid_olap_tpu.parallel.executor import QueryEngine
+    df, picks = _keyed_df(), _picks(1500)
+    want = _keyed_ctx(False, df, picks).sql(KEYED_SQL).to_pandas()
+    c = _keyed_ctx(True, df, picks)
+    monkeypatch.setattr(QueryEngine, "_count_survivors",
+                        lambda self, *a: 10)
+    got, st = _send(c, KEYED_SQL)
+    pd.testing.assert_frame_equal(got, want, check_exact=True)
+    assert st["compact_overflow"] == len(picks) - _budget_for(10)
+    assert st["compact_mask"] == "staged"
+    assert st["compact_live"] == len(picks)
+    assert st["compact_m"] == _budget_for(len(picks))
+
+
+def test_short_integer_list_is_counted_and_compacts():
+    """72 keys: a value list the split calls cheap, over a column with no
+    dictionary, where the estimate guesses 100 distinct values and would
+    call it unselective — TPC-H q18's outer at SF1. Counted at its first
+    sight, it compacts on its cheap mask."""
+    df, picks = _keyed_df(), _picks(72)
+    sql = KEYED_SQL.replace("(select pk from picks)",
+                            "(" + ", ".join(map(str, picks)) + ")")
+    c = _keyed_ctx(True, df, picks)
+    got, st = _send(c, sql)
+    pd.testing.assert_frame_equal(
+        got, _keyed_ctx(False, df, picks).sql(sql).to_pandas(),
+        check_exact=True)
+    assert st["compact_mask"] == "cheap"
+    assert st["compact_from"] == "observed" and st["n_dispatch"] == 2
+    assert st["compact_live"] == len(picks)
+
+
+def test_dictionary_list_keeps_the_estimate():
+    """A value list over a dictionary column is priced by the estimate,
+    as before: 40 of 50 skus is unselective by it, so the statement is
+    neither counted nor compacted."""
+    c = _ctx(True)
+    c.config.set("sdot.cache.enabled", False)
+    skus = ", ".join(f"'sku{i:03d}'" for i in range(40))
+    _, st = _send(c, "select region, sum(qty) as s from sales where sku "
+                     f"in ({skus}) group by region order by region")
+    assert "compact_m" not in st and "compact_mask" not in st
+    assert st["n_dispatch"] == 1 and not c.engine._compact_seen
+
+
+def _filters():
+    from spark_druid_olap_tpu.ir import expr as E
+    from spark_druid_olap_tpu.ir import spec as S
+    cheap = S.SelectorFilter("sku", "sku007")
+    wide = S.InFilter("k", E.FrozenIntSet(_picks(1500)))
+    return cheap, wide, S.LogicalFilter("and", (cheap, wide))
+
+
+@pytest.mark.parametrize("case", ["none", "cheap_only", "cheap_and_staged"])
+def test_compaction_filters_keep_the_split(case):
+    """Every filter with a cheap conjunct, or none at all, compacts as it
+    did: on the split's cheap part, the staged part after it."""
+    from spark_druid_olap_tpu.parallel import executor as X
+    cheap, _, both = _filters()
+    f = {"none": None, "cheap_only": cheap, "cheap_and_staged": both}[case]
+    assert X._compaction_filters(f) == X.QueryEngine._split_filter_staged(f)
+    assert not X._all_staged(f)
+
+
+def test_compaction_filters_make_a_staged_filter_its_own_mask():
+    from spark_druid_olap_tpu.ir import spec as S
+    from spark_druid_olap_tpu.parallel import executor as X
+    cheap, wide, _ = _filters()
+    assert X.QueryEngine._split_filter_staged(wide) == (None, wide)
+    assert X._compaction_filters(wide) == (wide, None)
+    two = S.LogicalFilter("and", (wide, wide))
+    assert X._compaction_filters(two) == (two, None)
+    assert X._all_staged(wide) and X._all_staged(two)

@@ -138,6 +138,37 @@ def test_q18_lower_threshold_served(served):
     assert len(inners) == 1 and inners[0]["sql"] == "<subquery>"
 
 
+@pytest.mark.parametrize("thresh,mask", [(250, "cheap"), (190, "staged")])
+def test_q18_outer_compacts_on_its_in_list(served, thresh, mask):
+    """q18's outer has one conjunct, the inner's order keys. With the
+    gate's row minimum lowered to this scale it compacts on that list,
+    counted at its first sight (no estimate prices keys over a column
+    with no dictionary): under 1,024 keys a value list (SF1's 56-71 are
+    one), over them a sorted set the split stages, then the filter is its
+    own mask."""
+    key = "sdot.engine.scan.compact.min.rows"
+    was = served.ctx.config.get(key)
+    served.ctx.config.set(key, 0)
+    try:
+        sql = STATEMENTS["q18"]["sql"].replace("> 300", f"> {thresh}")
+        got, rec, _ = served.send(sql)
+        again, rec2, _ = served.send(sql)
+    finally:
+        served.ctx.config.set(key, was)
+    want = REF.oracle_q18(served.data, thresh=thresh)
+    _check("q18", got, want)
+    _check("q18", again, want)
+    qty = served.tables["lineitem"].groupby("l_orderkey").l_quantity.sum()
+    keys = qty[qty > thresh].index
+    assert (len(keys) > 1024) == (mask == "staged")
+    lines = int(served.data["tpch_flat"].o_orderkey.isin(keys).sum())
+    for r in (rec, rec2):
+        assert r["compact_mask"] == mask
+        assert r["compact_from"] == "observed"
+        assert r["compact_live"] == lines <= r["compact_m"]
+    assert rec["n_dispatch"] == 3 and rec2["n_dispatch"] == 2
+
+
 # -- every send executes its subqueries ----------------------------------------
 
 @pytest.mark.parametrize("cls", CLASSES)
