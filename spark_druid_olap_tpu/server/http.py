@@ -34,6 +34,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import threading
+import time
 import traceback
 import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -112,6 +113,7 @@ class SqlServer:
 
     # -- lifecycle ------------------------------------------------------------
     def start(self, background: bool = True):
+        from spark_druid_olap_tpu.utils.config import PHASES_ENABLED
         server = self
 
         class Handler(BaseHTTPRequestHandler):
@@ -124,6 +126,10 @@ class SqlServer:
                 # abandons in-flight connections at interpreter exit)
                 t = threading.current_thread()
                 server._handler_threads.add(t)
+                # HTTP/1.0: one request a connection, one thread a
+                # connection, so this thread began at this accept
+                self.accepted_ns = self.server.accepted.pop(self.request,
+                                                            None)
                 try:
                     super().handle()
                 finally:
@@ -181,6 +187,22 @@ class SqlServer:
             # the accept loop catches up
             request_queue_size = 128
 
+            def __init__(self, *args):
+                self.accepted = {}      # socket -> perf_counter_ns
+                super().__init__(*args)
+
+            def get_request(self):
+                # where a POST /sql's root span starts (http.accept)
+                sock, addr = super().get_request()
+                ctx = server.ctx        # None until a cluster node boots
+                if ctx is not None and ctx.config.get(PHASES_ENABLED):
+                    self.accepted[sock] = time.perf_counter_ns()
+                return sock, addr
+
+            def shutdown_request(self, request):
+                self.accepted.pop(request, None)    # never handled
+                super().shutdown_request(request)
+
         self._httpd = _Httpd((self.host, self.port), Handler)
         # handler threads must not pin the process (tests start/stop many
         # servers; a hung client connection would otherwise block exit),
@@ -206,15 +228,14 @@ class SqlServer:
             return
         httpd.shutdown()           # stop the serve_forever loop
         httpd.server_close()       # release the listen socket NOW
-        deadline = __import__("time").monotonic() + join_timeout_s
+        deadline = time.monotonic() + join_timeout_s
         for t in list(self._handler_threads):
-            remaining = deadline - __import__("time").monotonic()
+            remaining = deadline - time.monotonic()
             if remaining <= 0:
                 break
             t.join(remaining)      # daemons: a hung one won't pin exit
         if self._thread is not None:
-            self._thread.join(max(0.0, deadline
-                                  - __import__("time").monotonic()))
+            self._thread.join(max(0.0, deadline - time.monotonic()))
             self._thread = None
 
     # -- handlers -------------------------------------------------------------
@@ -463,7 +484,7 @@ class SqlServer:
         url = urlparse(h.path)
         if url.path == "/sql":
             from spark_druid_olap_tpu.utils.config import PHASES_ENABLED
-            root = PH.open_root("http.request") \
+            root = PH.open_root("http.request", accepted_ns=h.accepted_ns) \
                 if self.ctx.config.get(PHASES_ENABLED) else None
             try:
                 self._handle_sql(h, root)

@@ -685,8 +685,7 @@ def _run_select_planned(ctx, stmt, sql: str, ph_tok, memo,
         stats["plan_cached"] = True
     if memo_hit is not None:
         stats["plan_memo"] = {"hit": bool(memo_hit)}
-    _close_phases(stats, ph_tok)
-    ctx.history.record(stmt, stats, sql=sql)
+    _record(ctx, stmt, stats, ph_tok, sql)
     res = QueryResult(list(df.columns),
                       {c: df[c].to_numpy() for c in df.columns})
     # partial-results mode: the degraded annotation survives the
@@ -699,19 +698,23 @@ def _run_select_planned(ctx, stmt, sql: str, ph_tok, memo,
     return res
 
 
-def _close_phases(stats, ph_tok) -> None:
-    """Close the statement's accumulator into its record: the flat
-    ``phases`` and the span tree they are a view of. ``spans`` is the
-    live list — a root the server's handler opened gets its
-    ``http.encode`` / ``http.write`` after the record is written."""
+def _record(ctx, stmt, stats, ph_tok, sql: str) -> None:
+    """File the statement's record in the history, with its closed
+    accumulator: the flat ``phases`` and the span tree they are a view
+    of. ``spans`` is the live list — a root the server's handler opened
+    gets its ``http.encode`` / ``http.write`` after the record is
+    written, and the record its ``cpu_us``, ``wait_cpu_us`` and ``gc``
+    when the root closes."""
     phases = PH.end(ph_tok)
-    if phases is None:
-        return
-    stats["phases"] = {k: round(v, 3) for k, v in phases.items()}
-    stats["spans"] = ph_tok.stmt.spans
-    stats["t0_ns"] = ph_tok.stmt.t0_ns
-    if ph_tok.stmt.qid is not None:
-        stats["query_id"] = ph_tok.stmt.qid
+    if phases is not None:
+        stats["phases"] = {k: round(v, 3) for k, v in phases.items()}
+        stats["spans"] = ph_tok.stmt.spans
+        stats["t0_ns"] = ph_tok.stmt.t0_ns
+        if ph_tok.stmt.qid is not None:
+            stats["query_id"] = ph_tok.stmt.qid
+    rec = ctx.history.record(stmt, stats, sql=sql)
+    if phases is not None:
+        ph_tok.stmt.publish(rec.stats)
 
 
 def _maybe_windows(ctx, stmt):
@@ -746,8 +749,7 @@ def _run_windowed(ctx, wp, sql: str, ph_tok, t0: float) -> QueryResult:
                        "window_ms": round(
                            (_time.perf_counter() - _tw) * 1000, 2)}
     stats["total_ms"] = (_time.perf_counter() - t0) * 1000
-    _close_phases(stats, ph_tok)
-    ctx.history.record(base_stmt, stats, sql=sql)
+    _record(ctx, base_stmt, stats, ph_tok, sql)
     res = QueryResult(list(df.columns),
                       {c: df[c].to_numpy() for c in df.columns})
     res.degraded = base.degraded

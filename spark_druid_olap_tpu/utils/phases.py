@@ -11,7 +11,7 @@ tests/test_phases.py).
 
 Usage::
 
-    root = PH.open_root("http.request", qid)   # server only
+    root = PH.open_root("http.request", accepted_ns=a)   # server only
     tok = PH.begin()                 # open the statement's accumulator
     with PH.phase("plan.build"):
         ...
@@ -20,6 +20,7 @@ Usage::
         ...
     phases = PH.end(tok)             # {"plan.build": ms, ...}
     tok.stmt.spans, tok.stmt.t0_ns   # the tree, for the record
+    tok.stmt.publish(stats)          # CPU and GC: now or at the close
     PH.close_root(root)
 
 Semantics:
@@ -30,7 +31,34 @@ Semantics:
   on the same thread when this one began, the root is index 0 with
   parent -1.  The root is ``http.request`` when the server's handler
   opened it (``open_root``) and ``sql`` when ``begin()`` had to.  A span
-  still open has ``dur_us`` None; readers skip it.
+  still open has ``dur_us`` None; readers skip it.  A root the server
+  opens starts where its accept returned (``accepted_ns``), and its
+  first child ``http.accept`` covers the hand-off up to the handler's
+  first line (the connection's thread started, request line and headers
+  read), so the root's self time is what it was before.
+- When the root closes, three record keys are written into the record
+  the statement was ``publish``-ed to (null until then):
+  ``cpu_us``, the thread's CPU time (``time.thread_time_ns()``) over
+  the root — under the server from the start of the thread the accept
+  began, so the clock is read once, at the close, after the answer was
+  written; ``wait_cpu_us``, its CPU inside the spans that wait by
+  design (``WAITS``: the device, the coalescer, admission), read at the
+  open and close of each that ``phase()`` times, one inside another
+  once (an ``add()`` wait was parked and counts none); and ``gc``,
+  ``{"ms", "collections", "max_gen"}`` of the process's collections
+  that overlap the root's interval.  Wall minus CPU is time the thread
+  did not run: outside the designed waits, a GIL turn, a lock, the OS
+  scheduler, a collection by another thread.  No other span reads the
+  CPU clock: on a TPU v5e host a read is a system call, and there the
+  clock advances in 10 ms ticks, so a short span's own reading says
+  nothing and only a mean over many statements estimates CPU.
+- A collection holds the GIL and so stalls every Python thread: one
+  started on any thread counts.  A ``gc.callbacks`` hook, installed
+  when the first statement with spans opens, keeps each collection's
+  start, end and generation in a ring of ``_GC_RING`` entries; it
+  writes no profiler annotation.  Its end is read in the hook's second
+  call, which may first hand the GIL to a waiting thread: under
+  contention a collection can read up to one switch interval long.
 - ``stats["phases"]`` is the flat view ``{name: ms}`` of the spans that
   are *direct children of the root* while the accumulator is open.  A
   span nested in another (``dispatch.launch`` in ``dispatch``,
@@ -65,8 +93,9 @@ Semantics:
   named ``"sdot:" + name`` carrying ``qid`` and ``t0_ns`` (the span's
   own ``perf_counter_ns`` start), so any profiler capture of the process
   shows the same spans on the clock of the device lines; with no
-  capture running the annotation is a flag test.  ``add()`` learns of
-  its interval after the fact and writes no annotation.
+  capture running the annotation is a flag test.  ``add()`` and
+  ``http.accept`` learn of their interval after the fact and write no
+  annotation; the root's annotation begins where it was opened.
 
 The ``PHASES`` registry below is the single source of truth for span
 names; sdlint cross-checks every ``PH.phase("...")``/``PH.add("...")``
@@ -74,6 +103,7 @@ call site against it and against the docs/STATS.md phase table.
 """
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from typing import Dict, List, Optional
@@ -83,7 +113,8 @@ from jax.profiler import TraceAnnotation
 # name -> one-line meaning (kept a pure literal: sdlint parses it)
 PHASES = {
     "sql": "root of a statement run without the server (ctx.sql)",
-    "http.request": "root of a POST /sql: handler's first line to its last",
+    "http.request": "root of a POST /sql: accept to the handler's last line",
+    "http.accept": "accept -> handler body: thread start, request headers",
     "http.read": "request body read + json.loads",
     "http.encode": "result -> DataFrame -> JSON rows / Arrow bytes",
     "http.write": "status line, headers and body written to the socket",
@@ -123,25 +154,107 @@ PHASES = {
 
 _tls = threading.local()
 
+# the spans that wait by design: their CPU is read (``wait_cpu_us``)
+WAITS = frozenset(("dispatch.wait", "dispatch.fetch", "coalesce.hold",
+                   "coalesce.ride", "wlm.admit"))
+
+# the last _GC_RING collections of the process, in order:
+# (start_ns, end_ns, generation) on perf_counter_ns; _gc_n counts them all
+_GC_RING = 4096
+_gc_ring: List[Optional[tuple]] = [None] * _GC_RING
+_gc_n = 0
+_gc_start = 0
+_gc_hooked = False
+_gc_lock = threading.Lock()
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ring entry a collection."""
+    global _gc_n, _gc_start
+    if phase == "start":
+        _gc_start = time.perf_counter_ns()
+    elif _gc_start:                     # not one begun before the hook
+        _gc_ring[_gc_n % _GC_RING] = (_gc_start, time.perf_counter_ns(),
+                                      info["generation"])
+        _gc_n += 1
+        _gc_start = 0
+
+
+def _hook_gc() -> None:
+    """Install ``_on_gc`` once, when the first statement opens."""
+    global _gc_hooked
+    with _gc_lock:
+        if not _gc_hooked:
+            gc.callbacks.append(_on_gc)
+            _gc_hooked = True
+
+
+def _gc_overlap(t0_ns: int, t1_ns: int) -> dict:
+    """The ring's collections that overlap [t0_ns, t1_ns]: their
+    overlap in ms, their count, the highest generation (None: none)."""
+    ns, n, top = 0, 0, None
+    i = _gc_n
+    while i > max(0, _gc_n - _GC_RING):
+        i -= 1
+        start, end, gen = _gc_ring[i % _GC_RING]
+        if end <= t0_ns:
+            break                       # every older one ended earlier
+        if start < t1_ns:
+            ns += min(end, t1_ns) - max(start, t0_ns)
+            n += 1
+            top = gen if top is None else max(top, gen)
+    return {"ms": ns / 1e6, "collections": n, "max_gen": top}
+
 
 class _Stmt:
     """One statement's span tree; lives with the history record."""
 
-    __slots__ = ("spans", "stack", "t0_ns", "qid", "acc", "note")
+    __slots__ = ("spans", "stack", "t0_ns", "qid", "acc", "note", "cpu0",
+                 "wait_cpu_ns", "waiting", "cpu_us", "wait_cpu_us", "gc",
+                 "recs")
 
-    def __init__(self, name: str, qid: Optional[str], t0_ns: int) -> None:
+    def __init__(self, name: str, qid: Optional[str], t0_ns: int,
+                 cpu0: int, opened_ns: Optional[int] = None) -> None:
+        if not _gc_hooked:
+            _hook_gc()
         self.spans: List[list] = [[name, 0.0, None, -1]]
         self.stack = [0]
         self.t0_ns = t0_ns
         self.qid = qid
         self.acc: Optional[_Acc] = None     # the open accumulator
+        self.cpu0 = cpu0                    # thread_time_ns at the start
+        self.wait_cpu_ns = 0
+        self.waiting = False                # a designed wait is open
+        # the record's keys, None until the root closes
+        self.cpu_us: Optional[float] = None
+        self.wait_cpu_us: Optional[float] = None
+        self.gc: Optional[dict] = None
+        self.recs: List[dict] = []          # the records published to
         self.note = TraceAnnotation("sdot:" + name, qid=qid or "",
-                                    t0_ns=t0_ns)
+                                    t0_ns=opened_ns or t0_ns)
         self.note.__enter__()
+
+    def publish(self, stats: dict) -> None:
+        """Give the record ``stats`` the root's CPU and collections: now
+        if the root has closed, else null until it closes."""
+        self.recs.append(stats)
+        self._fill(stats)
+
+    def _fill(self, stats: dict) -> None:
+        stats.update({"cpu_us": self.cpu_us,
+                      "wait_cpu_us": self.wait_cpu_us, "gc": self.gc})
 
     def close(self) -> None:
         if self.spans[0][2] is None:
-            self.spans[0][2] = (time.perf_counter_ns() - self.t0_ns) / 1e3
+            cpu = time.thread_time_ns()
+            end = time.perf_counter_ns()
+            self.cpu_us = (cpu - self.cpu0) / 1e3
+            self.wait_cpu_us = self.wait_cpu_ns / 1e3
+            self.gc = _gc_overlap(self.t0_ns, end)
+            for stats in self.recs:
+                # first: a reader that sees the root closed finds them
+                self._fill(stats)
+            self.spans[0][2] = (end - self.t0_ns) / 1e3
             self.note.__exit__(None, None, None)
         if getattr(_tls, "st", None) is self:
             _tls.st = None
@@ -158,12 +271,25 @@ def _acc() -> Optional[_Acc]:
     return st.acc if st is not None else None
 
 
-def open_root(name: str, qid: Optional[str] = None) -> Optional[_Stmt]:
+def open_root(name: str, qid: Optional[str] = None,
+              accepted_ns: Optional[int] = None) -> Optional[_Stmt]:
     """Open a statement whose root span starts now (the server's
-    handler); None when this thread already has one."""
+    handler); None when this thread already has one.
+
+    ``accepted_ns``: the ``perf_counter_ns`` at which the server's
+    accept returned the connection this thread was started for.  The
+    root then starts there, its CPU counts from the thread's start (the
+    clock is not read here), and its first child ``http.accept`` covers
+    the accept up to now."""
     if getattr(_tls, "st", None) is not None:
         return None
-    st = _tls.st = _Stmt(name, qid, time.perf_counter_ns())
+    if accepted_ns is None:
+        st = _tls.st = _Stmt(name, qid, time.perf_counter_ns(),
+                             time.thread_time_ns())
+        return st
+    now = time.perf_counter_ns()
+    st = _tls.st = _Stmt(name, qid, accepted_ns, 0, opened_ns=now)
+    st.spans.append(["http.accept", 0.0, (now - accepted_ns) / 1e3, 0])
     return st
 
 
@@ -191,7 +317,7 @@ def begin(enabled: bool = True, qid: Optional[str] = None) -> Optional[_Acc]:
     if stash:
         acc.t0_ns = min(acc.t0_ns, min(s for s, _ in stash.values()))
     if st is None:
-        st = _tls.st = _Stmt("sql", qid, acc.t0_ns)
+        st = _tls.st = _Stmt("sql", qid, acc.t0_ns, time.thread_time_ns())
     elif qid is not None:
         st.qid = qid
     acc.stmt = st
@@ -250,6 +376,29 @@ class _Phase:
             self.st = None
 
 
+class _Wait(_Phase):
+    """A phase in ``WAITS``: the thread's CPU inside it joins the
+    statement's ``wait_cpu_us``, unless it opens inside another wait."""
+
+    __slots__ = ("cpu0",)
+
+    def __enter__(self) -> "_Wait":
+        _Phase.__enter__(self)
+        st = self.st
+        self.cpu0 = None
+        if st is not None and not st.waiting:
+            st.waiting = True
+            self.cpu0 = time.thread_time_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self.cpu0 is not None:
+            st = self.st
+            st.wait_cpu_ns += time.thread_time_ns() - self.cpu0
+            st.waiting = False
+        _Phase.__exit__(self)
+
+
 class _Lifted(_Phase):
     """A phase that is no part of the spans open around it (see
     ``lifted``)."""
@@ -277,7 +426,7 @@ class _Lifted(_Phase):
 
 def phase(name: str) -> _Phase:
     """Context manager timing one span; no-op without an open statement."""
-    return _Phase(name)
+    return _Wait(name) if name in WAITS else _Phase(name)
 
 
 def lifted(name: str) -> _Phase:

@@ -6,7 +6,9 @@ Five layers:
    nested-begin merge, no-op outside an accumulator, stash folding, the
    span tree (parents, starts, durations, self time), the child-span
    rule, and the always-on overhead micro-budget (< 1% of wall enforced
-   as a per-timing ceiling far below the ~1.7 ms dispatch floor).
+   as a per-timing ceiling far below the ~1.7 ms dispatch floor), the
+   statement's thread CPU time beside its wall time, and the process's
+   collections landing in the statements they overlap.
 2. **Stats contract** — every executed statement carries
    ``stats["phases"]`` whose names all come from the PHASES registry,
    and the key disappears when ``sdot.phases.enabled`` is off.
@@ -28,6 +30,7 @@ Five layers:
 """
 
 import contextlib
+import gc
 import json
 import os
 import threading
@@ -254,6 +257,139 @@ def test_overhead_micro_budget():
     assert per < 50e-6, f"{per * 1e6:.1f}us per span"
 
 
+def _spin_cpu(seconds):
+    """Run on the CPU until this thread has used ``seconds`` of it."""
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.mark.parametrize("work,on_cpu", [
+    (lambda: time.sleep(0.05), False),
+    (lambda: _spin_cpu(0.02), True),
+], ids=["sleep", "spin"])
+def test_span_cpu_tells_work_from_waiting(work, on_cpu):
+    """The root's CPU time is its thread's: a sleep inside the statement
+    reads as off-CPU (wall far above CPU), a busy loop as on-CPU."""
+    tok = PH.begin()
+    with PH.phase("bind"):
+        work()
+    PH.end(tok)
+    wall, cpu = tok.stmt.spans[0][2], tok.stmt.cpu_us
+    assert 0.0 <= cpu <= wall and tok.stmt.wait_cpu_us == 0.0
+    if on_cpu:
+        assert cpu >= 20000.0                       # us
+    else:
+        assert wall >= 50000.0 and cpu < 5000.0
+
+
+def test_wait_cpu_counts_each_designed_wait_once():
+    """``wait_cpu_us`` is the CPU inside the spans that wait by design,
+    a wait inside another counted once; work outside them is not in
+    it, and a wait cut after the fact carries none."""
+    tok = PH.begin()
+    with PH.phase("bind"):
+        _spin_cpu(0.01)
+    with PH.phase("dispatch.wait"):
+        _spin_cpu(0.01)
+        with PH.phase("dispatch.fetch"):
+            _spin_cpu(0.03)
+    PH.add("coalesce.ride", 0.5)
+    PH.end(tok)
+    st = tok.stmt
+    assert 40000.0 <= st.wait_cpu_us < 55000.0          # us: not 70 ms
+    assert st.wait_cpu_us + 10000.0 <= st.cpu_us <= st.spans[0][2]
+
+
+def test_cpu_and_gc_reach_the_record_when_the_root_closes():
+    """A record published while its root is open holds the three keys
+    null, and gets them when the root closes; one published after the
+    close gets them at once. No span row grows a column."""
+    root = PH.open_root("http.request")
+    tok = PH.begin()
+    with PH.phase("bind"):
+        PH.add("tier.fault", 0.001)
+    PH.end(tok)
+    early = {}
+    tok.stmt.publish(early)
+    assert early == {"cpu_us": None, "wait_cpu_us": None, "gc": None}
+    PH.close_root(root)
+    late = {}
+    tok.stmt.publish(late)
+    assert early == late == {"cpu_us": root.cpu_us, "gc": root.gc,
+                             "wait_cpu_us": root.wait_cpu_us}
+    assert set(late) == {"cpu_us", "wait_cpu_us", "gc"}
+    assert late["gc"].keys() == {"ms", "collections", "max_gen"}
+    assert 0.0 <= late["cpu_us"] <= root.spans[0][2]
+    assert all(len(sp) == 4 for sp in root.spans)
+
+
+def test_gc_hook_waits_for_a_statement_with_spans(monkeypatch):
+    """The ``gc.callbacks`` hook goes in with the first statement that
+    has spans, once; with spans off the process runs without it."""
+    hooked = PH._on_gc in gc.callbacks
+    if hooked:
+        gc.callbacks.remove(PH._on_gc)
+    monkeypatch.setattr(PH, "_gc_hooked", False)
+    try:
+        PH.end(PH.begin(enabled=False))
+        assert PH._on_gc not in gc.callbacks
+        PH.end(PH.begin())
+        PH.end(PH.begin())
+        assert gc.callbacks.count(PH._on_gc) == 1
+    finally:
+        while PH._on_gc in gc.callbacks:
+            gc.callbacks.remove(PH._on_gc)
+        if hooked:
+            gc.callbacks.append(PH._on_gc)
+
+
+@contextlib.contextmanager
+def _no_automatic_gc():
+    """Only the collections a test forces."""
+    gc.disable()
+    try:
+        yield
+    finally:
+        gc.enable()
+
+
+def test_gc_on_another_thread_lands_in_the_statement_it_stalls():
+    """A collection forced on another thread during a statement is in
+    that record's ``gc``; one outside every statement is in none."""
+    junk = [[i] for i in range(100_000)]        # something to traverse
+    with _no_automatic_gc():
+        gc.collect()                            # outside: before
+        tok = PH.begin()
+        t = threading.Thread(target=gc.collect)
+        t.start()
+        t.join(60)
+        assert not t.is_alive()
+        PH.end(tok)
+        gc.collect()                            # outside: between
+        after = PH.begin()
+        PH.end(after)
+    stalled, clean = tok.stmt.gc, after.stmt.gc
+    assert stalled["collections"] == 1 and stalled["max_gen"] == 2
+    assert 0.0 < stalled["ms"] <= tok.stmt.spans[0][2] / 1000.0
+    assert clean == {"ms": 0.0, "collections": 0, "max_gen": None}
+    del junk
+
+
+def test_gc_overlap_counts_only_the_part_inside():
+    """The ring's arithmetic: a collection straddling the root's start
+    counts its part inside, one that ended before it counts not."""
+    PH.end(PH.begin())                  # the hook is in
+    with _no_automatic_gc():
+        gc.collect()
+        start, end, gen = PH._gc_ring[(PH._gc_n - 1) % PH._GC_RING]
+        mid = (start + end) // 2
+        assert PH._gc_overlap(end, end + 10**9)["collections"] == 0
+        half = PH._gc_overlap(mid, end + 10**9)
+        assert half["collections"] == 1 and half["max_gen"] == gen == 2
+        assert half["ms"] == pytest.approx((end - mid) / 1e6)
+
+
 # -- 2/3. session stats contract + memo --------------------------------------
 
 def _sales_df(n=2000):
@@ -413,7 +549,8 @@ def test_negative_outcomes_are_memoized(ctx):
 
 # -- 5. the record's span tree ------------------------------------------------
 
-CHILD_SPANS = {"sql", "http.request", "http.read", "http.encode",
+CHILD_SPANS = {"sql", "http.request", "http.accept", "http.read",
+               "http.encode",
                "http.write", "dispatch.launch", "dispatch.wait",
                "dispatch.fetch"}
 
@@ -707,14 +844,54 @@ def test_http_root_and_its_children(served):
     spans = rec["spans"]
     assert spans[0][0] == "http.request"
     top = [sp[0] for sp in spans if sp[3] == 0]
-    assert top[0] == "http.read" and top[-2:] == ["http.encode",
-                                                  "http.write"]
+    assert top[:2] == ["http.accept", "http.read"]
+    assert top[-2:] == ["http.encode", "http.write"]
     assert "dispatch" in top and "decode" in top
     assert not any(k.startswith("http.") for k in rec["phases"])
     # total_ms is the session's: the handler's spans lie around it
-    read, enc = spans[1], next(sp for sp in spans if sp[0] == "http.encode")
+    read, enc = spans[2], next(sp for sp in spans if sp[0] == "http.encode")
     assert (enc[1] - (read[1] + read[2])) / 1000.0 >= rec["total_ms"] - 0.05
     assert spans[0][2] >= enc[1] + enc[2]
+
+
+def test_root_cpu_at_most_wall_served_and_not(served, ctx):
+    """The record's ``cpu_us`` is the root's CPU, never above its wall,
+    and ``wait_cpu_us`` is part of it — under the server and without."""
+    qid = _post_sql(served.port, Q)["queryId"]
+    ctx.sql(Q)
+    for rec in (_completed(served.port, qid), _last_stats(ctx)):
+        wall = rec["spans"][0][2]
+        assert wall is not None and rec["gc"]["collections"] >= 0
+        assert 0.0 <= rec["wait_cpu_us"] <= rec["cpu_us"] <= wall
+
+
+class _Stamps(dict):
+    """The server's accept stamps, counting every one written."""
+
+    def __init__(self):
+        super().__init__()
+        self.written = 0
+
+    def __setitem__(self, sock, ns):
+        self.written += 1
+        super().__setitem__(sock, ns)
+
+
+@pytest.mark.parametrize("on", [True, False], ids=["spans_on", "spans_off"])
+def test_accept_stamped_only_with_spans_on(served, ctx, on):
+    """The accept is stamped, and the record gets its CPU and GC keys,
+    only while spans are on: with them off the server's path is the
+    parent's."""
+    served._httpd.accepted = stamps = _Stamps()
+    ctx.config.set("sdot.phases.enabled", on)
+    try:
+        _post_sql(served.port, Q)
+    finally:
+        ctx.config.set("sdot.phases.enabled", True)
+    rec = ctx.history.entries()[-1].stats
+    assert stamps.written == int(on) and not stamps
+    keys = {"spans", "cpu_us", "wait_cpu_us", "gc"}
+    assert keys & set(rec) == (keys if on else set())
 
 
 def test_profiler_capture_carries_the_spans(served, tmp_path):

@@ -2,6 +2,7 @@
 CancelDruidRequestTest/metadata-views suites)."""
 
 import json
+import time
 import urllib.request
 import urllib.error
 
@@ -239,6 +240,51 @@ def test_sql_returns_query_id(server):
     code, body = _post(server, "/sql", {
         "sql": "select count(*) as n from sales"})
     assert code == 200 and len(body["queryId"]) >= 16   # minted
+
+
+def _completed_record(server, qid):
+    """The history record of ``qid`` once its handler has closed the
+    root (the client has its answer a moment before the last line)."""
+    for _ in range(200):
+        _, body = _get(server, "/history")
+        rec = next(r for r in body["history"] if r.get("query_id") == qid)
+        if rec["spans"][0][2] is not None:
+            return rec
+        time.sleep(0.01)
+    raise AssertionError(f"root of {qid} never closed: {rec['spans']}")
+
+
+def _self_us(dur, kids):
+    """``dur`` minus the union of the ``kids`` rows' intervals."""
+    covered, end = 0.0, float("-inf")
+    for a, b in sorted((k[1], k[1] + k[2]) for k in kids):
+        covered += max(0.0, b - max(a, end))
+        end = max(end, b)
+    return dur - covered
+
+
+def test_sql_root_starts_at_the_accept(server):
+    """A POST /sql's root starts where the server's accept returned,
+    inside the client's own wall: its first child ``http.accept`` covers
+    the hand-off (thread start, request line, headers) up to where
+    ``http.read`` begins, so the root's self time is what the union rule
+    gives without it — the interval the root gained is covered."""
+    before = time.perf_counter_ns()
+    _, body = _post(server, "/sql", {
+        "sql": "select count(*) as n from sales"})
+    after = time.perf_counter_ns()
+    rec = _completed_record(server, body["queryId"])
+    spans = rec["spans"]
+    acc, read = spans[1], spans[2]
+    assert (acc[0], acc[1], acc[3]) == ("http.accept", 0.0, 0)
+    assert (read[0], read[3]) == ("http.read", 0)
+    assert before <= rec["t0_ns"] < rec["t0_ns"] + acc[2] * 1e3 <= after
+    assert 0.0 <= read[1] - acc[2] < 50_000.0      # us: where read opens
+    assert 0.0 <= rec["cpu_us"] <= spans[0][2]
+    kids = [sp for sp in spans[1:] if sp[3] == 0 and sp[2] is not None]
+    without = [sp for sp in kids if sp[0] != "http.accept"]
+    assert _self_us(spans[0][2], kids) == pytest.approx(
+        _self_us(spans[0][2] - acc[2], without), abs=1e-6)
 
 
 def test_cancel_unknown_id(server):
